@@ -19,10 +19,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from mxnet_tpu.base import ensure_live_backend  # noqa: E402
-
-ensure_live_backend()
-
 import numpy as np  # noqa: E402
 
 import mxnet_tpu as mx  # noqa: E402

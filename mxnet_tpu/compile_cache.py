@@ -62,7 +62,7 @@ import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
-from .base import get_env
+from .base import force_cpu, get_env
 from . import telemetry as _telemetry
 
 __all__ = ["enabled", "cache_dir", "envelope", "cache_key",
@@ -401,56 +401,82 @@ def reset_stats() -> None:
 # neither pickle nor key stably — so those programs can never use the
 # store.  jax's own persistent compilation cache (keyed on the
 # optimized-HLO hash, so it needs no tree serialization) covers exactly
-# that residue: a warm process still pays TRACING for those sites but
-# skips XLA optimization+codegen.  activate() arms it under
-# <MX_COMPILE_CACHE>/xla and maps jax's cache-hit/miss monitoring
+# that residue, and every program when MX_COMPILE_CACHE is unset: a warm
+# process still pays TRACING but skips XLA optimization+codegen.
+# activate() arms it for every process — the path is part of the cache
+# key, so it is a FIXED one — and maps jax's cache-hit/miss monitoring
 # events onto compile_cache.xla_hits / xla_misses.
 
 _activate_lock = threading.Lock()
 _activated = False
+
+#: where jax's cache goes when neither JAX_COMPILATION_CACHE_DIR nor
+#: MX_COMPILE_CACHE places it: next to the package, in the checkout
+DEFAULT_XLA_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def xla_cache_dir() -> Optional[str]:
+    """The directory activate() sets for jax's persistent compilation
+    cache: ``<MX_COMPILE_CACHE>/xla`` if the operator gave one, else the
+    fixed ``<checkout>/.jax_cache``.  None under the harness's
+    MX_FORCE_CPU=1 pin with no directory given: a CPU-pinned process
+    compiles nothing worth keeping, and XLA:CPU's loader writes two long
+    error lines to stderr per cache hit (machine-feature check, jaxlib
+    0.9.0) — enough to fill the undrained stderr pipe of a supervised
+    child and block it."""
+    if enabled():
+        return os.path.join(cache_dir(), "xla")
+    if force_cpu():
+        return None
+    return DEFAULT_XLA_DIR
 
 
 def _on_jax_event(name: str, **kw) -> None:
     if name == "/jax/compilation_cache/cache_hits":
         _counter("compile_cache.xla_hits",
                  "XLA-level persistent-cache hits (jax compilation "
-                 "cache under MX_COMPILE_CACHE/xla: trace paid, "
-                 "XLA compile skipped)").inc()
+                 "cache: trace paid, XLA compile skipped)").inc()
     elif name == "/jax/compilation_cache/cache_misses":
         _counter("compile_cache.xla_misses",
                  "XLA-level persistent-cache misses (cold compile, "
                  "entry written for the next process)").inc()
 
 
-def activate() -> bool:
-    """Arm both cache layers for this process (idempotent).  Called by
-    ``programs.register_program`` on first use, so every jit site —
-    AOT or light — is covered the moment MX_COMPILE_CACHE is set."""
+def activate() -> None:
+    """Arm jax's persistent compilation cache for this process
+    (idempotent).  Called by ``programs.register_program``, whether or
+    not MX_COMPILE_CACHE is set, so every jit site — AOT or light —
+    finds its XLA compile again in the next process.  Where
+    JAX_COMPILATION_CACHE_DIR is set jax reads it itself and no
+    directory is set in code."""
     global _activated
-    if not enabled():
-        return False
+    if _activated:
+        return
+    from_env = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    xla_dir = None if from_env else xla_cache_dir()
+    if xla_dir is None and not from_env:
+        return              # nothing to arm (yet): see xla_cache_dir
     with _activate_lock:
         if _activated:
-            return True
+            return
         _activated = True
-    try:
-        import jax
-        from jax import monitoring as _mon
-        xla_dir = os.path.join(cache_dir(), "xla")
-        os.makedirs(xla_dir, exist_ok=True)
+    import jax
+    from jax import monitoring as _mon
+    if xla_dir is not None:
+        try:
+            os.makedirs(xla_dir, exist_ok=True)
+        except OSError as e:
+            logger.warning("compile_cache: cannot create %s (%s); jax's "
+                           "persistent cache stays off", xla_dir, e)
+            return
         jax.config.update("jax_compilation_cache_dir", xla_dir)
-        # default thresholds skip sub-second/small programs — exactly
-        # the long tail a warm restart re-pays 100x of
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _mon.register_event_listener(_on_jax_event)
-    except Exception as e:
-        logger.warning("compile_cache: XLA-layer cache unavailable "
-                       "(%s: %s); executable store still active",
-                       type(e).__name__, e)
-        return True
-    return True
+    # default thresholds skip sub-second/small programs — exactly
+    # the long tail a warm restart re-pays 100x of
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    _mon.register_event_listener(_on_jax_event)
 
 
 # ---------------------------------------------------------------------------

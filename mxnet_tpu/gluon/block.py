@@ -698,11 +698,17 @@ class SymbolBlock(HybridBlock):
 
     @staticmethod
     def imports(symbol_file, input_names, param_file=None, ctx=None):
+        """Load an exported artifact; the parameters are registered as
+        this block's Parameters on ``ctx`` (default: the current
+        context), so ``collect_params``/``functionalize`` see them and
+        a jitted forward takes them as operands on that device instead
+        of baking host constants into the program."""
         from ..symbol import load as sym_load
         sym = sym_load(symbol_file)
         if isinstance(input_names, str):
             input_names = [input_names]
         block = SymbolBlock(sym, input_names)
+        loaded = {}
         if param_file:
             loaded = _nd_mod.load(param_file)
             if isinstance(loaded, list):
@@ -712,15 +718,20 @@ class SymbolBlock(HybridBlock):
                         "LIST; parameters need the dict form (arg:/aux: "
                         "keys)" % param_file)
                 loaded = {}      # empty save is format-ambiguous
-            block._sym_params = loaded
-        else:
-            block._sym_params = {}
+        ctx = ctx or current_context()
+        for key, value in loaded.items():
+            aux = key.startswith("aux:")
+            name = key[4:] if aux or key.startswith("arg:") else key
+            param = Parameter(name, shape=value.shape, dtype=value.dtype,
+                              grad_req="null" if aux else "write")
+            param._deferred_init = (None, [ctx], None)
+            param.set_data(value)    # materializes on ctx
+            block._reg_params[name] = param
         block._input_names = input_names
         return block
 
     def forward(self, *args):
         from ..symbol import evaluate as sym_eval
         feeds = dict(zip(self._input_names, args))
-        params = {k[4:] if k.startswith(("arg:", "aux:")) else k: v
-                  for k, v in self._sym_params.items()}
+        params = {name: p.data() for name, p in self._reg_params.items()}
         return sym_eval(self._outputs, feeds, params)

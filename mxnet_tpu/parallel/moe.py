@@ -17,13 +17,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:                    # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["moe_apply", "moe_parallel", "top1_dispatch"]
 

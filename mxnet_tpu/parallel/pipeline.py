@@ -21,13 +21,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:                    # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply", "pipeline_parallel"]
 

@@ -29,13 +29,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:                                   # jax >= 0.7 canonical location
-    from jax import shard_map
-except ImportError:                    # older: experimental alias
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["ring_attention", "ulysses_attention",
            "context_parallel_attention"]
@@ -256,16 +251,12 @@ def context_parallel_attention(q, k, v, mesh: Mesh, *, sp_axis: str = "sp",
     spec = P(None, sp_axis, None, None)
     inner = functools.partial(fn, axis_name=sp_axis, causal=causal,
                               scale=scale)
-    # check_vma/check_rep off: interpret-mode pallas inside shard_map trips
-    # jax's varying-axes checker on kernel constants ("Primitive mul
-    # requires varying manual axes to match ... as a temporary workaround
-    # pass check_vma=False") — the jax-recommended workaround
-    try:
-        mapped = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_vma=False)
-    except TypeError:   # older jax spells it check_rep
-        mapped = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_rep=False)
+    # check_vma off: interpret-mode pallas inside shard_map trips jax's
+    # varying-axes checker on kernel constants ("Primitive mul requires
+    # varying manual axes to match ... as a temporary workaround pass
+    # check_vma=False") — the jax-recommended workaround
+    mapped = shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     sharding = NamedSharding(mesh, spec)
     q, k, v = (jax.device_put(x, sharding) for x in (q, k, v))
     return mapped(q, k, v)

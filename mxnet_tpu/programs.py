@@ -696,8 +696,7 @@ def register_program(name: str, fn: Callable, mode: str = "aot",
     ``MX_PROGRAM_CENSUS=0`` this is exactly ``jax.jit``.
     """
     from . import compile_cache as _cc
-    if _cc.enabled():
-        _cc.activate()          # idempotent; arms the XLA-level layer
+    _cc.activate()              # idempotent; arms jax's persistent cache
     if not census_enabled():
         return jax.jit(fn, **jit_kw)
     return Program(name, mode, fn, jit_kw, aot=(mode == "aot"),

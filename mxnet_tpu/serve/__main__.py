@@ -38,7 +38,8 @@ def _build_servables(args):
     multi-model co-hosting (ISSUE 20): the FIRST spec is the default
     model, the rest are admitted through ``ServeServer.add_model``
     under the MX_SERVE_HBM_BUDGET packer and addressed by the wire
-    envelope's model field."""
+    envelope's model field.  Parameters load onto the current context
+    (main() builds under ``tpu(0)``)."""
     from .servable import BucketTable, Servable
     buckets = BucketTable([int(b) for b in args.buckets.split(",")]) \
         if args.buckets else None
@@ -112,8 +113,16 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from ..base import get_env
+    from ..device import tpu
     from ..health import Heartbeat
     from .server import ServeServer, serve_forever
+
+    # A replica serves from the accelerator: parameters, KV pools and
+    # inputs live on tpu(0) — the chip, or the first host device under
+    # the harness's MX_FORCE_CPU=1 pin.  With neither, resolving the
+    # device raises and the replica does not start.
+    ctx = tpu(0)
+    device = ctx.jax_device
 
     port = args.port
     if port is None and args.port_base is not None:
@@ -142,53 +151,54 @@ def main(argv=None) -> int:
 
     decode_engine = None
     t_warm0 = time.perf_counter()
-    if args.decode:
-        # the GENERATE lane: demo LM + continuous-batching decode pump
-        # (ISSUE 15); warm() pre-builds every prefill/decode bucket so
-        # serve time pays zero traces.  MX_SERVE_KV_PAGES > 0 selects
-        # the PAGED engine (ISSUE 18): shared page heap + block tables,
-        # hash-shared prefixes, chunked prefill — same wire surface.
-        paged = int(get_env("MX_SERVE_KV_PAGES", 0, int) or 0) > 0
-        draft_layers = int(get_env("MX_SERVE_DRAFT", 0, int) or 0)
-        if draft_layers > 0:
-            # speculative decoding (ISSUE 20): a shallow draft proposes
-            # MX_SERVE_SPEC_K tokens per window, the paged target
-            # verifies them in ONE multi-position dispatch; co-hosted
-            # draft+target share the page heap budget
-            if not paged:
-                raise SystemExit("serve: MX_SERVE_DRAFT needs the "
-                                 "paged engine (set MX_SERVE_KV_PAGES)")
-            from .decode import (DecodeConfig, DraftDecodeServable,
-                                 PagedDecodeServable,
-                                 SpeculativeDecodeBatcher,
-                                 demo_spec_pair)
-            cfg = DecodeConfig()
-            tparams, dcfg, dparams = demo_spec_pair(
-                cfg, draft_layers=draft_layers)
-            decode_engine = SpeculativeDecodeBatcher(
-                PagedDecodeServable(params=tparams, config=cfg),
-                DraftDecodeServable(params=dparams, config=dcfg,
-                                    name="demo-lm-draft"),
-                on_tick=tick)
-        elif paged:
-            from .decode import PagedDecodeBatcher, PagedDecodeServable
-            decode_engine = PagedDecodeBatcher(PagedDecodeServable(),
-                                               on_tick=tick)
-        else:
-            from .decode import DecodeBatcher, DecodeServable
-            decode_engine = DecodeBatcher(DecodeServable(),
-                                          on_tick=tick)
-    state = ServeServer(on_tick=tick, decode=decode_engine)
-    sv = None
-    specs = _build_servables(args)
-    if specs:
-        sv, example = specs[0]
-        state.host.deploy(sv, example=example)
-        for extra_sv, extra_ex in specs[1:]:
-            state.add_model(extra_sv, example=extra_ex, on_tick=tick)
-    elif not args.decode:
-        raise SystemExit("serve: need --model PREFIX, --demo or "
-                         "--decode")
+    with ctx:
+        if args.decode:
+            # the GENERATE lane: demo LM + continuous-batching decode pump
+            # (ISSUE 15); warm() pre-builds every prefill/decode bucket so
+            # serve time pays zero traces.  MX_SERVE_KV_PAGES > 0 selects
+            # the PAGED engine (ISSUE 18): shared page heap + block tables,
+            # hash-shared prefixes, chunked prefill — same wire surface.
+            paged = int(get_env("MX_SERVE_KV_PAGES", 0, int) or 0) > 0
+            draft_layers = int(get_env("MX_SERVE_DRAFT", 0, int) or 0)
+            if draft_layers > 0:
+                # speculative decoding (ISSUE 20): a shallow draft proposes
+                # MX_SERVE_SPEC_K tokens per window, the paged target
+                # verifies them in ONE multi-position dispatch; co-hosted
+                # draft+target share the page heap budget
+                if not paged:
+                    raise SystemExit("serve: MX_SERVE_DRAFT needs the "
+                                     "paged engine (set MX_SERVE_KV_PAGES)")
+                from .decode import (DecodeConfig, DraftDecodeServable,
+                                     PagedDecodeServable,
+                                     SpeculativeDecodeBatcher,
+                                     demo_spec_pair)
+                cfg = DecodeConfig()
+                tparams, dcfg, dparams = demo_spec_pair(
+                    cfg, draft_layers=draft_layers)
+                decode_engine = SpeculativeDecodeBatcher(
+                    PagedDecodeServable(params=tparams, config=cfg),
+                    DraftDecodeServable(params=dparams, config=dcfg,
+                                        name="demo-lm-draft"),
+                    on_tick=tick)
+            elif paged:
+                from .decode import PagedDecodeBatcher, PagedDecodeServable
+                decode_engine = PagedDecodeBatcher(PagedDecodeServable(),
+                                                   on_tick=tick)
+            else:
+                from .decode import DecodeBatcher, DecodeServable
+                decode_engine = DecodeBatcher(DecodeServable(),
+                                              on_tick=tick)
+        state = ServeServer(on_tick=tick, decode=decode_engine)
+        sv = None
+        specs = _build_servables(args)
+        if specs:
+            sv, example = specs[0]
+            state.host.deploy(sv, example=example)
+            for extra_sv, extra_ex in specs[1:]:
+                state.add_model(extra_sv, example=extra_ex, on_tick=tick)
+        elif not args.decode:
+            raise SystemExit("serve: need --model PREFIX, --demo or "
+                             "--decode")
     warm_s = time.perf_counter() - t_warm0
     # warm-start visibility (ISSUE 13): with MX_COMPILE_CACHE set, a
     # respawned replica deserializes its whole bucket table instead of
@@ -197,9 +207,10 @@ def main(argv=None) -> int:
     from ..compile_cache import stats as _cc_stats
     cs = _cc_stats()
     if sv is not None:
-        print("serve: %s v%d warm on %d bucket(s) %r in %.2fs "
-              "(compile-cache%s hits=%d misses=%d), port %d"
-              % (sv.name, sv.version, len(sv.buckets.sizes),
+        print("serve: %s v%d warm on %s (params on %s), %d bucket(s) %r "
+              "in %.2fs (compile-cache%s hits=%d misses=%d), port %d"
+              % (sv.name, sv.version, device, sv.param_platform(),
+                 len(sv.buckets.sizes),
                  list(sv.buckets.sizes), warm_s,
                  "" if cs["enabled"] else " off",
                  cs["hits"], cs["misses"], port),

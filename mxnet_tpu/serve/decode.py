@@ -730,6 +730,14 @@ class DecodeServable:
             "len": jnp.zeros((cfg.slots + 1,), jnp.int32),
         }
 
+    def param_platform(self) -> str:
+        """Where the parameters AND the KV pool live, for HEALTH (both
+        are built uncommitted with jnp, so they land on jax's default
+        device — the chip, unless the process is CPU-pinned)."""
+        from .servable import platform_of
+        return platform_of(list(self.params.values()) +
+                           [self._state["k"], self._state["v"]])
+
     def __init__(self, params: Optional[Dict] = None,
                  config: Optional[DecodeConfig] = None,
                  name: str = "demo-lm", version: int = 1):

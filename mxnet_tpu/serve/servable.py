@@ -75,6 +75,14 @@ def _counter(name, doc):
     return _telemetry.registry.counter(name, doc=doc)
 
 
+def platform_of(arrays) -> str:
+    """The platform(s) holding `arrays` — "tpu", "cpu", or a
+    comma-joined list when they straddle.  A replica reports this in
+    HEALTH so a client can check that the model is on the chip."""
+    plats = {d.platform for a in arrays for d in a.devices()}
+    return ",".join(sorted(plats)) or "none"
+
+
 class Servable:
     """One immutable model version: parameters + AOT program table.
 
@@ -98,6 +106,10 @@ class Servable:
         self.version = int(version)
         self.buckets = buckets or BucketTable.from_env()
         self._pure, self._param_values = functionalize(block)
+        #: the Context the parameters were built/loaded on (a SWAP
+        #: loads its successor onto the same one); None = no parameters
+        self.ctx = next((p.list_ctx()[0] for p in
+                         block.collect_params().values()), None)
         # buffer-census attribution (ISSUE 10): this version's parameter
         # arrays show up under the "serve" owner bucket
         from .. import programs as _programs
@@ -141,18 +153,22 @@ class Servable:
     @staticmethod
     def from_checkpoint(prefix: str, epoch: int = 0,
                         input_names: Sequence[str] = ("data",),
-                        **kwargs) -> "Servable":
+                        ctx=None, **kwargs) -> "Servable":
         """Host an exported/foreign ``<prefix>-symbol.json`` +
         ``<prefix>-%04d.params`` artifact through the existing
         ``SymbolBlock.imports`` lane (the deploy format every MXNet-era
-        tool emits)."""
+        tool emits).  Parameters load onto ``ctx`` (default: the
+        current context) as inference-only operands of the bucket
+        programs."""
         from ..gluon.block import SymbolBlock
         sym_file = "%s-symbol.json" % prefix
         params_file = "%s-%04d.params" % (prefix, int(epoch))
         if not os.path.exists(params_file):
             params_file = None
         block = SymbolBlock.imports(sym_file, list(input_names),
-                                    params_file)
+                                    params_file, ctx=ctx)
+        # serving never differentiates: no gradient buffers on the device
+        block.collect_params().setattr("grad_req", "null")
         kwargs.setdefault("name", os.path.basename(prefix))
         return Servable(block, **kwargs)
 
@@ -279,6 +295,10 @@ class Servable:
         arrays its ``buffer_census()`` owner tags claim)."""
         return sum(int(getattr(a, "nbytes", 0))
                    for a in self._param_values.values())
+
+    def param_platform(self) -> str:
+        """Where this version's parameters live, for HEALTH."""
+        return platform_of(self._param_values.values())
 
     def footprint_bytes(self) -> int:
         """Measured HBM footprint for budget admission: live bytes

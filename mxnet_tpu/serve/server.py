@@ -493,6 +493,7 @@ class ServeServer:
             status: Dict = {"status": "serving", "version": sv.version,
                             "model": sv.name,
                             "buckets": list(sv.buckets.sizes),
+                            "param_platform": sv.param_platform(),
                             "retraces": sv.retraces,
                             "bucket_hits": sv.bucket_hits}
         except MXNetError:
@@ -511,6 +512,7 @@ class ServeServer:
             status["decode"] = {
                 "model": dsv.name, "version": dsv.version,
                 "engine": getattr(dsv, "engine", "flat"),
+                "param_platform": dsv.param_platform(),
                 "slots": dsv.config.slots,
                 "active": self.decode.active_count(),
                 "queued": self.decode.queue_depth(),
@@ -549,8 +551,10 @@ class ServeServer:
         if cur_name is not None:
             # a SWAP replaces the DEFAULT model's version chain — same
             # name, next version — not a new co-hosted model (add_model
-            # is the multi-model admission path)
+            # is the multi-model admission path) — on the same device
+            # (the handler thread has no default context of its own)
             kw["name"] = cur_name
+            kw["ctx"] = self.host.active().ctx
         sv = Servable.from_checkpoint(prefix, epoch=epoch,
                                      input_names=input_names,
                                      version=new_version, **kw)

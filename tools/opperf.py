@@ -83,8 +83,6 @@ def main():
     ap.add_argument("-o", "--output", default=None)
     args = ap.parse_args()
 
-    from mxnet_tpu.base import ensure_live_backend
-    backend = ensure_live_backend()
     import jax
     import test_operator as batt  # tests/ on sys.path
 
@@ -101,7 +99,7 @@ def main():
     overhead = [r["dispatch_overhead_us"] for r in ok
                 if "dispatch_overhead_us" in r]
     summary = {
-        "device": jax.default_backend() if backend != "cpu" else "cpu",
+        "device": jax.default_backend(),
         "num_ops": len(ok),
         "num_errors": len(results) - len(ok),
         "median_eager_us": round(sorted(
