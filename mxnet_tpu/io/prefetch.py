@@ -50,6 +50,7 @@ import jax
 
 from ..base import MXNetError, get_env
 from ..ndarray.ndarray import NDArray
+from .. import profiler as _profiler
 from .. import telemetry as _telemetry
 
 __all__ = ["DevicePrefetcher", "prefetch_enabled", "prefetch_depth"]
@@ -171,7 +172,8 @@ class DevicePrefetcher:
         if self._closed:
             raise MXNetError("DevicePrefetcher is closed")
         t0 = self._clock()
-        with self._cv:
+        # the wait is also `mx.data_wait` on jax's profiler clock
+        with _profiler.host_span("data_wait"), self._cv:
             while not self._q:
                 if self._stop.is_set() or not self._thread.is_alive():
                     # producer died without a sentinel (interpreter

@@ -485,7 +485,9 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def attention_core(q, k, v, scale=None, causal=False, mask=None):
     """Dispatch: Pallas flash on TPU for aligned mask-free shapes, jnp
-    composition otherwise.  q,k,v: (B, H, T, D)."""
+    composition otherwise.  q,k,v: (B, H, T, D).  Both paths trace under
+    the scope ``attention_core``, so a device trace names the attention
+    whatever implements it."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     Tq, Tk, D = q.shape[2], k.shape[2], q.shape[3]
@@ -498,17 +500,18 @@ def attention_core(q, k, v, scale=None, causal=False, mask=None):
         use_flash = aligned          # CPU interprets; TPU lowers via Mosaic
     else:
         use_flash = _on_tpu() and aligned
-    if use_flash:
-        return flash_attention(q, k, v, float(scale), bool(causal))
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    if causal:
-        cm = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
-        logits = jnp.where(cm, logits, -jnp.inf)
-    if mask is not None:
-        logits = jnp.where(mask.astype(bool), logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    with jax.named_scope("attention_core"):
+        if use_flash:
+            return flash_attention(q, k, v, float(scale), bool(causal))
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        if causal:
+            cm = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
+            logits = jnp.where(cm, logits, -jnp.inf)
+        if mask is not None:
+            logits = jnp.where(mask.astype(bool), logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
 # ---------------------------------------------------------------------------

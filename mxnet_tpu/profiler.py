@@ -23,7 +23,9 @@ the reference's ``NaiveEngine`` profiling mode.
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import sys
 import threading
 import time
 from collections import defaultdict
@@ -34,7 +36,7 @@ from .base import get_env
 __all__ = [
     "set_config", "set_state", "state", "start", "stop", "pause", "resume",
     "dump", "dumps", "dump_profile", "Domain", "Task", "Frame", "Event",
-    "Counter", "Marker", "scope", "annotate",
+    "Counter", "Marker", "scope", "host_span",
 ]
 
 # module-level fast flags read by the dispatch hot loop -----------------------
@@ -312,27 +314,21 @@ class scope:
         return self._named.__exit__(*exc)
 
 
-class _NullSpan:
-    """Free when the profiler is stopped (annotate's fast path)."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
-def annotate(name: str):
-    """Phase range for the steady-state training step (allreduce / update /
-    metric): a full scope() — host span + jax.named_scope so the fused
-    blocks show as single ranges in a device trace — when the profiler is
-    running, and a shared no-op context otherwise, so the fit hot loop
-    pays one global read per phase."""
-    return scope(name) if RUNNING else _NULL_SPAN
+def host_span(name: str, step_num: Optional[int] = None):
+    """The ONE hook that puts the program's own host spans on jax's
+    profiler clock: a ``jax.profiler.TraceAnnotation("mx." + name)`` (a
+    ``StepTraceAnnotation`` when `step_num` is given), which a running
+    ``jax.profiler`` session records on the host plane beside the device
+    trace.  Outside a session it is a C++ check and an object.  A process
+    that never imported jax (the numpy-only kvstore server) gets a no-op:
+    this module must not be what imports it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    if step_num is not None:
+        return jax.profiler.StepTraceAnnotation("mx." + name,
+                                                step_num=step_num)
+    return jax.profiler.TraceAnnotation("mx." + name)
 
 
 # -- output -------------------------------------------------------------------

@@ -60,6 +60,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import re
 import threading
 import time
 import weakref
@@ -73,7 +74,7 @@ from . import telemetry as _telemetry
 __all__ = [
     "register_program", "Program", "ProgramRecord", "census_enabled",
     "program_table", "program_summary", "program_memory_bytes",
-    "find_record", "reset_records",
+    "find_record", "reset_records", "module_name", "program_scopes",
     "signature_of", "diff_signatures",
     "track_buffers", "buffer_census", "leak_detector", "LeakDetector",
     "CENSUS_OWNERS",
@@ -297,6 +298,9 @@ class ProgramRecord:
         self.temp_bytes_peak: Optional[int] = None
         self.last_sig: Optional[Tuple] = None
         self.last_retrace: Optional[Dict[str, Any]] = None
+        # newest AOT executable (alive in Program._cache anyway): what
+        # program_scopes() reads, on demand only
+        self.executable = None
         labels = {"program": name}
         reg = _telemetry.registry
         self._h_compile = reg.histogram(
@@ -365,6 +369,8 @@ class ProgramRecord:
                                              "compile_seconds": seconds}
             self._seen_sigs.add(sig)
             self.last_sig = sig
+            if compiled is not None:
+                self.executable = compiled
             self._absorb_metadata_locked(mem, cost)
         self._h_compile.observe(seconds)
         self._publish_metadata_gauges(mem, cost)
@@ -391,6 +397,8 @@ class ProgramRecord:
             self.deserialize_seconds_total += seconds
             self._seen_sigs.add(sig)
             self.last_sig = sig
+            if compiled is not None:
+                self.executable = compiled
             self._absorb_metadata_locked(mem, cost)
         self._publish_metadata_gauges(mem, cost)
 
@@ -509,6 +517,152 @@ def reset_records() -> None:
 
 
 # ---------------------------------------------------------------------------
+# Module names and op scopes: what a device trace is joined to
+# ---------------------------------------------------------------------------
+
+def module_name(name: str) -> str:
+    """The name a program's jitted function carries, so that its XLA
+    module (jax prefixes ``jit_``) is called what the census calls it:
+    ``step.step`` -> ``mx_step_step`` (module ``jit_mx_step_step``)."""
+    return "mx_" + re.sub(r"[^0-9A-Za-z]+", "_", name).strip("_")
+
+
+# the step program's top-level scopes (step.py); an op under `forward`
+# whose path passes through transpose(...) belongs to the backward pass
+TOP_SCOPES = ("forward", "backward", "exchange", "optimizer", "metric")
+
+_HLO_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(ROOT\s+)?%?([^\s=]+)\s*=\s*.*?\s([a-z][a-z0-9\-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLED = re.compile(
+    r"(?:calls|body|condition|to_apply|true_computation|"
+    r"false_computation)=%?([^\s,)}]+)")
+_HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_HLO_NO_WORK = frozenset(("constant", "parameter", "tuple",
+                          "get-tuple-element", "bitcast", "broadcast",
+                          "iota", "reshape"))
+
+
+def top_scope(path: str) -> Optional[str]:
+    """Which of :data:`TOP_SCOPES` an ``op_name`` path lies under, or
+    None: the first component that is no ``jit(...)`` wrapper and no
+    loop of a scan window, with jax's transform wrappers
+    (``jvp(forward)``) taken off."""
+    for part in path.split("/"):
+        if not part or part.startswith(("jit(", "pjit(")) \
+                or part in ("while", "body", "cond"):   # a scan window's loop
+            continue
+        base = part[part.rfind("(") + 1:].rstrip(")") if "(" in part \
+            else part
+        if base == "forward":
+            return "backward" if "transpose(" in path else "forward"
+        return base if base in TOP_SCOPES else None
+    return None
+
+
+def _parse_scopes(text: str) -> Dict[str, Any]:
+    """:func:`program_scopes` of one compiled module's HLO text."""
+    module = text.split(None, 2)[1].rstrip(",") \
+        if text.startswith("HloModule") else None
+    computations: Dict[str, List[Tuple]] = {}
+    entry = current = None
+    for line in text.splitlines():
+        if current is None:
+            m = _HLO_COMPUTATION.match(line)
+            if m:
+                current = computations.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        op_name = _HLO_OP_NAME.search(line)
+        called = _HLO_CALLED.findall(line)
+        branches = _HLO_BRANCHES.search(line)
+        if branches:
+            called += [b.strip().lstrip("%")
+                       for b in branches.group(1).split(",")]
+        current.append((m.group(2), m.group(3), bool(m.group(1)),
+                        op_name.group(1) if op_name else "", called))
+    instructions: Dict[str, Dict[str, Any]] = {}
+    seen = set()
+
+    def tops_of(computation):
+        """Top-level scopes of the work a fused computation holds (XLA
+        shares one constant between scopes: such an instruction's name
+        says nothing about the fusion)."""
+        found = set()
+        for _n, op, _root, path, called in computations.get(
+                computation, ()):
+            if op in _HLO_NO_WORK:
+                continue
+            top = top_scope(path)
+            if top:
+                found.add(top)
+            for c in called:
+                found |= tops_of(c)
+        return found
+
+    def walk(computation):
+        if computation in seen:
+            return
+        seen.add(computation)
+        for name, op, _root, path, called in computations.get(
+                computation, ()):
+            tops = set()
+            if op == "fusion":
+                for c in called:
+                    root = [i for i in computations.get(c, ()) if i[2]]
+                    if root and root[0][3]:
+                        path = root[0][3]
+                    tops |= tops_of(c)
+            else:
+                for c in called:       # while / conditional / call bodies
+                    walk(c)
+            top = top_scope(path)
+            # backward has no scope of its own: a fusion of forward and
+            # transposed ops lies under ONE scope step.py opens, `forward`
+            opened = {"forward" if t == "backward" else t for t in tops}
+            instructions[name] = {"scope": path, "top": top,
+                                  "tops": sorted(tops | ({top} - {None})),
+                                  "mixed": len(opened) > 1}
+
+    if entry is not None:
+        walk(entry)
+    return {"module": module, "instructions": instructions}
+
+
+def program_scopes(name: str) -> Optional[Dict[str, Any]]:
+    """Where each instruction of program `name`'s newest executable comes
+    from, parsed from its compiled text ON DEMAND (never at compile
+    time); None when the program has no AOT executable::
+
+        {"module": "<hlo module name>",
+         "instructions": {"<instr>": {"scope": "<op_name path>",
+                                      "top": one of TOP_SCOPES or None,
+                                      "tops": [...], "mixed": bool}}}
+
+    Every instruction of the entry computation and of the ``while`` /
+    ``conditional`` / ``call`` bodies it reaches.  A fusion takes the
+    ``op_name`` of its root; ``tops`` lists the top-level scopes its
+    fused instructions fall under and ``mixed`` says they are under more
+    than one of the scopes step.py opens (``backward`` is no scope of its
+    own: it is what ``forward`` becomes under ``transpose(``).  This is what a device trace's op rows are joined to by
+    instruction name: it needs nothing of what the trace itself
+    carries."""
+    rec = find_record(name)
+    compiled = rec.executable if rec is not None else None
+    if compiled is None:
+        return None
+    return _parse_scopes(compiled.as_text())
+
+
+# ---------------------------------------------------------------------------
 # The wrapper
 # ---------------------------------------------------------------------------
 
@@ -544,6 +698,11 @@ class Program:
             return fn(*a, **k)
 
         functools.update_wrapper(_trace_probe, fn, updated=())
+        # the XLA module is called what the census calls the program
+        # (jax prefixes "jit_"): trace "XLA Modules" events, HLO dumps
+        # and jax's persistent-cache entries all carry the registry name
+        _trace_probe.__name__ = _trace_probe.__qualname__ = \
+            module_name(name)
         self._jit = jax.jit(_trace_probe, **jit_kw)
         self._jit_kw = dict(jit_kw)
         self._aot = aot

@@ -51,6 +51,8 @@ from .base import MXNetError, get_env
 from .device import cpu
 from .ndarray.ndarray import NDArray
 from . import autograd
+from . import profiler as _profiler
+from . import telemetry as _telemetry
 from .ops import random as _ops_random
 from .ops.optimizer import tree_body
 from .gluon.block import _flatten_nds
@@ -453,8 +455,11 @@ class CompiledStep:
 
         def forward_backward(t_vals, f_vals, rng, x_vals, y_val):
             def loss_of(tv):
-                losses, outs, new_f = run_forward(tv, f_vals, rng,
-                                                  x_vals, y_val)
+                # backward needs no scope of its own: value_and_grad
+                # names the transposed ops transpose(jvp(forward))/...
+                with jax.named_scope("forward"):
+                    losses, outs, new_f = run_forward(tv, f_vals, rng,
+                                                      x_vals, y_val)
                 # backward() seeds a ones cotangent on the loss: the
                 # gradient of the elementwise SUM is exactly that
                 total = losses[0].sum()
@@ -549,7 +554,8 @@ class CompiledStep:
                     key, x_mb, y_mb = minp
                     loss0, out0, grads, new_f = forward_backward(
                         t_use, f_v, key, x_mb, y_mb)
-                    mst = accumulate_metric(mst, loss0, out0, y_mb)
+                    with jax.named_scope("metric"):
+                        mst = accumulate_metric(mst, loss0, out0, y_mb)
                     g_acc = tuple(a + g for a, g in zip(g_acc, grads))
                     return (new_f, g_acc, mst), (loss0, out0)
 
@@ -567,22 +573,26 @@ class CompiledStep:
                     mcarry, (losses, outs) = lax.scan(
                         micro, init, (rngs, x_row, y_row))
                 f_vals, g_sum, mstate = mcarry
-                if shardings is not None:
-                    # the reduce-scatter point (ISSUE 14): the gradient
-                    # sum over the data×fsdp-sharded batch lands directly
-                    # on each parameter's shards — GSPMD fuses the cross-
-                    # chip sum and the scatter into one collective, and
-                    # the updated params all-gather just in time at the
-                    # next forward's use sites
-                    g_sum = tuple(lax.with_sharding_constraint(g, s)
-                                  for g, s in zip(g_sum, shardings))
-                if exchange is not None:
-                    new_g, new_res = exchange(list(g_sum),
-                                              list(residuals))
-                    g_sum = tuple(new_g)
-                    residuals = tuple(new_res)
-                t_vals, opt_states, w32s = apply_optimizer(
-                    t_vals, g_sum, opt_states, w32s, lr_row, decay_row)
+                with jax.named_scope("exchange"):
+                    if shardings is not None:
+                        # the reduce-scatter point (ISSUE 14): the
+                        # gradient sum over the data×fsdp-sharded batch
+                        # lands directly on each parameter's shards —
+                        # GSPMD fuses the cross-chip sum and the scatter
+                        # into one collective, and the updated params
+                        # all-gather just in time at the next forward's
+                        # use sites
+                        g_sum = tuple(lax.with_sharding_constraint(g, s)
+                                      for g, s in zip(g_sum, shardings))
+                    if exchange is not None:
+                        new_g, new_res = exchange(list(g_sum),
+                                                  list(residuals))
+                        g_sum = tuple(new_g)
+                        residuals = tuple(new_res)
+                with jax.named_scope("optimizer"):
+                    t_vals, opt_states, w32s = apply_optimizer(
+                        t_vals, g_sum, opt_states, w32s, lr_row,
+                        decay_row)
                 out_row = (losses, outs) if return_outs else losses
                 return (t_vals, f_vals, opt_states, w32s, residuals,
                         mstate), out_row
@@ -757,110 +767,134 @@ class CompiledStep:
             self._metric._dev_sum, self._metric._dev_inst = new_mstate
 
     # -- dispatch ----------------------------------------------------------
-    def _run(self, plan, n_steps, accum, xs, ys, batch_size, transfers):
+    def _run(self, n_steps, accum, xs, ys, batch_size, transfers):
         """One window dispatch: xs/ys leaves shaped (n_steps*accum, B,
-        ...).  Returns (losses, outs_or_None) as jax arrays."""
+        ...).  Returns (ctx, losses, outs_or_None) with jax arrays, or
+        None when there is no plan (the caller steps eagerly).
+
+        The host's part of a step is three phases, each an ``mx.*`` span
+        on jax's profiler clock (docs/ARCHITECTURE.md, span taxonomy):
+        ``step.prepare``, the dispatch, ``step.write_back``."""
         from .engine import engine as _engine
-        from . import telemetry as _telemetry
-        rescale, wds, lr_rows, decay_rows = self._lr_rows(
-            plan, n_steps, batch_size)
-        metric_info = metric_trace_kernel(self._metric)
-        return_outs = self._metric is not None and metric_info is None
-        layout = plan.get("layout")
-        key = (n_steps, accum, rescale, wds, plan["clip"],
-               None if layout is None else layout.signature(),
-               plan["spec"]["kind"],
-               tuple(sorted(plan["spec"]["static"].items())),
-               plan["mp_flags"],
-               tuple((tuple(x.shape), str(x.dtype)) for x in xs),
-               (tuple(ys.shape), str(ys.dtype)),
-               tuple((p.shape, str(p.dtype)) for p in plan["trainable"]),
-               tuple((p.shape, str(p.dtype)) for p in plan["frozen"]),
-               tuple((wk, tuple(s), str(jnp.dtype(dt))) for wk, s, dt in
-                     (plan["exchange"].residual_specs
-                      if plan["exchange"] is not None else ())),
-               metric_cache_key(self._metric, metric_info),
-               return_outs)
-        fn = self._cache.get(key)
-        if fn is None:
-            # profiler blind spot fix (ISSUE 8): a retrace is the
-            # expensive rare event that used to hide inside the first
-            # dispatch — it gets its own phase span so hybridize-style
-            # recompiles are visible in dumps() and the flight recorder
-            with _telemetry.phase("retrace"):
-                fn = self._build_fn(plan, n_steps, accum, rescale, wds,
-                                    decay_rows is not None, metric_info,
-                                    return_outs)
-                self._cache[key] = fn
-        state = self._gather_state(plan)
+        with _telemetry.phase("step.prepare"):
+            try:
+                plan = self._plan()
+            except DeferredInitializationError:
+                plan = None   # first call finishes deferred init eagerly
+            if plan is None:
+                return None
+            rescale, wds, lr_rows, decay_rows = self._lr_rows(
+                plan, n_steps, batch_size)
+            metric_info = metric_trace_kernel(self._metric)
+            return_outs = self._metric is not None and metric_info is None
+            layout = plan.get("layout")
+            key = (n_steps, accum, rescale, wds, plan["clip"],
+                   None if layout is None else layout.signature(),
+                   plan["spec"]["kind"],
+                   tuple(sorted(plan["spec"]["static"].items())),
+                   plan["mp_flags"],
+                   tuple((tuple(x.shape), str(x.dtype)) for x in xs),
+                   (tuple(ys.shape), str(ys.dtype)),
+                   tuple((p.shape, str(p.dtype))
+                         for p in plan["trainable"]),
+                   tuple((p.shape, str(p.dtype)) for p in plan["frozen"]),
+                   tuple((wk, tuple(s), str(jnp.dtype(dt)))
+                         for wk, s, dt in
+                         (plan["exchange"].residual_specs
+                          if plan["exchange"] is not None else ())),
+                   metric_cache_key(self._metric, metric_info),
+                   return_outs)
+            fn = self._cache.get(key)
+            if fn is None:
+                # profiler blind spot fix (ISSUE 8): a retrace is the
+                # expensive rare event that used to hide inside the first
+                # dispatch — it gets its own phase span so hybridize-style
+                # recompiles are visible in dumps() and the flight recorder
+                with _telemetry.phase("retrace", annotation="step.retrace"):
+                    fn = self._build_fn(plan, n_steps, accum, rescale, wds,
+                                        decay_rows is not None,
+                                        metric_info, return_outs)
+                    self._cache[key] = fn
+            state = self._gather_state(plan)
 
-        def donatable(a):
-            if a is None or id(a) in self._owned:
-                return a
-            return jnp.array(a, copy=True)   # foreign: may be aliased
+            def donatable(a):
+                if a is None or id(a) in self._owned:
+                    return a
+                return jnp.array(a, copy=True)   # foreign: may be aliased
 
-        state = tuple(jax.tree_util.tree_map(donatable, s) for s in state)
-        rng = _ops_random.next_key()
-        if layout is not None:
-            # the batch crosses to the mesh sharded over data×fsdp (axis
-            # 0 of each micro-batch; axis 1 of stacked window leaves) —
-            # the ONE transfer the dispatch budget charges.  rng is a
-            # committed single-device jit output: replicate it onto the
-            # mesh or the dispatch mixes incompatible device sets.
-            bdim = 0 if n_steps * accum == 1 else 1
-            xs = tuple(jax.device_put(
-                x, layout.sharding(layout.batch_spec_for(x.shape, bdim)))
-                for x in xs)
-            ys = jax.device_put(
-                ys, layout.sharding(layout.batch_spec_for(ys.shape, bdim)))
-            rng = jax.device_put(rng, plan["replicated"])
-            transfers = max(transfers, 1)
+            state = tuple(jax.tree_util.tree_map(donatable, s)
+                          for s in state)
+            rng = _ops_random.next_key()
+            if layout is not None:
+                # the batch crosses to the mesh sharded over data×fsdp
+                # (axis 0 of each micro-batch; axis 1 of stacked window
+                # leaves) — the ONE transfer the dispatch budget charges.
+                # rng is a committed single-device jit output: replicate
+                # it onto the mesh or the dispatch mixes incompatible
+                # device sets.
+                bdim = 0 if n_steps * accum == 1 else 1
+                xs = tuple(jax.device_put(
+                    x, layout.sharding(layout.batch_spec_for(x.shape,
+                                                             bdim)))
+                    for x in xs)
+                ys = jax.device_put(
+                    ys, layout.sharding(layout.batch_spec_for(ys.shape,
+                                                              bdim)))
+                rng = jax.device_put(rng, plan["replicated"])
+                transfers = max(transfers, 1)
         # distinct span names so scan windows and single compiled steps
         # aggregate separately in profiler.dumps() (the eager-only
         # blind spot this satellite closes)
         span_name = "compiled_step" if n_steps * accum == 1 \
             else "compiled_window"
-        with _telemetry.phase(span_name):
+        with _telemetry.phase(span_name, annotation="step.dispatch"):
             out = fn(*state, lr_rows, decay_rows, rng, xs, ys)
-        (new_t, new_f, new_states, new_w32, new_res, new_mstate,
-         losses, outs) = out
-        self._write_back(plan, new_t, new_f, new_states, new_w32,
-                         new_res, new_mstate)
-        self._owned_refs = [
-            a for a in jax.tree_util.tree_leaves(
-                (new_t, new_f, new_states, new_w32, new_res, new_mstate))
-            if a is not None]
-        self._owned = {id(a) for a in self._owned_refs}
-        _engine.count_step_window(n_steps * accum,
-                                  dispatches=1 + transfers)
-        if plan["exchange"] is not None:
-            _engine.count_wire_bytes(
-                plan["exchange"].wire_bytes * n_steps)
-        _telemetry.note_step(steps=n_steps * accum, batch_size=batch_size,
-                             extra={"compiled": True})
-        return losses, outs
+        with _telemetry.phase("step.write_back"):
+            (new_t, new_f, new_states, new_w32, new_res, new_mstate,
+             losses, outs) = out
+            self._write_back(plan, new_t, new_f, new_states, new_w32,
+                             new_res, new_mstate)
+            self._owned_refs = [
+                a for a in jax.tree_util.tree_leaves(
+                    (new_t, new_f, new_states, new_w32, new_res,
+                     new_mstate))
+                if a is not None]
+            self._owned = {id(a) for a in self._owned_refs}
+            _engine.count_step_window(n_steps * accum,
+                                      dispatches=1 + transfers)
+            if plan["exchange"] is not None:
+                _engine.count_wire_bytes(
+                    plan["exchange"].wire_bytes * n_steps)
+            _telemetry.note_step(steps=n_steps * accum,
+                                 batch_size=batch_size,
+                                 extra={"compiled": True})
+        return plan["ctxs"][0], losses, outs
+
+    def _step_span(self):
+        """``mx.step``: the whole of one step() / run_window() call, a
+        step annotation numbered by the trainer's update count.  No phase
+        of its own: the step record (note_step) has the step's time, and
+        its parts are the phases."""
+        return _profiler.host_span(
+            "step", step_num=self._trainer._optimizer.num_update)
 
     def step(self, data, label, batch_size=None):
         """One training step (forward + backward + exchange + update +
         metric) in ONE dispatch; returns the loss (eager shape)."""
-        datas = data if isinstance(data, (list, tuple)) else (data,)
-        B = int(_as_jax(datas[0]).shape[0])
-        batch_size = batch_size or B
-        try:
-            plan = self._plan()
-        except DeferredInitializationError:
-            plan = None   # first call finishes deferred init eagerly
-        if plan is None:
-            return self._eager_step(datas, label, batch_size)
-        ctx0 = plan["ctxs"][0]
-        xs = tuple(_as_jax(d) for d in datas)
-        y = _as_jax(label)
-        losses, outs = self._run(plan, 1, 1, xs, y, batch_size,
-                                 transfers=0)
-        if outs is not None:
-            self._metric.update([_as_nd(y, ctx0)],
-                                [NDArray(outs[0], ctx=ctx0)])
-        return NDArray(losses.reshape(losses.shape[1:]), ctx=ctx0)
+        with self._step_span():
+            datas = data if isinstance(data, (list, tuple)) else (data,)
+            B = int(_as_jax(datas[0]).shape[0])
+            batch_size = batch_size or B
+            xs = tuple(_as_jax(d) for d in datas)
+            y = _as_jax(label)
+            ran = self._run(1, 1, xs, y, batch_size, transfers=0)
+            if ran is None:
+                return self._eager_step(datas, label, batch_size)
+            ctx0, losses, outs = ran
+            if outs is not None:
+                self._metric.update([_as_nd(y, ctx0)],
+                                    [NDArray(outs[0], ctx=ctx0)])
+            return NDArray(losses.reshape(losses.shape[1:]), ctx=ctx0)
 
     def run_window(self, data, label, batch_size=None, accum=1):
         """N-step scan window: `data` leaves are (n_micro, B, ...) with
@@ -879,33 +913,29 @@ class CompiledStep:
         n_steps = n_micro // accum
         B = int(xs[0].shape[1])
         batch_size = batch_size or B * accum
-        try:
-            plan = self._plan()
-        except DeferredInitializationError:
-            plan = None
-        if plan is None:
-            if accum > 1:
-                raise MXNetError(
-                    "run_window(accum=%d) has no eager fallback (%s); use "
-                    "grad_req='add' accumulation on the eager path"
-                    % (accum, self._fallback_reason))
-            losses = [self._eager_step(
-                tuple(NDArray(x[t], ctx=self._trainer._contexts[0])
-                      for x in xs),
-                NDArray(y[t], ctx=self._trainer._contexts[0]),
-                batch_size).mean()._jax
-                for t in range(n_micro)]
-            return NDArray(jnp.stack(losses),
-                           ctx=self._trainer._contexts[0])
-        ctx0 = plan["ctxs"][0]
-        losses, outs = self._run(plan, n_steps, accum, xs, y, batch_size,
-                                 transfers=1)
-        if outs is not None:
-            flat = outs.reshape((-1,) + outs.shape[2:])
-            self._metric.update(
-                [NDArray(y.reshape((-1,) + y.shape[2:]), ctx=ctx0)],
-                [NDArray(flat, ctx=ctx0)])
-        return NDArray(losses, ctx=ctx0)
+        with self._step_span():
+            ran = self._run(n_steps, accum, xs, y, batch_size, transfers=1)
+            if ran is None:
+                if accum > 1:
+                    raise MXNetError(
+                        "run_window(accum=%d) has no eager fallback (%s); "
+                        "use grad_req='add' accumulation on the eager path"
+                        % (accum, self._fallback_reason))
+                losses = [self._eager_step(
+                    tuple(NDArray(x[t], ctx=self._trainer._contexts[0])
+                          for x in xs),
+                    NDArray(y[t], ctx=self._trainer._contexts[0]),
+                    batch_size).mean()._jax
+                    for t in range(n_micro)]
+                return NDArray(jnp.stack(losses),
+                               ctx=self._trainer._contexts[0])
+            ctx0, losses, outs = ran
+            if outs is not None:
+                flat = outs.reshape((-1,) + outs.shape[2:])
+                self._metric.update(
+                    [NDArray(y.reshape((-1,) + y.shape[2:]), ctx=ctx0)],
+                    [NDArray(flat, ctx=ctx0)])
+            return NDArray(losses, ctx=ctx0)
 
     # -- the debug path ----------------------------------------------------
     def _eager_step(self, datas, label, batch_size):

@@ -24,18 +24,23 @@ scale — arxiv 1605.08695, PAPERS.md):
   step (taxonomy: ``data_wait`` / ``forward`` / ``backward`` /
   ``exchange`` / ``optimizer_apply`` / ``metric_update`` /
   ``metric_drain`` / ``retrace`` / ``compiled_step`` /
-  ``compiled_window``, plus the serving engine's request phases
+  ``compiled_window``, the compiled step's host parts
+  ``step.prepare`` / ``step.write_back``, plus the serving engine's
+  request phases
   ``queue_wait`` / ``pad`` / ``serve_dispatch`` / ``scatter`` —
   ISSUE 9 — and the decode engine's ``prefill`` / ``decode_step`` /
   ``kv_evict`` — ISSUE 15, with per-token latency in the
   ``serve.decode.token_seconds`` histogram).  A span measures
   *dispatch* latency — it never
   syncs the device (the host-sync mxlint rule roots this file's
-  helpers) — and feeds three sinks: the per-phase histogram
+  helpers) — and feeds four sinks: the per-phase histogram
   (``step_phase_seconds{phase=...}``), the existing profiler
-  chrome-trace (via :func:`mxnet_tpu.profiler.annotate`, so phases and
-  compiled-step dispatches land in ``profiler.dumps()`` aggregates),
-  and the distributed trace buffer below.
+  chrome-trace (via :func:`mxnet_tpu.profiler.record_span`, so phases
+  and compiled-step dispatches land in ``profiler.dumps()``
+  aggregates), the distributed trace buffer below, and — as ``mx.*``
+  host spans through :func:`mxnet_tpu.profiler.host_span` — any running
+  ``jax.profiler`` session, on the clock of its device trace (the
+  names: docs/ARCHITECTURE.md, span taxonomy).
 
 * **Distributed trace context** — :func:`rpc_span` spans carry
   (trace_id, span_id, parent_id); the kvstore client attaches the
@@ -70,7 +75,6 @@ import json
 import os
 import threading
 import time
-import uuid
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -329,9 +333,34 @@ registry = Registry()
 # Enablement
 # ---------------------------------------------------------------------------
 
+def _env_flag(name: str, cache: list, read) -> bool:
+    # HOT PATH (several reads per phase span): one raw os.environ read,
+    # cached by VALUE as engine.is_naive does - set_env/environment()
+    # keep os.environ in sync, monkeypatch.setenv writes it - so
+    # get_env's lock, override dict and parsing (`read`) run only when
+    # the variable changes
+    val = os.environ.get(name)  # mxlint: disable=env-var-registry
+    if val != cache[0]:
+        cache[0] = val
+        cache[1] = bool(read())
+    return cache[1]
+
+
+_enabled_cache = [0, True]          # [raw value (0: never read), flag]
+_trace_dir_cache = [0, False]
+
+
+def _read_enabled():
+    return get_env("MX_TELEMETRY", dtype=bool)
+
+
+def _read_trace_dir():
+    return get_env("MX_TELEMETRY_TRACE", "")
+
+
 def enabled() -> bool:
     """MX_TELEMETRY (default on): phase histograms + step records."""
-    return bool(get_env("MX_TELEMETRY", dtype=bool))
+    return _env_flag("MX_TELEMETRY", _enabled_cache, _read_enabled)
 
 
 _trace_lock = threading.Lock()
@@ -344,10 +373,9 @@ _atexit_armed = [False]
 def tracing_enabled() -> bool:
     """Span buffering is on: ``start_tracing()`` held, or
     ``MX_TELEMETRY_TRACE`` names a directory to flush into at exit."""
-    with _trace_lock:
-        if _trace_forced[0]:
-            return True
-    return bool(get_env("MX_TELEMETRY_TRACE", ""))
+    # one list read: no lock needed to see start_tracing()'s hold
+    return bool(_trace_forced[0]) or \
+        _env_flag("MX_TELEMETRY_TRACE", _trace_dir_cache, _read_trace_dir)
 
 
 def start_tracing() -> None:
@@ -407,7 +435,7 @@ _tls = _TLS()
 
 
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return os.urandom(8).hex()     # 16 hex digits, a fifth of uuid4's cost
 
 
 def current_trace() -> Tuple[Optional[str], Optional[str]]:
@@ -425,24 +453,32 @@ class Span:
     creates) ``trace_id``/``parent_id`` from the thread's span stack;
     exiting buffers a chrome-trace ``X`` event (when tracing is on) and,
     while the profiler runs, a profiler span so the range lands in
-    ``profiler.dumps()``.  :meth:`event` adds instant child events
-    (retries, replays).  Measures dispatch latency only — it must never
-    touch device buffers (hot-path lint roots this class)."""
+    ``profiler.dumps()``.  The whole range is also a
+    ``mx.<annotation>`` host span on jax's profiler clock
+    (:func:`mxnet_tpu.profiler.host_span`), so a ``jax.profiler`` trace
+    shows it beside the device's ops.  :meth:`event` adds instant child
+    events (retries, replays).  Measures dispatch latency only — it must
+    never touch device buffers (hot-path lint roots this class)."""
 
     __slots__ = ("name", "cat", "trace_id", "span_id", "parent_id",
+                 "annotation", "_ann",
                  "_t0", "_wall0", "_prof_ts", "_events")
 
     def __init__(self, name: str, cat: str = "span",
                  trace_id: Optional[str] = None,
-                 parent_id: Optional[str] = None):
+                 parent_id: Optional[str] = None,
+                 annotation: Optional[str] = None):
         self.name = name
         self.cat = cat
         self.trace_id = trace_id
         self.span_id: Optional[str] = None
         self.parent_id = parent_id
+        self.annotation = annotation or name
         self._events: List[dict] = []
 
     def __enter__(self) -> "Span":
+        self._ann = _profiler.host_span(self.annotation)
+        self._ann.__enter__()
         cur_trace, cur_span = current_trace()
         if self.trace_id is None:
             self.trace_id = cur_trace or _new_id()
@@ -497,6 +533,7 @@ class Span:
         elif self in stack:            # unbalanced exit: drop through it
             stack.remove(self)
         self._close(dur)
+        self._ann.__exit__(None, None, None)
         return False
 
 
@@ -504,7 +541,7 @@ class _PhaseSpan(Span):
     """A :class:`Span` that also accumulates into the per-phase
     histogram and this thread's current step record."""
 
-    __slots__ = ()
+    __slots__ = ("_pname", "_on")       # set by phase()
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0
@@ -514,16 +551,21 @@ class _PhaseSpan(Span):
         elif self in stack:
             stack.remove(self)
         self._close(dur)
-        # a same-name phase still open on the stack means this was a
-        # nested re-entry (Module.forward_backward wrapping a backward
-        # that wraps autograd.backward): the outer span owns the
-        # accounting — accumulating both would double the phase
-        if enabled() and not any(isinstance(s, _PhaseSpan) and
-                                 s.name == self.name for s in stack):
-            pname = self.name[len("phase."):] \
-                if self.name.startswith("phase.") else self.name
-            _phase_hist(pname).observe(dur)
-            _tls.phases[pname] = _tls.phases.get(pname, 0.0) + dur
+        if self._on:
+            # a same-name phase still open on the stack means this was a
+            # nested re-entry (Module.forward_backward wrapping a backward
+            # that wraps autograd.backward): the outer span owns the
+            # accounting — accumulating both would double the phase
+            name = self.name
+            for s in stack:
+                if s.name == name and isinstance(s, _PhaseSpan):
+                    break
+            else:
+                pname = self._pname
+                _phase_hist(pname).observe(dur)
+                phases = _tls.phases
+                phases[pname] = phases.get(pname, 0.0) + dur
+        self._ann.__exit__(None, None, None)
         return False
 
 
@@ -554,8 +596,7 @@ _phase_hists: Dict[str, Histogram] = {}
 
 
 def _phase_hist(name: str) -> Histogram:
-    with _phase_hist_lock:
-        h = _phase_hists.get(name)
+    h = _phase_hists.get(name)      # one dict read; the lock is for writers
     if h is None:
         h = registry.histogram("step_phase_seconds",
                                doc="training-step phase durations "
@@ -567,15 +608,22 @@ def _phase_hist(name: str) -> Histogram:
     return h
 
 
-def phase(name: str):
+def phase(name: str, annotation: Optional[str] = None):
     """One training-step phase span (``data_wait`` / ``forward`` / ...).
 
     Dispatch-time semantics only: the span brackets host work and async
-    XLA dispatches, never a device sync.  Returns a shared no-op when
-    telemetry, tracing and the profiler are all off."""
-    if not (_profiler.RUNNING or enabled() or tracing_enabled()):
+    XLA dispatches, never a device sync.  On jax's profiler clock the
+    span is called ``mx.<annotation>`` (default: ``mx.<name>``).  Returns
+    a shared no-op when telemetry, tracing and the profiler are all
+    off."""
+    on = enabled()
+    if not (_profiler.RUNNING or on or tracing_enabled()):
         return _NULL_SPAN
-    return _PhaseSpan("phase." + name, cat="phase")
+    span = _PhaseSpan("phase." + name, cat="phase",
+                      annotation=annotation or name)
+    span._pname = name
+    span._on = on
+    return span
 
 
 def observe_phase(name: str, seconds: float) -> None:

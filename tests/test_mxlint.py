@@ -1111,10 +1111,10 @@ def test_reinjected_asnumpy_in_compiled_step_host_path_trips():
     p = os.path.join(REPO, "mxnet_tpu", "step.py")
     with open(p) as f:
         code = f.read()
-    anchor = "        state = self._gather_state(plan)"
+    anchor = "            state = self._gather_state(plan)"
     assert anchor in code, "CompiledStep._run moved; update this test"
     bad = code.replace(
-        anchor, anchor + "\n        _dbg = state[0][0].asnumpy()", 1)
+        anchor, anchor + "\n            _dbg = state[0][0].asnumpy()", 1)
     diags = lint_source(bad, "mxnet_tpu/step.py")
     assert "host-sync-in-hot-path" in rules_of(diags)
     new, _, _ = apply_baseline(diags, load_baseline(BASELINE))
