@@ -40,7 +40,10 @@ SIZES = {
     "train_bert_base": dict(layers=12, units=768, heads=12, vocab=30522,
                             batch=16, seq=512, lr=0.5),
     "eager": dict(rows=256, cols=8, hidden=32),
-    "pallas": dict(shape=(2, 8, 2048, 128)),
+    # long causal at head size 128; BERT-base's own: 32 rows a chip of
+    # 512 tokens, 12 heads of 64, no mask
+    "pallas": dict(cases=(((2, 8, 2048, 128), True),
+                          ((32, 12, 512, 64), False))),
     "serve_resnet50": dict(model="resnet50_v1", image=224, classes=1000,
                            rows=(1, 3, 8, 2, 16, 5, 4, 1)),
     "serve_decode": dict(prompts=((3, 1, 4, 1, 5), (9, 2, 6),
@@ -256,14 +259,15 @@ def _mlm_loss():
 
 
 def _attention_impl(heads, units, seq):
-    """Which implementation ops.attention.attention_core picks for this
-    model's (B, H, T, D): the Pallas flash kernel or the jnp composition
+    """Which implementation ops.attention.attention_heads picks for this
+    model's (B, T, H*D): the Pallas flash kernels or the jnp composition
     XLA compiles.  Read off the lowering, not off the rule."""
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.ops.attention import attention_core
-    q = jax.ShapeDtypeStruct((2, heads, seq, units // heads), jnp.bfloat16)
-    text = jax.jit(attention_core).lower(q, q, q).as_text()
+    from mxnet_tpu.ops.attention import attention_heads
+    q = jax.ShapeDtypeStruct((2, seq, units), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v: attention_heads(q, k, v, heads)) \
+        .lower(q, q, q).as_text()
     return "flash" if "tpu_custom_call" in text else "xla"
 
 
@@ -345,56 +349,61 @@ def eager(rows, cols, hidden, platform=PLATFORM, seed=0):
                 [p.data()._jax for p in net.collect_params().values()])}
 
 
-def pallas(shape, platform=PLATFORM, seed=0):
-    """ops.attention.attention_core with the flash path forced, bf16
-    causal, forward and gradient, against _attention_jnp on the same
-    inputs.  On the TPU the lowered text must hold the Mosaic kernel
-    (tpu_custom_call); anywhere else Pallas interprets and it must not."""
+def pallas(cases, platform=PLATFORM, seed=0):
+    """ops.attention.attention_core with the flash path forced, bf16,
+    forward and gradient, against _attention_jnp on the same inputs, for
+    each (shape, causal) of `cases`.  On the TPU the lowered text must
+    hold the Mosaic kernels (tpu_custom_call); anywhere else Pallas
+    interprets and it must not."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from mxnet_tpu.ops import attention as att
     rng = np.random.RandomState(seed)
-    q, k, v = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
-               for _ in range(3))
-    scale = 1.0 / shape[-1] ** 0.5
-
-    def flash(q, k, v):
-        with att.attention_impl_scope("pallas"):
-            return att.attention_core(q, k, v, causal=True)
-
-    def ref(q, k, v):
-        return att._attention_jnp(q, k, v, scale, True)
-
-    def grads(f):
-        return jax.jit(jax.grad(
-            lambda q, k, v: f(q, k, v).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2)))
-
-    fwd_text = jax.jit(flash).lower(q, k, v).as_text()
-    bwd_text = grads(flash).lower(q, k, v).as_text()
-    calls = {"forward": fwd_text.count("tpu_custom_call"),
-             "backward": bwd_text.count("tpu_custom_call")}
-    want = platform == "tpu"
-    if (calls["forward"] > 0) != want or (calls["backward"] > 0) != want:
-        raise AssertionError("tpu_custom_call counts %r on %r"
-                             % (calls, platform))
-    out, want_out = jax.jit(flash)(q, k, v), jax.jit(ref)(q, k, v)
-    g, want_g = grads(flash)(q, k, v), grads(ref)(q, k, v)
 
     def rel(a, b):
         a, b = (np.asarray(x, np.float32) for x in (a, b))
         return float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-6))
 
-    # bf16 has 8 mantissa bits (2^-8 = 3.9e-3); the kernel and the
-    # reference round at different points of a 2048-term softmax sum
-    errs = {"out": rel(out, want_out),
-            "dq": rel(g[0], want_g[0]), "dk": rel(g[1], want_g[1]),
-            "dv": rel(g[2], want_g[2])}
-    if not all(e == e and e < 5e-2 for e in errs.values()):
-        raise AssertionError("flash vs jnp relative errors %r" % errs)
-    return {"tpu_custom_calls": calls, "max_rel_err": errs,
-            "shape": list(shape)}
+    def one(shape, causal):
+        q, k, v = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                   for _ in range(3))
+        scale = 1.0 / shape[-1] ** 0.5
+
+        def flash(q, k, v):
+            with att.attention_impl_scope("pallas"):
+                return att.attention_core(q, k, v, causal=causal)
+
+        def ref(q, k, v):
+            return att._attention_jnp(q, k, v, scale, causal)
+
+        def grads(f):
+            return jax.jit(jax.grad(
+                lambda q, k, v: f(q, k, v).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2)))
+
+        fwd_text = jax.jit(flash).lower(q, k, v).as_text()
+        bwd_text = grads(flash).lower(q, k, v).as_text()
+        calls = {"forward": fwd_text.count("tpu_custom_call"),
+                 "backward": bwd_text.count("tpu_custom_call")}
+        want = platform == "tpu"
+        if (calls["forward"] > 0) != want or (calls["backward"] > 0) != want:
+            raise AssertionError("tpu_custom_call counts %r on %r"
+                                 % (calls, platform))
+        out, want_out = jax.jit(flash)(q, k, v), jax.jit(ref)(q, k, v)
+        g, want_g = grads(flash)(q, k, v), grads(ref)(q, k, v)
+        # bf16 has 8 mantissa bits (2^-8 = 3.9e-3); the kernel and the
+        # reference round at different points of a 2048-term softmax sum
+        errs = {"out": rel(out, want_out),
+                "dq": rel(g[0], want_g[0]), "dk": rel(g[1], want_g[1]),
+                "dv": rel(g[2], want_g[2])}
+        if not all(e == e and e < 5e-2 for e in errs.values()):
+            raise AssertionError("flash vs jnp relative errors %r at %r"
+                                 % (errs, shape))
+        return {"tpu_custom_calls": calls, "max_rel_err": errs,
+                "shape": list(shape), "causal": causal}
+
+    return {"cases": [one(tuple(shape), causal) for shape, causal in cases]}
 
 
 def _free_port():

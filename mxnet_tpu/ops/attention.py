@@ -5,45 +5,75 @@ Reference: src/operator/contrib/transformer.cc
 selfatt_valatt, _contrib_interleaved_matmul_encdec_qk/valatt) — the fused
 attention matmuls GluonNLP's BERT uses.
 
-TPU-native: the hot path is a blockwise online-softmax (flash) attention
-kernel in Pallas (SURVEY.md §2.1 cuDNN row: "attention → Pallas flash
-attention").  Blocks stream K/V through VMEM with running (max, sum)
-accumulators so the T×T score matrix never materializes in HBM; the MXU
-does the two matmuls per block.  Backward recomputes attention from the
-saved inputs (rematerialization — trade FLOPs for HBM, SURVEY.md design
-notes).  Non-TPU backends and unaligned shapes fall back to the jnp
-composition, which XLA fuses well at moderate sequence length.
+TPU-native: unmasked attention runs blockwise flash kernels in Pallas,
+forward and backward, so the T×T scores never reach HBM.  What the code
+does, in the order a call meets it:
+
+* **The rule** (`flash_rule`, stated once; `use_flash` adds the backend
+  and the `set_attention_impl` / `attention_impl_scope` override): no
+  mask, `Tq` and `Tk` multiples of the 256-row unit, head size 64 or a
+  multiple of 128, bf16 or float32, and `Tq == Tk` when causal.  Anything
+  else takes the jnp composition, which XLA fuses.
+* **Two layouts, one set of kernels.**  `attention_core` takes
+  `(B, H, T, D)`: one head a block.  `attention_heads` takes the
+  `(B, T, H·D)` tensors a projection produces (what
+  `multi_head_attention` holds) and reads 128-lane blocks straight from
+  them — two 64-wide heads a block, told apart by lane masks, so every
+  tile is lane-dense and no transpose surrounds the call.  (On the v5e a
+  `(B, H, T, 64)` operand is padded to 128 lanes in HBM and costs its four
+  transposes: one BERT-base layer took 1.94 ms that way against 1.21.)
+* **bf16 into the MXU**: operands stay in their own dtype, products
+  accumulate in float32 (`preferred_element_type`), probabilities are
+  cast to the input dtype before the second product — the precision of
+  the composition.  Softmax statistics are float32, kept one value a lane
+  (`(B, H, 1, T)`: a trailing dim of 8 is padded to 128 lanes in HBM).
+* **Blocks from the shapes** (`_Geometry.blocks`): a non-causal call takes
+  512-row blocks where they divide and up to 1024 keys as one block (no
+  online rescaling); longer or causal calls stream key blocks with
+  running (max, sum).
+* **Backward** recomputes the probabilities from the saved logsumexp
+  (FlashAttention-2) on transposed scores, keys down the sublanes, so
+  that `p.T @ dO` and `ds.T @ q` are plain products.  Where one key block
+  holds the sequence (non-causal, T ≤ 512) one kernel yields dq, dk and
+  dv from one pass over the scores; otherwise a second kernel over query
+  blocks makes dq.
+* **Under a mesh** the kernels are opaque to GSPMD (jax refuses to lower
+  a Mosaic kernel it would have to partition), so `CompiledStep` opens
+  `attention_partition_scope(layout)` around its forward trace and the
+  call is wrapped in `shard_map` over the batch (data×fsdp) and head (tp)
+  axes: each chip runs its own rows.  (`custom_partitioning` is not
+  available: libtpu has no emitter for `CustomSPMDPartitioning`.)
 """
 from __future__ import annotations
 
 import functools
-import threading
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from .registry import register
 
-__all__ = ["attention_core", "flash_attention", "cached_attention",
+__all__ = ["attention_core", "attention_heads", "flash_attention",
+           "flash_rule", "use_flash", "cached_attention",
            "cached_attention_multi", "paged_attention",
            "paged_attention_multi"]
 
-# kernel block sizes: 256x256 keeps the fp32 accumulators + two operand
-# tiles comfortably inside v5e VMEM; overridable via env so a chip run
-# can sweep candidates without code edits
+# kernel block sizes.  256 rows is the unit: the rule asks for multiples of
+# it, causal calls use it (a larger block wastes more of its masked half),
+# and the env can still sweep it on a chip.  A non-causal call takes the
+# blocks the shapes allow (`_Geometry.blocks`): on the v5e 512-row blocks
+# beat 256 (one BERT-base layer, forward + backward: 1.77 against 2.53 ms,
+# PERF.md section 6, PR 27) and one key block of up to 1024 rows beats a
+# loop with online rescaling (2.53 against 3.46 ms).
 from ..base import get_env
 
 _BLOCK_Q = get_env("MX_FLASH_BLOCK_Q", 256, int)
 _BLOCK_K = get_env("MX_FLASH_BLOCK_K", 256, int)
 
-# Mosaic requires the last two dims of every block to be (8k, 128k) or
-# equal to the full array dims — a rank-2 (BH, T) residual with a
-# squeezed-BH block violates that.  The LSE therefore rides with a small
-# trailing lane dim (all lanes duplicate the value); 8 = one sublane's
-# width, and 8 == the full array dim satisfies the lowering rule while
-# costing 8x (not 128x) the compact residual's HBM.
-_LSE_LANES = 8
+_LANES = 128            # a vector register's lanes: the packed block width
 
 
 def _on_tpu() -> bool:
@@ -61,15 +91,20 @@ def _interpret() -> bool:
 
 # Lowering config (reference role: optimize_for(backend) /
 # MXNET_SUBGRAPH_BACKEND): None = heuristic dispatch, "pallas" = force the
-# flash kernel wherever alignment permits (any backend; CPU interprets),
+# flash kernel wherever the rule permits (any backend; CPU interprets),
 # "xla" = force the jnp composition.  Two levels:
 #   * process-wide default via set_attention_impl (MXNET_SUBGRAPH_BACKEND
 #     role);
 #   * a thread-local SCOPE (attention_impl_scope) that the subgraph
 #     backend-property registry pushes around one block's trace, so
 #     per-block optimize_for never leaks into other blocks.
+# The scopes are jax user contexts: part of the key of jax's trace caches,
+# so an op's own jitted program traced inside a scope is never handed to
+# a trace outside it, nor the reverse.
 _FORCED_IMPL = None
-_IMPL_TLS = threading.local()
+_UNSET = "unset"
+_IMPL_SCOPE = jax.make_user_context(_UNSET)
+_LAYOUT_SCOPE = jax.make_user_context(None)
 
 
 def set_attention_impl(impl):
@@ -82,53 +117,90 @@ def set_attention_impl(impl):
 
 
 def current_attention_impl():
-    stack = getattr(_IMPL_TLS, "stack", None)
-    if stack:
-        return stack[-1]
-    return _FORCED_IMPL
+    scoped = _IMPL_SCOPE.value
+    return _FORCED_IMPL if scoped == _UNSET else scoped
 
 
-class attention_impl_scope:
+class _Scope:
+    """`with` over one value of a jax user context (re-entrant)."""
+
+    def __init__(self, context, value):
+        self._context, self._value, self._entered = context, value, []
+
+    def __enter__(self):
+        entered = self._context(self._value)
+        entered.__enter__()
+        self._entered.append(entered)
+        return self
+
+    def __exit__(self, *exc):
+        self._entered.pop().__exit__(*exc)
+        return False
+
+
+class attention_impl_scope(_Scope):
     """Scoped override: the innermost scope wins over the global."""
 
     def __init__(self, impl):
         if impl not in (None, "pallas", "xla"):
             raise ValueError("attention impl must be None, 'pallas' or "
                              "'xla'")
-        self._impl = impl
+        super().__init__(_IMPL_SCOPE, impl)
 
-    def __enter__(self):
-        if not hasattr(_IMPL_TLS, "stack"):
-            _IMPL_TLS.stack = []
-        _IMPL_TLS.stack.append(self._impl)
-        return self
 
-    def __exit__(self, *exc):
-        _IMPL_TLS.stack.pop()
+class attention_partition_scope(_Scope):
+    """Trace-time note of the mesh layout a program is sharded by
+    (`parallel.SpecLayout`, or None): inside it a flash call runs under
+    `shard_map` over the layout's batch and head axes.  `CompiledStep`
+    opens it around the forward trace; it is no user-facing switch."""
+
+    def __init__(self, layout):
+        super().__init__(_LAYOUT_SCOPE, layout)
+
+
+def flash_rule(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16):
+    """THE dispatch rule: may this call run the flash kernels?  A
+    function of what the call shows and of nothing else."""
+    return (mask is None and Tq % _BLOCK_Q == 0 and Tk % _BLOCK_K == 0
+            and (D == 64 or D % _LANES == 0)
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
+                                     jnp.dtype(jnp.float32))
+            and (not causal or Tq == Tk))
+
+
+def use_flash(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16):
+    """`flash_rule` under the configured implementation: "xla" never,
+    "pallas" wherever the rule holds (the CPU interprets), otherwise on a
+    TPU only."""
+    impl = current_attention_impl()
+    if impl == "xla":
         return False
+    return flash_rule(Tq, Tk, D, causal, mask, dtype) and \
+        (impl == "pallas" or _on_tpu())
 
 
 # ---------------------------------------------------------------------------
-# jnp reference path (always-correct fallback; also the recompute backward)
+# jnp reference path (always-correct fallback)
 # ---------------------------------------------------------------------------
 
 
-def _attention_jnp(q, k, v, scale, causal):
+def _attention_jnp(q, k, v, scale, causal, mask=None):
     """q,k,v: (B, H, T, D)."""
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
         Tq, Tk = q.shape[2], k.shape[2]
-        mask = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
-        logits = jnp.where(mask, logits, -jnp.inf)
+        cm = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
+        logits = jnp.where(cm, logits, -jnp.inf)
+    if mask is not None:
+        logits = jnp.where(mask.astype(bool), logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash kernel (forward)
+# How the kernels see their operands
 # ---------------------------------------------------------------------------
-
 
 
 def _sds(shape, dtype, like):
@@ -144,269 +216,386 @@ def _sds(shape, dtype, like):
         pass
     return jax.ShapeDtypeStruct(shape, dtype)
 
+
+class _Geometry:
+    """Block specs of one call.  ``heads=None``: q/k/v are (B, H, T, D)
+    and a block holds one head.  ``heads=H``: they are (B, T, H*D) and a
+    block is max(D, 128) lanes wide — `per_block` heads side by side.
+    Either way a kernel sees 2-D (rows, width) tiles and per-row
+    statistics (logsumexp, the backward's delta) as (per_block, 1, rows)
+    of a (B, H, 1, T) float32 array - one value a LANE, so HBM holds them
+    compactly (a trailing dim of 1 or 8 would be padded to 128 lanes:
+    100 MB a BERT-base layer where 0.8 MB do); the grid is
+    (B, H // per_block, row blocks)."""
+
+    def __init__(self, q, k, heads):
+        self.packed = heads is not None
+        if self.packed:
+            self.B, self.Tq, hd = q.shape
+            self.H, self.D = heads, hd // heads
+            self.width = max(self.D, _LANES)
+        else:
+            self.B, self.H, self.Tq, self.D = q.shape
+            self.width = self.D
+        self.Tk = k.shape[1] if self.packed else k.shape[2]
+        self.per_block = self.width // self.D
+        if self.H % self.per_block:
+            raise ValueError("%d heads of size %d do not fill %d-lane "
+                             "blocks" % (self.H, self.D, self.width))
+
+    def grid(self, rows, block):
+        return (self.B, self.H // self.per_block, rows // block)
+
+    def shape(self, rows):
+        """Shape of a q-like array with `rows` positions."""
+        return (self.B, rows, self.H * self.D) if self.packed \
+            else (self.B, self.H, rows, self.D)
+
+    def tile(self, rows, blocked):
+        """A (rows, width) tile: the grid's row block when `blocked`,
+        else the whole sequence (resident across the row blocks)."""
+        import jax.experimental.pallas as pl
+        if self.packed:
+            return pl.BlockSpec(
+                (None, rows, self.width),
+                (lambda b, h, i: (b, i, h)) if blocked
+                else (lambda b, h, i: (b, 0, h)))
+        return pl.BlockSpec(
+            (None, None, rows, self.D),
+            (lambda b, h, i: (b, h, i, 0)) if blocked
+            else (lambda b, h, i: (b, h, 0, 0)))
+
+    def stats(self, rows, blocked):
+        """(per_block, 1, rows) statistics: of the grid's row block when
+        `blocked`, else of the whole sequence."""
+        import jax.experimental.pallas as pl
+        return pl.BlockSpec(
+            (None, self.per_block, 1, rows),
+            (lambda b, h, i: (b, h, 0, i)) if blocked
+            else (lambda b, h, i: (b, h, 0, 0)))
+
+    def blocks(self, causal):
+        """(query rows, key rows of the forward and dq kernels, key rows
+        of the dk/dv kernel) a block.  Causal: the unit.  Otherwise
+        twice the unit where it divides, and the forward takes up to
+        four units of keys as ONE block: no online rescaling (at T = 512
+        a head's whole K and V are 64 KB each)."""
+        if causal:
+            return _BLOCK_Q, _BLOCK_K, _BLOCK_K
+        bq = 2 * _BLOCK_Q if self.Tq % (2 * _BLOCK_Q) == 0 else _BLOCK_Q
+        bk = 2 * _BLOCK_K if self.Tk % (2 * _BLOCK_K) == 0 else _BLOCK_K
+        return bq, (self.Tk if self.Tk <= 4 * _BLOCK_K else bk), bk
+
+
+def _head_masks(per_block, d):
+    """One lane mask a head of a packed block ([None] for one head)."""
+    if per_block == 1:
+        return [None]
+    lane = lax.broadcasted_iota(jnp.int32, (1, per_block * d), 1)
+    return [(lane >= g * d) & (lane < (g + 1) * d)
+            for g in range(per_block)]
+
+
+def _only(x, mask):
+    """`x` with the lanes of the block's other heads zeroed: a product
+    contracting over the lanes then sees this head alone, at the cost of
+    the full-depth pass a 64-deep product takes anyway."""
+    return x if mask is None else jnp.where(mask, x, jnp.zeros_like(x))
+
+
+def _merge(out, part, mask):
+    """Take this head's lanes of `part` into `out`."""
+    return part if out is None or mask is None \
+        else jnp.where(mask, part, out)
+
+
+def _to_row(col):
+    """(rows, 1) -> (1, rows): through a 128-lane transpose, the shape
+    Mosaic's transpose unit takes."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
+
+
+def _to_col(row):
+    """(1, rows) -> (rows, 1)."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a.T @ b: contracts the rows of both."""
+    return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """a @ b.T without a transposed operand."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _exact_scale(scale):
+    """True when scaling by `scale` is exact in any float dtype (a power
+    of two: 1/8 at head size 64), so it can be folded into q."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _mask_causal(s, q0, k0, q_axis):
+    """Scores with key positions after the query's set to -inf; queries
+    run along `q_axis` of `s` from `q0`, keys along the other from `k0`."""
+    q_pos = q0 + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(q_pos >= k_pos, s, -jnp.inf)
+
+
+def _rows(start_block, block):
+    import jax.experimental.pallas as pl
+    return pl.ds(pl.multiple_of(start_block * block, block), block)
+
+
+def _loop(lo, hi, body, init):
+    """fori_loop, or the body once where the trip count is one."""
+    if isinstance(lo, int) and isinstance(hi, int) and hi - lo == 1:
+        return body(lo, init)
+    return lax.fori_loop(lo, hi, body, init)
+
+
+# ---------------------------------------------------------------------------
+# Pallas flash kernel (forward)
+# ---------------------------------------------------------------------------
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                      block_k, seq_k):
-    # refs: q (block_q, D), k/v (seq_k, D), o (block_q, D),
-    # lse (block_q, _LSE_LANES) — lanes duplicate the value; grid=(BH, Tq/bq)
+                      block_k, d):
+    # refs: q/o (block_q, width), k/v (seq_k, width),
+    # lse (per_block, 1, block_q); grid = (B, H // per_block, Tq // block_q)
     import jax.experimental.pallas as pl
 
-    block_q, d = q_ref.shape
-    q = q_ref[:].astype(jnp.float32) * scale
-    q_idx = pl.program_id(1)
-
-    m = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    acc = jnp.zeros((block_q, d), jnp.float32)
-
-    num_kb = seq_k // block_k
-
-    def body(kb, carry):
-        m, l, acc = carry
-        k_blk = k_ref[pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            q_pos = q_idx * block_q + \
-                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + \
-                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # guard fully-masked rows: exp(-inf - -inf) would be nan
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        alpha = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = alpha * acc + jnp.dot(p, v_blk,
-                                        preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
-
+    block_q, width = q_ref.shape
+    num_kb = k_ref.shape[0] // block_k
+    q_idx = pl.program_id(2)
+    fold = _exact_scale(scale)
+    q_all = q_ref[:] * scale if fold else q_ref[:]
     if causal:
         # only key blocks at or before this query block contribute
-        # (ceil-div: correct for any block_q/block_k ratio)
-        num_kb_eff = ((q_idx + 1) * block_q + block_k - 1) // block_k
-        num_kb_eff = jnp.minimum(num_kb_eff, num_kb)
-        m, l, acc = lax.fori_loop(0, num_kb_eff, body, (m, l, acc))
-    else:
-        m, l, acc = lax.fori_loop(0, num_kb, body, (m, l, acc))
+        # (ceil-div: correct for any block_q/block_k ratio).  The first
+        # block holds key 0, which every row sees: the running max is
+        # finite from then on and no row is ever fully masked.
+        num_kb = jnp.minimum(
+            ((q_idx + 1) * block_q + block_k - 1) // block_k, num_kb)
+    out = None
+    for g, mask in enumerate(_head_masks(width // d, d)):
+        q = _only(q_all, mask)
 
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    # logsumexp residual for the flash backward: lse = m + log(l)
-    # (softmax prob recomputes as exp(s - lse)); -inf for fully-masked rows
-    lse = jnp.where(l > 0,
-                    jnp.where(jnp.isfinite(m), m, 0.0)
-                    + jnp.log(jnp.maximum(l, 1e-30)),
-                    -jnp.inf)
-    lse_ref[:] = jnp.broadcast_to(lse, (block_q, _LSE_LANES))
+        def body(kb, carry, q=q):
+            m, l, acc = carry
+            rows = _rows(kb, block_k)
+            s = _dot_nt(q, k_ref[rows, :])
+            if not fold:
+                s = s * scale
+            if causal:
+                s = _mask_causal(s, q_idx * block_q, kb * block_k, 0)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            acc_new = alpha * acc + _dot(p.astype(v_ref.dtype),
+                                         v_ref[rows, :])
+            return m_new, l_new, acc_new
+
+        m, l, acc = _loop(
+            0, num_kb, body,
+            (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32),
+             jnp.zeros((block_q, width), jnp.float32)))
+        out = _merge(out, acc / l, mask)
+        # logsumexp residual for the backward: p = exp(s - lse)
+        lse_ref[g] = _to_row(m + jnp.log(l))
+    o_ref[:] = out.astype(o_ref.dtype)
 
 
-def _flash_fwd_res(q, k, v, scale, causal, block_q=_BLOCK_Q,
-                   block_k=_BLOCK_K):
-    """q,k,v: (B, H, T, D) with T % block == 0.  Returns (out, lse_lanes)
-    with lse_lanes (B*H, Tq, _LSE_LANES) fp32 — the laned residual the
-    backward kernels consume directly (no rebroadcast on the bwd path)."""
+def _flash_fwd_res(q, k, v, scale, causal, heads=None):
+    """(out, lse): out shaped like q, lse (B, H, 1, Tq) float32 — the
+    residual the backward consumes."""
     import jax.experimental.pallas as pl
 
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
+    geo = _Geometry(q, k, heads)
+    block_q, block_k, _ = geo.blocks(causal)
     kernel = functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
-                               block_k=block_k, seq_k=Tk)
-    out, lse_lanes = pl.pallas_call(
+                               block_k=block_k, d=geo.D)
+    return pl.pallas_call(
         kernel,
         interpret=_interpret(),
-        grid=(B * H, Tq // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, _LSE_LANES), lambda b, i: (b, i, 0)),
-        ],
+        grid=geo.grid(geo.Tq, block_q),
+        in_specs=[geo.tile(block_q, True), geo.tile(geo.Tk, False),
+                  geo.tile(geo.Tk, False)],
+        out_specs=[geo.tile(block_q, True), geo.stats(block_q, True)],
         out_shape=[
-            _sds((B * H, Tq, D), q.dtype, qr),
-            _sds((B * H, Tq, _LSE_LANES), jnp.float32, qr),
+            _sds(geo.shape(geo.Tq), q.dtype, q),
+            _sds((geo.B, geo.H, 1, geo.Tq), jnp.float32, q),
         ],
-    )(qr, kr, vr)
-    return out.reshape(B, H, Tq, D), lse_lanes
+    )(q, k, v)
 
 
-def _lse_from_lanes(lse_lanes, B, H, Tq):
-    """(B*H, Tq, _LSE_LANES) laned residual -> public (B, H, Tq)."""
-    return lse_lanes[:, :, 0].reshape(B, H, Tq)
-
-
-def _flash_fwd(q, k, v, scale, causal, block_q=_BLOCK_Q, block_k=_BLOCK_K):
-    """Public-shape wrapper: returns (out, lse) with lse (B, H, Tq)."""
-    B, H, Tq, _ = q.shape
-    out, lse_lanes = _flash_fwd_res(q, k, v, scale, causal, block_q, block_k)
-    return out, _lse_from_lanes(lse_lanes, B, H, Tq)
+def _flash_fwd(q, k, v, scale, causal, heads=None):
+    """(out, lse) with lse (B, H, Tq)."""
+    out, lse = _flash_fwd_res(q, k, v, scale, causal, heads)
+    return out, lse[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
 # Pallas flash backward (FlashAttention-2 recompute-from-LSE formulation):
-# O(L) memory — the T×T score matrix is never materialized.  Two kernels:
-# dq iterates q-blocks (streaming K/V), dk/dv iterates k-blocks (streaming
-# Q/dO).  delta = rowsum(dO * O) is the softmax-jacobian correction term.
+# O(L) memory — the T×T score matrix is never materialized.  The kernel
+# over key blocks works on TRANSPOSED scores (keys down the sublanes), so
+# dv = p.T @ dO and dk = ds.T @ q are plain products and the per-query
+# statistics broadcast as rows.  Where one key block holds the whole
+# sequence it yields dq as well (one product with a transposed operand):
+# scores and probabilities are computed once, 5 products where the two
+# kernels make 7.  Longer sequences add the kernel over query blocks for
+# dq.  delta = rowsum(dO * O), the softmax-jacobian correction term, is one
+# small XLA pass outside.
 # ---------------------------------------------------------------------------
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                         dq_ref, *, scale, causal, block_k, seq_k):
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, *, scale, causal, block_k, d):
     import jax.experimental.pallas as pl
 
-    block_q, d = q_ref.shape
-    q = q_ref[:].astype(jnp.float32)
-    do = do_ref[:].astype(jnp.float32)
-    # lanes all duplicate the value; a lane-reduce recovers (block_q, 1)
-    lse = jnp.max(lse_ref[:], axis=-1, keepdims=True)
-    # softmax-jacobian row term, computed in-kernel (saves a (BH, T)
-    # residual array + its laned rebroadcast)
-    delta = jnp.sum(do * o_ref[:].astype(jnp.float32), axis=-1,
-                    keepdims=True)
-    q_idx = pl.program_id(1)
-    lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-
-    num_kb = seq_k // block_k
-
-    def body(kb, dq):
-        k_blk = k_ref[pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.dslice(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = q_idx * block_q + \
-                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + \
-                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s) & jnp.isfinite(lse),
-                      jnp.exp(s - lse_safe), 0.0)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jnp.dot(ds, k_blk, preferred_element_type=jnp.float32)
-
-    dq = jnp.zeros((block_q, d), jnp.float32)
+    block_q, width = q_ref.shape
+    num_kb = k_ref.shape[0] // block_k
+    q_idx = pl.program_id(2)
+    fold = _exact_scale(scale)
+    q_all = q_ref[:] * scale if fold else q_ref[:]
+    do_all = do_ref[:]
     if causal:
-        num_kb_eff = jnp.minimum(
+        num_kb = jnp.minimum(
             ((q_idx + 1) * block_q + block_k - 1) // block_k, num_kb)
-        dq = lax.fori_loop(0, num_kb_eff, body, dq)
-    else:
-        dq = lax.fori_loop(0, num_kb, body, dq)
-    dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
+    out = None
+    for g, mask in enumerate(_head_masks(width // d, d)):
+        q, do = _only(q_all, mask), _only(do_all, mask)
+        lse, delta = _to_col(lse_ref[g]), _to_col(delta_ref[g])
+
+        def body(kb, dq, q=q, do=do, lse=lse, delta=delta):
+            rows = _rows(kb, block_k)
+            k_blk = k_ref[rows, :]
+            s = _dot_nt(q, k_blk)
+            if not fold:
+                s = s * scale
+            if causal:
+                s = _mask_causal(s, q_idx * block_q, kb * block_k, 0)
+            p = jnp.exp(s - lse)
+            dp = _dot_nt(do, v_ref[rows, :])
+            ds = (p * (dp - delta)).astype(k_ref.dtype)
+            return dq + _dot(ds, k_blk)
+
+        dq = _loop(0, num_kb, body,
+                   jnp.zeros((block_q, width), jnp.float32))
+        out = _merge(out, dq * scale, mask)
+    dq_ref[:] = out.astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                          dk_ref, dv_ref, *, scale, causal, block_q, seq_q):
+def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dq_ref=None, *, scale, causal,
+                          block_q, d):
+    # lse/delta: (per_block, 1, seq_q); the scores here are (block_k,
+    # block_q).  With dq_ref the block holds every key: dq (seq_q, width)
+    # comes from the same scores.
     import jax.experimental.pallas as pl
 
-    block_k, d = k_ref.shape
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
-    k_idx = pl.program_id(1)
+    block_k, width = k_ref.shape
+    num_qb = q_ref.shape[0] // block_q
+    k_idx = pl.program_id(2)
+    fold = _exact_scale(scale)
+    k_all = k_ref[:] * scale if fold else k_ref[:]
+    v_all = v_ref[:]
+    # causal: only query blocks at or after this key block contribute
+    qb_start = (k_idx * block_k) // block_q if causal else 0
+    dk_out = dv_out = None
+    for g, mask in enumerate(_head_masks(width // d, d)):
+        k, v = _only(k_all, mask), _only(v_all, mask)
 
-    num_qb = seq_q // block_q
+        def body(qb, carry, k=k, v=v, g=g, mask=mask):
+            dk, dv = carry
+            rows = _rows(qb, block_q)
+            q_blk, do_blk = q_ref[rows, :], do_ref[rows, :]
+            s = _dot_nt(k, q_blk)
+            if not fold:
+                s = s * scale
+            if causal:
+                s = _mask_causal(s, qb * block_q, k_idx * block_k, 1)
+            p = jnp.exp(s - lse_ref[g, :, rows])
+            dv_new = dv + _dot(p.astype(do_blk.dtype), do_blk)
+            dp = _dot_nt(v, do_blk)
+            ds = (p * (dp - delta_ref[g, :, rows])).astype(q_blk.dtype)
+            if dq_ref is not None:
+                # k carries the folded scale already; else scale here
+                dq = _dot_tn(ds, k) if fold else _dot_tn(ds, k) * scale
+                dq_ref[rows, :] = _merge(
+                    None if g == 0 else dq_ref[rows, :],
+                    dq.astype(dq_ref.dtype), mask)
+            return dk + _dot(ds, q_blk), dv_new
 
-    def body(qb, carry):
-        dk, dv = carry
-        q_blk = q_ref[pl.dslice(qb * block_q, block_q), :].astype(jnp.float32)
-        do_blk = do_ref[pl.dslice(qb * block_q, block_q), :].astype(
-            jnp.float32)
-        lse = jnp.max(lse_ref[pl.dslice(qb * block_q, block_q), :],
-                      axis=-1, keepdims=True)
-        delta = jnp.sum(
-            do_blk * o_ref[pl.dslice(qb * block_q, block_q), :].astype(
-                jnp.float32), axis=-1, keepdims=True)
-        lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-        s = jnp.dot(q_blk, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qb * block_q + \
-                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = k_idx * block_k + \
-                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s) & jnp.isfinite(lse),
-                      jnp.exp(s - lse_safe), 0.0)
-        dv_new = dv + jnp.dot(p.T, do_blk,
-                              preferred_element_type=jnp.float32)
-        dp = jnp.dot(do_blk, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_new = dk + jnp.dot(ds.T, q_blk,
-                              preferred_element_type=jnp.float32)
-        return dk_new, dv_new
-
-    dk = jnp.zeros((block_k, d), jnp.float32)
-    dv = jnp.zeros((block_k, d), jnp.float32)
-    if causal:
-        # only query blocks at or after this key block contribute
-        qb_start = (k_idx * block_k) // block_q
-        dk, dv = lax.fori_loop(qb_start, num_qb, body, (dk, dv))
-    else:
-        dk, dv = lax.fori_loop(0, num_qb, body, (dk, dv))
-    dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+        dk, dv = _loop(qb_start, num_qb, body,
+                       (jnp.zeros((block_k, width), jnp.float32),
+                        jnp.zeros((block_k, width), jnp.float32)))
+        dk_out = _merge(dk_out, dk * scale, mask)
+        dv_out = _merge(dv_out, dv, mask)
+    dk_ref[:] = dk_out.astype(dk_ref.dtype)
+    dv_ref[:] = dv_out.astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse_lanes, g, scale, causal,
-               block_q=_BLOCK_Q, block_k=_BLOCK_K):
-    """lse_lanes: (B*H, Tq, _LSE_LANES) fp32 as produced by
-    _flash_fwd_res; delta is recomputed in-kernel from o/do blocks."""
+def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
+    """(dq, dk, dv) from the forward's residuals and the cotangent `g`
+    of its output.  lse: (B, H, 1, Tq) float32."""
     import jax.experimental.pallas as pl
 
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
-    outr = o.reshape(B * H, Tq, D)
-    gr = g.reshape(B * H, Tq, D)
-
+    geo = _Geometry(q, k, heads)
+    block_q, block_k, block_kv = geo.blocks(causal)
+    # delta = rowsum(dO * O): (B, H, 1, Tq) float32, one fused pass
+    prod = g.astype(jnp.float32) * o.astype(jnp.float32)
+    if geo.packed:
+        delta = prod.reshape(geo.B, geo.Tq, geo.H, geo.D).sum(-1) \
+            .transpose(0, 2, 1)
+    else:
+        delta = prod.sum(-1)
+    delta = delta[:, :, None, :]
+    # one key block holds the sequence: its kernel yields dq too
+    fused = not causal and geo.Tk <= 2 * _BLOCK_K
+    if fused:
+        block_kv = geo.Tk
+    key_tile = geo.tile(block_kv, True)
+    whole_q = geo.tile(geo.Tq, False)
+    grads = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
+                          block_q=block_q, d=geo.D),
+        interpret=_interpret(),
+        grid=geo.grid(geo.Tk, block_kv),
+        in_specs=[whole_q, key_tile, key_tile, whole_q,
+                  geo.stats(geo.Tq, False), geo.stats(geo.Tq, False)],
+        out_specs=[key_tile, key_tile] + [whole_q] * fused,
+        out_shape=[_sds(geo.shape(geo.Tk), k.dtype, q),
+                   _sds(geo.shape(geo.Tk), v.dtype, q)]
+        + [_sds(geo.shape(geo.Tq), q.dtype, q)] * fused,
+    )(q, k, v, g, lse, delta)
+    if fused:
+        dk, dv, dq = grads
+        return dq, dk, dv
+    dk, dv = grads
+    q_tile = geo.tile(block_q, True)
+    whole_k = geo.tile(geo.Tk, False)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, seq_k=Tk),
+                          block_k=block_k, d=geo.D),
         interpret=_interpret(),
-        grid=(B * H, Tq // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, _LSE_LANES), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-        out_shape=_sds((B * H, Tq, D), q.dtype, qr),
-    )(qr, kr, vr, outr, gr, lse_lanes)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, seq_q=Tq),
-        interpret=_interpret(),
-        grid=(B * H, Tk // block_k),
-        in_specs=[
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tq, _LSE_LANES), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            _sds((B * H, Tk, D), k.dtype, qr),
-            _sds((B * H, Tk, D), v.dtype, qr),
-        ],
-    )(qr, kr, vr, outr, gr, lse_lanes)
-
-    return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
-            dv.reshape(B, H, Tk, D))
+        grid=geo.grid(geo.Tq, block_q),
+        in_specs=[q_tile, whole_k, whole_k, q_tile,
+                  geo.stats(block_q, True), geo.stats(block_q, True)],
+        out_specs=q_tile,
+        out_shape=_sds(geo.shape(geo.Tq), q.dtype, q),
+    )(q, k, v, g, lse, delta)
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -423,36 +612,31 @@ def flash_attention_with_lse(q, k, v, scale, causal):
 def _flash_lse_vjp_fwd(q, k, v, scale, causal):
     # symbolic_zeros=True wraps primals in CustomVJPPrimal
     q, k, v = (x.value if hasattr(x, "value") else x for x in (q, k, v))
-    B, H, Tq, _ = q.shape
-    out, lse_lanes = _flash_fwd_res(q, k, v, scale, causal)
-    return (out, _lse_from_lanes(lse_lanes, B, H, Tq)), (q, k, v, out,
-                                                         lse_lanes)
+    out, lse = _flash_fwd_res(q, k, v, scale, causal)
+    return (out, lse[:, :, 0]), (q, k, v, out, lse)
 
 
 def _flash_lse_vjp_bwd(scale, causal, res, cts):
     from jax.custom_derivatives import SymbolicZero
     g_out, g_lse = cts
-    q, k, v, o, lse_lanes = res
-    B, H, Tq, _ = q.shape
+    q, k, v, o, lse = res
     if isinstance(g_out, SymbolicZero):
         # out unused downstream: no kernel passes needed for its term
         dq = jnp.zeros(q.shape, q.dtype)
         dk = jnp.zeros(k.shape, k.dtype)
         dv = jnp.zeros(v.shape, v.dtype)
     else:
-        dq, dk, dv = _flash_bwd(q, k, v, o, lse_lanes, g_out, scale,
-                                causal)
+        dq, dk, dv = _flash_bwd(q, k, v, o, lse, g_out, scale, causal)
     if not isinstance(g_lse, SymbolicZero):
         # the lse term costs one extra fwd + one bwd kernel pass — the
         # symbolic-zero gate skips it when only `out` was used downstream
-        lse = _lse_from_lanes(lse_lanes, B, H, Tq)
-        gl = jnp.where(jnp.isfinite(lse), g_lse, 0.0)[..., None]
+        gl = jnp.where(jnp.isfinite(lse[:, :, 0]), g_lse, 0.0)[..., None]
         pk = _flash_fwd(q, k, k.astype(q.dtype), scale, causal)[0]
         dq = (dq.astype(jnp.float32)
               + scale * gl * pk.astype(jnp.float32)).astype(dq.dtype)
         g2 = (gl * q.astype(jnp.float32)).astype(q.dtype)
         _, _, dk2 = _flash_bwd(q, k, jnp.zeros_like(v), jnp.zeros_like(o),
-                               lse_lanes, g2, scale, causal)
+                               lse, g2, scale, causal)
         dk = (dk.astype(jnp.float32)
               + scale * dk2.astype(jnp.float32)).astype(dk.dtype)
     return dq, dk, dv
@@ -462,56 +646,90 @@ flash_attention_with_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd,
                                 symbolic_zeros=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention(q, k, v, scale, causal):
-    """Blockwise flash attention, (B, H, T, D) layout."""
-    return _flash_fwd(q, k, v, scale, causal)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def flash_attention(q, k, v, scale, causal, heads=None):
+    """Blockwise flash attention: (B, H, T, D) operands, or with `heads`
+    the packed (B, T, heads*D) ones.  The output is laid out like q."""
+    return _flash_fwd_res(q, k, v, scale, causal, heads)[0]
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal):
-    out, lse_lanes = _flash_fwd_res(q, k, v, scale, causal)
-    return out, (q, k, v, out, lse_lanes)
+def _flash_vjp_fwd(q, k, v, scale, causal, heads):
+    out, lse = _flash_fwd_res(q, k, v, scale, causal, heads)
+    return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(scale, causal, res, g):
-    # blockwise Pallas backward: O(L) memory (recompute-from-LSE), never
-    # building the T×T score matrix the old jnp rematerialization needed
-    q, k, v, o, lse_lanes = res
-    return _flash_bwd(q, k, v, o, lse_lanes, g, scale, causal)
+def _flash_vjp_bwd(scale, causal, heads, res, g):
+    # the transposed call keeps the forward's name stack, so these
+    # kernels too trace under attention_core
+    q, k, v, o, lse = res
+    return _flash_bwd(q, k, v, o, lse, g, scale, causal, heads)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _partition_spec(layout, shape, heads):
+    """PartitionSpec of q/k/v under `layout`: the batch over data×fsdp
+    where it divides, the heads over tp where whole blocks remain."""
+    spec = layout.batch_spec_for(shape)
+    entries = list(spec) + [None] * (len(shape) - len(spec))
+    axis, unit = (1, 1) if heads is None \
+        else (2, max(shape[2] // heads, _LANES))
+    if layout.tp > 1 and shape[axis] % (layout.tp * unit) == 0:
+        entries[axis] = layout.tp_axis
+    return P(*entries)
+
+
+def _flash(q, k, v, scale, causal, heads=None):
+    """flash_attention — per shard under the layout CompiledStep noted:
+    the kernels are opaque to GSPMD, which would gather their operands
+    and run every row on every chip."""
+    def call(q, k, v, heads=heads):
+        return flash_attention(q, k, v, float(scale), bool(causal), heads)
+
+    layout = _LAYOUT_SCOPE.value
+    spec = P() if layout is None else _partition_spec(layout, q.shape, heads)
+    if all(e is None for e in spec):
+        return call(q, k, v)
+    if heads is not None and spec[2] is not None:
+        call = functools.partial(call, heads=heads // layout.tp)
+    return jax.shard_map(call, mesh=layout.mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def attention_core(q, k, v, scale=None, causal=False, mask=None):
-    """Dispatch: Pallas flash on TPU for aligned mask-free shapes, jnp
-    composition otherwise.  q,k,v: (B, H, T, D).  Both paths trace under
-    the scope ``attention_core``, so a device trace names the attention
-    whatever implements it."""
+    """Dispatch: Pallas flash where `use_flash` says so, jnp composition
+    otherwise.  q,k,v: (B, H, T, D).  Both paths trace under the scope
+    ``attention_core``, so a device trace names the attention whatever
+    implements it."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    Tq, Tk, D = q.shape[2], k.shape[2], q.shape[3]
-    aligned = (mask is None and Tq % _BLOCK_Q == 0 and Tk % _BLOCK_K == 0
-               and D % 128 == 0 and (not causal or Tq == Tk))
-    impl = current_attention_impl()
-    if impl == "xla":
-        use_flash = False
-    elif impl == "pallas":
-        use_flash = aligned          # CPU interprets; TPU lowers via Mosaic
-    else:
-        use_flash = _on_tpu() and aligned
+    flash = use_flash(q.shape[2], k.shape[2], q.shape[3], causal, mask,
+                      q.dtype)
     with jax.named_scope("attention_core"):
-        if use_flash:
-            return flash_attention(q, k, v, float(scale), bool(causal))
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                            preferred_element_type=jnp.float32) * scale
-        if causal:
-            cm = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
-            logits = jnp.where(cm, logits, -jnp.inf)
-        if mask is not None:
-            logits = jnp.where(mask.astype(bool), logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+        if flash:
+            return _flash(q, k, v, scale, causal)
+        return _attention_jnp(q, k, v, scale, causal, mask)
+
+
+def attention_heads(q, k, v, num_heads, scale=None, causal=False, mask=None):
+    """attention_core for the (B, T, H*D) tensors a projection produces.
+    Where the flash kernels run they read those tensors as they are (two
+    64-wide heads to a 128-lane block): no transpose on either side.
+    Otherwise: split, transpose, `attention_core`, and back."""
+    B, Tq, HD = q.shape
+    D = HD // num_heads
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
+    if use_flash(Tq, k.shape[1], D, causal, mask, q.dtype) \
+            and num_heads % (max(D, _LANES) // D) == 0:
+        with jax.named_scope("attention_core"):
+            return _flash(q, k, v, scale, causal, heads=num_heads)
+    qh = q.reshape(B, Tq, num_heads, D).transpose(0, 2, 1, 3)
+    kh = k.reshape(B, -1, num_heads, D).transpose(0, 2, 1, 3)
+    vh = v.reshape(B, -1, num_heads, D).transpose(0, 2, 1, 3)
+    out = attention_core(qh, kh, vh, scale=scale, causal=causal, mask=mask)
+    return out.transpose(0, 2, 1, 3).reshape(B, Tq, HD)
 
 
 # ---------------------------------------------------------------------------

@@ -387,25 +387,20 @@ def _dropout(key, data, p=0.5, mode="training", axes=(), cudnn_off=False,
 
 # ---------------------------------------------------------------------------
 # Attention (reference: src/operator/contrib/transformer.cc interleaved
-# self-attention ops).  Composition form; the Pallas flash path plugs in at
-# mxnet_tpu/parallel/attention.py for long sequences.
+# self-attention ops).  ops/attention.py decides what runs: the Pallas
+# flash kernels straight on these (B, T, H*D) tensors where its rule
+# holds, the jnp composition on split-and-transposed heads otherwise.
 # ---------------------------------------------------------------------------
 
 
 @register("multi_head_attention")
 def _mha(q, k, v, mask=None, num_heads=1, scaled=True, causal=False,
          units=None):  # units: carried for ONNX export (scale = sqrt(units/heads))
-    # q,k,v: (B, T, H*D), mask broadcastable to (B, H, Tq, Tk);
-    # hot path = Pallas flash attention on TPU
-    from .attention import attention_core
-    B, Tq, HD = q.shape
-    D = HD // num_heads
-    qh = q.reshape(B, Tq, num_heads, D).transpose(0, 2, 1, 3)
-    kh = k.reshape(B, -1, num_heads, D).transpose(0, 2, 1, 3)
-    vh = v.reshape(B, -1, num_heads, D).transpose(0, 2, 1, 3)
-    scale = (1.0 / D ** 0.5) if scaled else 1.0
-    out = attention_core(qh, kh, vh, scale=scale, causal=causal, mask=mask)
-    return out.transpose(0, 2, 1, 3).reshape(B, Tq, HD)
+    # q,k,v: (B, T, H*D), mask broadcastable to (B, H, Tq, Tk)
+    from .attention import attention_heads
+    return attention_heads(q, k, v, num_heads,
+                           scale=None if scaled else 1.0,  # None: 1/sqrt(D)
+                           causal=causal, mask=mask)
 
 
 # ---------------------------------------------------------------------------

@@ -144,10 +144,16 @@ class TrainStep:
         self.opt_state = jax.tree_util.tree_map(jnp.zeros_like, self.params)
         self._batch_sharding = batch_sharded(mesh, dp_axis)
         lr, mom = learning_rate, momentum
+        from ..ops.attention import attention_partition_scope
+        from .speclayout import SpecLayout
+        layout = SpecLayout(mesh, data_axis=dp_axis, tp_axis=tp_axis)
 
         def step(params, opt_state, *batch):
             def loss_of(p):
-                out = pure_fn(p, *batch[:-1], training=True)
+                # flash kernels run per shard of this mesh (a Mosaic
+                # kernel cannot be partitioned by GSPMD)
+                with attention_partition_scope(layout):
+                    out = pure_fn(p, *batch[:-1], training=True)
                 return loss_fn(out, batch[-1])
 
             loss, grads = jax.value_and_grad(loss_of)(params)

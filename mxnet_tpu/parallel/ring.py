@@ -59,18 +59,11 @@ def _block_attn(q, k, v, q_off, k_off, causal, scale):
 
 
 def _flash_ok(q, k) -> bool:
-    """Shard shapes eligible for the blockwise Pallas kernel per hop —
-    same gate as attention_core: Mosaic on TPU (or the 'pallas' lowering
-    config forced, which interprets off-TPU), never interpret-by-default
-    on CPU/GPU where the compiled jnp path is far faster."""
-    from ..ops import attention as _att
-    impl = _att.current_attention_impl()   # per-block scope wins over global
-    if impl == "xla":
-        return False
-    lq, lk, d = q.shape[1], k.shape[1], q.shape[3]
-    aligned = (lq % _att._BLOCK_Q == 0 and lk % _att._BLOCK_K == 0
-               and d % 128 == 0)
-    return aligned and (_att._on_tpu() or impl == "pallas")
+    """Shard shapes eligible for the blockwise Pallas kernel per hop:
+    attention_core's own rule (ops/attention.use_flash), asked for the
+    shard's (B, L, H, D) shapes."""
+    from ..ops.attention import use_flash
+    return use_flash(q.shape[1], k.shape[1], q.shape[3], dtype=q.dtype)
 
 
 def _ring_attention_flash(q, k, v, *, axis_name, causal, scale):

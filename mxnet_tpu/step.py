@@ -54,6 +54,7 @@ from . import autograd
 from . import profiler as _profiler
 from . import telemetry as _telemetry
 from .ops import random as _ops_random
+from .ops.attention import attention_partition_scope
 from .ops.optimizer import tree_body
 from .gluon.block import _flatten_nds
 from .gluon.parameter import (DeferredInitializationError,
@@ -457,7 +458,10 @@ class CompiledStep:
             def loss_of(tv):
                 # backward needs no scope of its own: value_and_grad
                 # names the transposed ops transpose(jvp(forward))/...
-                with jax.named_scope("forward"):
+                # the flash kernels are opaque to GSPMD: they read the
+                # layout here and run per shard (ops/attention.py)
+                with jax.named_scope("forward"), \
+                        attention_partition_scope(plan.get("layout")):
                     losses, outs, new_f = run_forward(tv, f_vals, rng,
                                                       x_vals, y_val)
                 # backward() seeds a ones cotangent on the loss: the

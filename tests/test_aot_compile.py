@@ -33,24 +33,29 @@ sys.path.insert(0, REPO)
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """Sharding onto one chip of a described v5e:2x2; the persistent
-    compile cache is off around these compiles (an entry written for a
-    described device cannot be read back without one, and the retry
-    warns)."""
+def topo():
+    """A described v5e:2x2; the persistent compile cache is off around
+    these compiles (an entry written for a described device cannot be
+    read back without one, and the retry warns)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        described = topologies.get_topology_desc(platform="tpu",
+                                                 topology_name="v5e:2x2")
     except Exception as e:      # no libtpu here: nothing to compile with
         pytest.skip("cannot describe a v5e topology: %s" % e)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield described
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Sharding onto one chip of the described v5e:2x2."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _flash(direction, shape, causal):
@@ -98,38 +103,112 @@ def _user_kernel():
         [((256, 256), jnp.float32)]
 
 
-# (id, builder of (fn, [(shape, dtype), ...]), tpu_custom_calls expected)
+def _heads(direction, shape, heads, mask=False):
+    """multi_head_attention's entry: the packed (B, T, H*D) operands."""
+    B, T, _ = shape
+
+    def fwd(q, k, v):
+        m = jnp.ones((B, 1, T, T), bool) if mask else None
+        return att.attention_heads(q, k, v, heads, mask=m)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    return (fwd if direction == "fwd" else bwd), \
+        [(shape, jnp.bfloat16)] * 3
+
+
+# (id, builder of (fn, [(shape, dtype), ...]), tpu_custom_calls expected,
+#  whether the text must equal attention_impl_scope("xla")'s).  The
+# backward is the dk/dv kernel and the dq kernel - or one kernel for all
+# three where one key block holds the sequence (non-causal, T <= 512).
 CASES = [
     ("flash-%s-%s-%s" % (d, "x".join(map(str, s)), "causal" if c else "full"),
-     lambda d=d, s=s, c=c: _flash(d, s, c), n)
+     lambda d=d, s=s, c=c: _flash(d, s, c),
+     1 if d == "fwd" else 3 if c or s[2] > 512 else 2, False)
     for s in ((8, 8, 512, 128), (2, 8, 2048, 128))
     for c in (True, False)
-    for d, n in (("fwd", 1), ("bwd", 3))
+    for d in ("fwd", "bwd")
 ] + [
-    # BERT-base's own shape: head dim 64 is not a multiple of 128, so the
-    # dispatch rule gives it the jnp composition TODAY.  A change of the
-    # rule must show here.
-    ("bert-base-8x12x512x64-gets-xla",
-     lambda: _flash("fwd", (8, 12, 512, 64), False), 0),
-    ("user-kernel", _user_kernel, 1),
+    # BERT-base's own shape, as multi_head_attention hands it over: head
+    # size 64, two heads to a 128-lane block.  A change of the rule must
+    # show here.
+    ("bert-base-8x12x512x64-gets-flash",
+     lambda: _heads("fwd", (8, 512, 768), 12), 1, False),
+    ("bert-base-8x12x512x64-gets-flash-bwd",
+     lambda: _heads("bwd", (8, 512, 768), 12), 2, False),
+    # ... and as attention_core's (B, H, T, D): one 64-wide head a block
+    ("bhtd-8x12x512x64-gets-flash",
+     lambda: _flash("fwd", (8, 12, 512, 64), False), 1, False),
+    ("bhtd-8x12x512x64-causal-gets-flash-bwd",
+     lambda: _flash("bwd", (8, 12, 512, 64), True), 3, False),
+    # a mask, or a T that is no block multiple, keeps the composition:
+    # the same program text as with the kernels switched off
+    ("bert-base-8x12x512x64-masked-gets-xla",
+     lambda: _heads("fwd", (8, 512, 768), 12, mask=True), 0, True),
+    ("bert-base-8x12x128x64-gets-xla",
+     lambda: _heads("fwd", (8, 128, 768), 12), 0, True),
+    ("user-kernel", _user_kernel, 1, False),
 ] + [
-    (name, lambda name=name: _decode_op(name), 0)
+    (name, lambda name=name: _decode_op(name), 0, False)
     for name in ("cached_attention", "cached_attention_multi",
                  "paged_attention", "paged_attention_multi")
 ]
 
 
-@pytest.mark.parametrize("build,custom_calls",
-                         [pytest.param(b, n, id=i) for i, b, n in CASES])
-def test_compiles_for_v5e(chip, monkeypatch, build, custom_calls):
+@pytest.mark.parametrize("build,custom_calls,as_xla",
+                         [pytest.param(b, n, x, id=i)
+                          for i, b, n, x in CASES])
+def test_compiles_for_v5e(chip, monkeypatch, build, custom_calls, as_xla):
     # the code asks jax.default_backend(), sees the CPU here and would
     # take interpret mode: steer it in the test, not through an option
     monkeypatch.setattr(att, "_on_tpu", lambda: True)
     fn, args = build()
     abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
                 for shape, dtype in args]
-    compiled = jax.jit(fn).lower(*abstract).compile()
-    assert compiled.as_text().count("tpu_custom_call") == custom_calls
+    lowered = jax.jit(fn).lower(*abstract)
+    assert lowered.compile().as_text().count("tpu_custom_call") \
+        == custom_calls
+    if as_xla:
+        with att.attention_impl_scope("xla"):
+            assert jax.jit(fn).lower(*abstract).as_text() \
+                == lowered.as_text()
+
+
+def test_flash_is_partitioned_by_batch_on_four_chips(topo, monkeypatch):
+    """BERT-base's attention of the fsdp4 cell (128 rows over data,fsdp =
+    2x2) inside attention_partition_scope, compiled for the described
+    v5e:2x2: every kernel works on its chip's 32 rows and nothing gathers
+    a [..,512,768] operand.  Without the scope there is no program at
+    all: jax refuses to lower a Mosaic kernel it would have to partition
+    itself."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.parallel import SpecLayout
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    layout = SpecLayout.infer(Mesh(np.array(topo.devices).reshape(2, 2),
+                                   ("data", "fsdp")))
+    rows = NamedSharding(layout.mesh, P(("data", "fsdp")))
+    fn, args = _heads("bwd", (128, 512, 768), 12)
+    abstract = [jax.ShapeDtypeStruct(s, d, sharding=rows) for s, d in args]
+
+    def compiled(scope):
+        with att.attention_partition_scope(scope):
+            return jax.jit(fn, out_shardings=(rows,) * 3) \
+                .lower(*abstract).compile().as_text()
+
+    def lines(text, what):
+        return [l for l in text.splitlines() if what in l and " = " in l]
+
+    text = compiled(layout)
+    calls = lines(text, "tpu_custom_call")
+    assert len(calls) == 2                  # forward; dk, dv, dq in one
+    assert all("bf16[32,512,768]" in l and "[128,512,768]" not in l
+               for l in calls)
+    assert not lines(text, "all-gather")
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        compiled(None)
 
 
 # ---------------------------------------------------------------------------
@@ -140,7 +219,8 @@ SMOKE_TINY = {
     "train_bert_base": dict(layers=1, units=32, heads=2, vocab=64, batch=4,
                             seq=16, lr=0.5),
     "eager": dict(rows=32, cols=8, hidden=16),
-    "pallas": dict(shape=(1, 2, 256, 128)),
+    "pallas": dict(cases=(((1, 2, 256, 128), True),
+                          ((1, 2, 256, 64), False))),
 }
 
 
@@ -156,7 +236,8 @@ def test_chip_smoke_phase_tiny_on_cpu(phase):
         assert out["losses"][-1] < out["losses"][0]
         assert out["attention_impl"] == "xla"
     if phase == "pallas":
-        assert out["tpu_custom_calls"] == {"forward": 0, "backward": 0}
+        assert [c["tpu_custom_calls"] for c in out["cases"]] \
+            == [{"forward": 0, "backward": 0}] * 2
 
 
 def test_chip_smoke_refuses_cpu():

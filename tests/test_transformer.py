@@ -38,92 +38,246 @@ def test_attention_core_matches_numpy():
     assert np.allclose(out_c, ref_c, atol=1e-5)
 
 
-def test_flash_kernel_matches_reference_cpu_interpret():
-    """Run the Pallas kernel in interpreter mode on CPU against the jnp
-    path (the TPU run is covered by bench/verify)."""
-    import jax
-    import jax.experimental.pallas as pl
-    from mxnet_tpu.ops import attention as att
-    np.random.seed(0)
-    B, H, T, D = 1, 2, 512, 128
-    q = np.random.randn(B, H, T, D).astype(np.float32)
-    k = np.random.randn(B, H, T, D).astype(np.float32)
-    v = np.random.randn(B, H, T, D).astype(np.float32)
-    scale = 1.0 / np.sqrt(D)
+# The shapes that take the flash kernels (ops/attention.flash_rule), run in
+# Pallas interpret mode on the CPU against the jnp composition: head size
+# 64 (BERT-base; two heads a 128-lane block in the packed layout) and 128,
+# full and causal, float32 and bf16, in both layouts the kernels read -
+# (B, H, T, D) as attention_core takes it and the packed (B, T, H*D) that
+# multi_head_attention holds.
+FLASH_CASES = [
+    pytest.param(D, causal, dtype, layout,
+                 id="d%d-%s-%s-%s" % (D, "causal" if causal else "full",
+                                      dtype, layout))
+    for D in (64, 128) for causal in (False, True)
+    for dtype in ("float32", "bfloat16") for layout in ("bhtd", "packed")]
 
-    # _flash_fwd auto-interprets off-TPU — no monkeypatching needed
-    out, lse = att._flash_fwd(q, k, v, scale, False)
-    out, lse = np.asarray(out), np.asarray(lse)
-    out_causal = np.asarray(att._flash_fwd(q, k, v, scale, True)[0])
-    ref = _np_attention(q, k, v, scale)
-    ref_causal = _np_attention(q, k, v, scale, causal=True)
-    assert np.allclose(out, ref, atol=2e-4), np.abs(out - ref).max()
-    assert np.allclose(out_causal, ref_causal, atol=2e-4)
-    # lse residual: logsumexp of the scaled scores
-    s = np.einsum("bhqd,bhkd->bhqk", q, k) * scale
+
+def _flash_case(D, dtype, layout, seed):
+    """(q, k, v, g) as float32 arrays already rounded to `dtype`, the
+    flash call on them in `layout`, and its float32 tolerance."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as att
+    B, H, T = 2, 4, 512
+    rng = np.random.RandomState(seed)
+    q, k, v, g = (jnp.asarray(rng.randn(B, H, T, D), dtype)
+                  .astype(jnp.float32) for _ in range(4))
+    heads = H if layout == "packed" else None
+
+    def lay(x):
+        x = x.astype(dtype)
+        return x.transpose(0, 2, 1, 3).reshape(B, T, H * D) if heads else x
+
+    def unlay(x):
+        return x.reshape(B, T, H, D).transpose(0, 2, 1, 3) if heads else x
+
+    def call(fn, q, k, v, scale, causal):
+        out = fn(lay(q), lay(k), lay(v), scale, causal, heads)
+        if isinstance(out, tuple):
+            return unlay(out[0]), out[1]
+        return unlay(out)
+
+    return (q, k, v, g), call
+
+
+@pytest.mark.parametrize("D,causal,dtype,layout", FLASH_CASES)
+def test_flash_forward_matches_jnp_cpu_interpret(D, causal, dtype, layout):
+    """Output and logsumexp residual against the composition."""
+    from mxnet_tpu.ops import attention as att
+    (q, k, v, _), call = _flash_case(D, dtype, layout, seed=0)
+    scale = 1.0 / np.sqrt(D)
+    out, lse = call(att._flash_fwd, q, k, v, scale, causal)
+    assert out.dtype == np.dtype(dtype) or str(out.dtype) == dtype
+    ref = _np_attention(np.asarray(q), np.asarray(k), np.asarray(v), scale,
+                        causal=causal)
+    tol = 2e-4 if dtype == "float32" else 0.05 * np.abs(ref).max()
+    err = np.abs(np.asarray(out, np.float32) - ref).max()
+    assert err < tol, err
+    # lse residual: logsumexp of the scaled (masked) scores
+    s = np.einsum("bhqd,bhkd->bhqk", np.asarray(q), np.asarray(k)) * scale
+    if causal:
+        s = np.where(np.tril(np.ones(s.shape[-2:], bool)), s, -np.inf)
     lse_ref = np.log(np.exp(s - s.max(-1, keepdims=True)).sum(-1)) \
         + s.max(-1)
-    assert np.allclose(lse, lse_ref, atol=1e-4), np.abs(lse - lse_ref).max()
+    assert lse.shape == lse_ref.shape and lse.dtype == np.float32
+    assert np.allclose(lse, lse_ref, atol=1e-4 if dtype == "float32"
+                       else 0.05), np.abs(lse - lse_ref).max()
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_backward_matches_jnp_cpu_interpret(causal):
+@pytest.mark.parametrize("D,causal,dtype,layout", FLASH_CASES)
+def test_flash_backward_matches_jnp_cpu_interpret(D, causal, dtype, layout):
     """The blockwise Pallas backward (recompute-from-LSE, O(L) memory) must
-    produce the same dq/dk/dv as differentiating the jnp composition."""
+    produce the same dq/dk/dv as differentiating the jnp composition;
+    bf16 inputs (the MXU-native training dtype) give bf16 gradients near
+    the float32 reference."""
     import jax
     import jax.numpy as jnp
-    import jax.experimental.pallas as pl
     from mxnet_tpu.ops import attention as att
-    np.random.seed(1)
-    B, H, T, D = 1, 2, 512, 128
-    q = np.random.randn(B, H, T, D).astype(np.float32)
-    k = np.random.randn(B, H, T, D).astype(np.float32)
-    v = np.random.randn(B, H, T, D).astype(np.float32)
-    g = np.random.randn(B, H, T, D).astype(np.float32)
-    scale = 1.0 / np.sqrt(D)
-
-    _, vjp = jax.vjp(
-        lambda q, k, v: att.flash_attention(q, k, v, scale, causal),
-        q, k, v)
-    dq, dk, dv = vjp(jnp.asarray(g))
-
-    _, vjp_ref = jax.vjp(
-        lambda q, k, v: att._attention_jnp(q, k, v, scale, causal), q, k, v)
-    dq_r, dk_r, dv_r = vjp_ref(jnp.asarray(g))
-    for got, want, name in ((dq, dq_r, "dq"), (dk, dk_r, "dk"),
-                            (dv, dv_r, "dv")):
-        err = np.abs(np.asarray(got) - np.asarray(want)).max()
-        rel = err / max(np.abs(np.asarray(want)).max(), 1e-6)
-        assert rel < 2e-4, (name, err, rel)
-
-
-def test_flash_backward_bf16_cpu_interpret():
-    """bf16 inputs (the MXU-native training dtype) flow through the flash
-    backward; grads come back bf16 and near the fp32 reference."""
-    import jax
-    import jax.numpy as jnp
-    import jax.experimental.pallas as pl
-    from mxnet_tpu.ops import attention as att
-    np.random.seed(2)
-    B, H, T, D = 1, 1, 256, 128
-    q = jnp.asarray(np.random.randn(B, H, T, D), jnp.bfloat16)
-    k = jnp.asarray(np.random.randn(B, H, T, D), jnp.bfloat16)
-    v = jnp.asarray(np.random.randn(B, H, T, D), jnp.bfloat16)
+    (q, k, v, g), call = _flash_case(D, dtype, layout, seed=1)
     scale = 1.0 / np.sqrt(D)
 
     def loss(q, k, v):
-        return jnp.sum(att.flash_attention(q, k, v, scale, False)
-                       .astype(jnp.float32))
-    dq, dk, dv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    assert dq.dtype == jnp.bfloat16
-    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+        out = call(att.flash_attention, q, k, v, scale, causal)
+        assert str(out.dtype) == dtype
+        return jnp.sum(out.astype(jnp.float32) * g)
+
     def loss_ref(q, k, v):
-        return jnp.sum(att._attention_jnp(q, k, v, scale, False))
-    rq, rk, rv = jax.grad(loss_ref, argnums=(0, 1, 2))(qf, kf, vf)
-    for got, want in ((dq, rq), (dk, rk), (dv, rv)):
-        rel = (np.abs(np.asarray(got, np.float32) - np.asarray(want)).max()
-               / max(np.abs(np.asarray(want)).max(), 1e-6))
-        assert rel < 0.05, rel
+        return jnp.sum(att._attention_jnp(q, k, v, scale, causal) * g)
+
+    got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        rel = np.abs(np.asarray(a) - np.asarray(b)).max() \
+            / max(np.abs(np.asarray(b)).max(), 1e-6)
+        assert rel < (2e-4 if dtype == "float32" else 0.05), (name, rel)
+
+
+def test_flash_rule_is_stated_once():
+    """What takes the kernels: no mask, T a multiple of 256, head size 64
+    or a multiple of 128, bf16/float32, square when causal - and the ring
+    asks the same function."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.parallel import ring
+    rule = att.flash_rule
+    assert rule(512, 512, 64) and rule(512, 512, 128) and rule(256, 512, 256)
+    assert rule(512, 512, 64, causal=True, dtype=jnp.float32)
+    assert not rule(128, 128, 64) and not rule(512, 384, 64)
+    assert not rule(512, 512, 96) and not rule(512, 512, 32)
+    assert not rule(512, 512, 64, mask=np.ones((1, 1, 512, 512)))
+    assert not rule(256, 512, 64, causal=True)
+    assert not rule(512, 512, 64, dtype=jnp.float16)
+    q = jnp.zeros((1, 512, 2, 64), jnp.bfloat16)       # (B, L, H, D) shard
+    assert not att.use_flash(512, 512, 64)              # the CPU: composition
+    assert not ring._flash_ok(q, q)
+    with att.attention_impl_scope("pallas"):
+        assert att.use_flash(512, 512, 64) and ring._flash_ok(q, q)
+        assert not ring._flash_ok(q[:, :128], q[:, :128])
+    with att.attention_impl_scope("xla"):
+        assert not att.use_flash(512, 512, 64)
+
+
+def test_mha_takes_packed_kernels_without_transposes():
+    """multi_head_attention hands its (B, T, H*D) tensors to the kernels
+    as they are; a masked or a short call lowers to the composition's own
+    program, letter for letter."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops.nn import _mha
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 256, 128), jnp.float32)
+
+    def text(impl, **kw):
+        with att.attention_impl_scope(impl):
+            return jax.jit(lambda q: _mha(q, q, q, num_heads=2, **kw)) \
+                .lower(x).as_text()
+
+    heads_first = "dims = [0, 2, 1, 3]"     # (B, T, H, D) <-> (B, H, T, D)
+    assert heads_first not in text("pallas") and heads_first in text("xla")
+    with att.attention_impl_scope("pallas"):
+        got = _mha(x, x, x, num_heads=2)
+    with att.attention_impl_scope("xla"):
+        want = _mha(x, x, x, num_heads=2)
+    assert np.allclose(got, want, atol=2e-4)
+    mask = jnp.ones((2, 1, 256, 256), bool)
+    assert text("pallas", mask=mask) == text("xla", mask=mask)
+    short = jax.jit(lambda q: _mha(q, q, q, num_heads=2))
+    with att.attention_impl_scope("pallas"):
+        a = short.lower(x[:, :128]).as_text()
+    with att.attention_impl_scope("xla"):
+        b = short.lower(x[:, :128]).as_text()
+    assert a == b
+
+
+def test_flash_runs_per_shard_under_a_layout():
+    """Inside attention_partition_scope the kernels run under shard_map
+    over the layout's batch axes (GSPMD cannot partition them), and an op
+    program traced there is not handed to a trace outside."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.parallel import SpecLayout, make_mesh
+    mesh = make_mesh(axes=("data", "fsdp"), shape=(2, 2),
+                     devices=jax.devices()[:4])
+    layout = SpecLayout.infer(mesh)
+    x = jnp.asarray(np.random.RandomState(4).randn(4, 256, 128), jnp.float32)
+
+    def f(q):
+        return att.attention_heads(q, q, q, 2)
+
+    with att.attention_impl_scope("pallas"):
+        plain = jax.make_jaxpr(f)(x)
+        with att.attention_partition_scope(layout):
+            sharded = jax.make_jaxpr(f)(x)
+            xs = jax.device_put(x, layout.batch_sharding())
+            got = jax.jit(f)(xs)
+        again = jax.make_jaxpr(f)(x)
+        want = f(x)
+    assert "shard_map" in str(sharded)
+    assert "shard_map" not in str(plain) and "shard_map" not in str(again)
+    assert got.sharding.is_equivalent_to(layout.batch_sharding(), 3)
+    assert np.allclose(got, want, atol=2e-4)
+    # a batch the mesh does not divide stays whole
+    with att.attention_impl_scope("pallas"), \
+            att.attention_partition_scope(layout):
+        assert "shard_map" not in str(jax.make_jaxpr(f)(x[:3]))
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2, 2)],
+                         ids=["one-device", "data2-fsdp2"])
+def test_bert_compiled_step_takes_flash_and_matches_xla(mesh_shape):
+    """A BERT with 64-wide heads at T = 256 through the compiled train
+    step, the kernels forced (the CPU interprets) against the composition:
+    the same losses.  Under a data,fsdp layout CompiledStep's partition
+    scope puts the kernels under shard_map."""
+    import jax
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.parallel import SpecLayout, make_mesh
+    rng = np.random.RandomState(0)
+    tok = rng.randint(0, 50, (4, 256))
+    lab = rng.randint(0, 50, (4, 256)).astype("float32")
+    sce = gluon.loss.SoftmaxCrossEntropyLoss()
+    seen = {"flash": 0, "sharded": 0}
+    orig = att.flash_attention
+
+    def spy(q, *a, **kw):
+        seen["flash"] += 1
+        seen["sharded"] += q.shape[0] == 1     # 4 rows over 2 x 2 devices
+        return orig(q, *a, **kw)
+
+    def losses(impl):
+        mx.random.seed(0)
+        net = bert_mod.get_bert(num_layers=1, units=128, num_heads=2,
+                                vocab_size=50, max_length=256, dropout=0.0,
+                                use_classifier=False)
+        net.initialize(mx.init.Normal(0.02))
+        net.hybridize()
+        two = mx.nd.zeros((2, 256), dtype="int32")
+        net(two, two)
+        trainer = gluon.Trainer(net.collect_params(), "sgd",
+                                {"learning_rate": 0.5, "momentum": 0.9})
+        layout = None if mesh_shape is None else SpecLayout.infer(
+            make_mesh(axes=("data", "fsdp"), shape=mesh_shape,
+                      devices=jax.devices()[:4]))
+        step = trainer.make_compiled_step(
+            net, lambda outs, y: sce(outs[-1], y), layout=layout)
+        data = (mx.nd.array(tok, dtype="int32"),
+                mx.nd.zeros((4, 256), dtype="int32"))
+        with att.attention_impl_scope(impl):
+            out = [float(step.step(data, mx.nd.array(lab)).asnumpy().mean())
+                   for _ in range(3)]
+        assert step.compiled, step.fallback_reason
+        return out
+
+    want = losses("xla")
+    att.flash_attention = spy
+    try:
+        got = losses("pallas")
+    finally:
+        att.flash_attention = orig
+    assert seen["flash"] >= 1
+    assert seen["sharded"] == (seen["flash"] if mesh_shape else 0)
+    assert got[-1] < got[0]
+    assert np.allclose(got, want, rtol=2e-4), (got, want)
 
 
 def test_interleaved_selfatt_ops():
