@@ -385,7 +385,7 @@ def _run_backward(heads, head_grads, retain_graph, write_leaves=True,
 
     def _write_leaf(arr, val):
         req = arr._grad_req
-        if req == "null" or arr._grad is None:
+        if req == "null" or arr.grad is None:   # .grad: allocates a lazy one
             return
         if req == "add":
             arr._grad._set_jax(arr._grad._jax + val.astype(arr._grad.dtype))

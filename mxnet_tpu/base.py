@@ -156,6 +156,15 @@ ENV_CATALOG: Dict[str, Any] = {
 }
 
 
+# ``jax.ad_checkpoint.checkpoint_name`` tag of a value a recomputed block
+# (``gluon.Block.recompute``) must KEEP from its forward pass instead of
+# computing it again: a discrete decision (a router's top-k choice) that a
+# second run of the same arithmetic, fused and rounded otherwise by XLA,
+# can make the other way - the backward pass would then differentiate
+# another function than the forward pass ran (PERF.md section 6, PR 28).
+RECOMPUTE_KEEP = "mx_recompute_keep"
+
+
 def get_env(name: str, default: Any = None, dtype: Callable = str) -> Any:
     """Read an env flag with overrides (reference: dmlc::GetEnv)."""
     with _env_lock:

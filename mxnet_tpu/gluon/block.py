@@ -19,6 +19,7 @@ single fused node (SURVEY.md §7.2 item 1).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import re
 import threading
@@ -29,7 +30,7 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 
-from ..base import MXNetError
+from ..base import MXNetError, RECOMPUTE_KEEP
 from ..device import Context, current_context, cpu
 from ..ndarray import ndarray as _nd_mod
 from ..ndarray.ndarray import NDArray
@@ -40,7 +41,16 @@ from .parameter import (Parameter, Constant, ParameterDict,
                         DeferredInitializationError, _ParamOverrideScope,
                         _overrides)
 
-__all__ = ["Block", "HybridBlock", "SymbolBlock"]
+__all__ = ["Block", "HybridBlock", "SymbolBlock", "trace_scope"]
+
+
+def trace_scope(name: str):
+    """``jax.named_scope(name)`` inside a whole-program trace (a compiled
+    step, a hybridize cache), nothing in an eager call: how code that is
+    no block of the net (a loss) puts its ops under a scope a device
+    trace can be read by."""
+    return jax.named_scope(name) if _overrides() is not None \
+        else contextlib.nullcontext()
 
 
 class Block:
@@ -250,10 +260,71 @@ class Block:
             # eager block call cost 4 % of a small net's forward).
             with jax.named_scope(self.__dict__.get("_scope_name")
                                  or type(self).__name__):
-                out = self._call_impl(*args, **kwargs)
+                out = self._call_recomputed(args, kwargs) \
+                    if self.__dict__.get("_recompute") \
+                    else self._call_impl(*args, **kwargs)
         for hook in self._forward_hooks.values():
             hook(self, args, out)
         return out
+
+    def recompute(self, active: bool = True) -> "Block":
+        """Mark this block as RECOMPUTED in the backward pass: inside a
+        whole-program trace (``CompiledStep``, a hybridized ancestor) its
+        forward runs under ``jax.checkpoint``, so only its inputs - and
+        what an operator tags ``base.RECOMPUTE_KEEP``: a router's choices
+        - are kept for the backward pass, which runs the forward again
+        (the ops of that second run carry
+        ``checkpoint/rematted_computation`` on their path).  An eager call
+        ignores the mark.  Returns the block, so a model's definition can
+        write ``self.layer = Layer(...).recompute()``."""
+        object.__setattr__(self, "_recompute", bool(active))
+        return self
+
+    def _call_recomputed(self, args, kwargs):
+        """``_call_impl`` under ``jax.checkpoint``.  The block's own
+        parameters and its NDArray arguments are the checkpointed
+        function's operands; aux state written inside (BatchNorm's
+        statistics, an expert layer's counters) comes back as outputs and
+        is stored into the enclosing trace's values, and random ops draw
+        from a key handed in, so no tracer of the inner trace escapes."""
+        outer = _overrides()
+        own, seen = [], set()
+        for _, p in self._iter_params():
+            if id(p) in outer and id(p) not in seen:
+                seen.add(id(p))
+                own.append((p, outer[id(p)]))
+        in_leaves: List[NDArray] = []
+        template = _flatten_nds(args, in_leaves)
+        ctx = _first_ctx(args) or cpu()
+        keyed = getattr(_ops_random._root(), "trace_holder", None) is not None
+        key = _ops_random.next_key() if keyed else None
+        shape = {}
+
+        def body(p_vals, in_vals, key):
+            inner, fresh = dict(outer), []
+            for (p, _), v in zip(own, p_vals):
+                fresh.append(NDArray(v, ctx=cpu()))
+                inner[id(p)] = fresh[-1]
+            rebuilt = _rebuild(template,
+                               [NDArray(v, ctx=ctx) for v in in_vals], [0])
+            with _ParamOverrideScope(inner), \
+                    (_ops_random.trace_key_scope(key) if keyed
+                     else contextlib.nullcontext()):
+                out = self._call_impl(*rebuilt, **kwargs)
+            leaves: List[NDArray] = []
+            shape["out"] = _flatten_nds(out, leaves)
+            written = {i: nd._jax for i, nd in enumerate(fresh)
+                       if nd._chunk.version > 0}
+            return tuple(o._jax for o in leaves), written
+
+        keep = jax.checkpoint_policies.save_only_these_names(RECOMPUTE_KEEP)
+        outs, written = jax.checkpoint(body, policy=keep)(
+            tuple(nd._jax for _, nd in own),
+            tuple(x._jax for x in in_leaves), key)
+        for i, value in written.items():
+            own[i][1]._set_jax(value)
+        return _rebuild(shape["out"], [NDArray(o, ctx=ctx) for o in outs],
+                        [0])
 
     def _call_impl(self, *args, **kwargs):
         try:

@@ -76,13 +76,40 @@ def _param_census_arrays(p):
     """One parameter's live device buffers (data + grad, every ctx copy)
     for the buffer census."""
     out = []
-    for store in (p._data, p._grad):
-        if store:
-            for nd in store.values():
-                a = getattr(nd, "_jax", None)
-                if a is not None:
-                    out.append(a)
+    for nds in (p._data.values() if p._data else (),
+                p._grad.allocated() if p._grad else ()):
+        for nd in nds:
+            a = getattr(nd, "_jax", None)
+            if a is not None:
+                out.append(a)
     return out
+
+
+class _GradStore:
+    """ctx -> gradient NDArray of a parameter's data arrays, read like the
+    dict it replaces.  A buffer is allocated when it is first asked for
+    (``NDArray.attach_grad(lazy=True)``): the eager path asks on its first
+    backward pass, a compiled step never does."""
+
+    def __init__(self, data):
+        self._data = data
+
+    def __len__(self):
+        return len(self._data)
+
+    def __contains__(self, ctx):
+        return ctx in self._data
+
+    def __getitem__(self, ctx):
+        return self._data[ctx].grad
+
+    def values(self):
+        return [arr.grad for arr in self._data.values()]
+
+    def allocated(self):
+        """The buffers that exist, without making the others."""
+        return [arr._grad for arr in self._data.values()
+                if arr._grad is not None]
 
 
 class Parameter:
@@ -218,10 +245,9 @@ class Parameter:
             self._init_grad()
 
     def _init_grad(self):
-        self._grad = OrderedDict()
-        for c, arr in self._data.items():
-            arr.attach_grad(self._grad_req)
-            self._grad[c] = arr.grad
+        for arr in self._data.values():
+            arr.attach_grad(self._grad_req, lazy=True)
+        self._grad = _GradStore(self._data)
 
     def _finish_deferred_init(self):
         if self._deferred_init is None:
@@ -315,7 +341,7 @@ class Parameter:
     def zero_grad(self):
         if self._grad is None:
             return
-        for g in self._grad.values():
+        for g in self._grad.allocated():      # one not yet made is zero
             g[:] = 0
 
     def reset_ctx(self, ctx):

@@ -341,14 +341,23 @@ class NDArray:
     # ------------------------------------------------------------------
     # autograd surface (reference: attach_grad / .grad / detach / backward)
     # ------------------------------------------------------------------
-    def attach_grad(self, grad_req: str = "write", stype=None) -> None:
+    def attach_grad(self, grad_req: str = "write", stype=None,
+                    lazy: bool = False) -> None:
+        """`lazy`: the gradient buffer is allocated when first asked for
+        (`grad`, or a backward pass that writes it) - a Gluon parameter's
+        way: a compiled step computes its gradients inside its program and
+        never reads the buffer, which is as large as the parameter."""
         from .. import autograd
-        self._grad = NDArray(jnp.zeros(self.shape, self.dtype), ctx=self.context)
+        self._grad = None if lazy else NDArray(
+            jnp.zeros(self.shape, self.dtype), ctx=self.context)
         self._grad_req = grad_req
         self._ag_node = autograd.VariableNode(self)
 
     @property
     def grad(self) -> Optional["NDArray"]:
+        if self._grad is None and self._grad_req != "null":
+            self._grad = NDArray(jnp.zeros(self.shape, self.dtype),
+                                 ctx=self.context)
         return self._grad
 
     def detach(self) -> "NDArray":
@@ -471,10 +480,14 @@ class NDArray:
             shape = tuple(shape[0])
         shape = _infer_reshape(self.shape, shape)
         from .. import autograd
-        if autograd.is_recording() and self._ag_node is not None:
+        if (autograd.is_recording() and self._ag_node is not None) \
+                or _sym_tracer is not None:
             # recorded op-form reshape: a view would drop the tape node and
             # silently cut the gradient chain (rnn param packing relies on
-            # grads flowing through reshape)
+            # grads flowing through reshape) - and under the symbol tracer
+            # it would be no node of the graph: a block that ENDS in a
+            # reshape (PixelShuffle2D) exported only while the view
+            # happened to reuse the address of a recorded intermediate
             return invoke("reshape", self, shape=shape)
         if self._index is None and self._vshape is None:
             # view of the root chunk: writes through (reference semantics)

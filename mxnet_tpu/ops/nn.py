@@ -403,6 +403,66 @@ def _mha(q, k, v, mask=None, num_heads=1, scaled=True, causal=False,
                            causal=causal, mask=mask)
 
 
+@register("rotary_embedding")
+def _rotary_embedding(data, num_heads=1, rotary_dim=None, theta=10000.0):
+    """Rotary positions on the packed (B, T, H*D) tensor a projection
+    produces: the LAST `rotary_dim` lanes of every head are rotated by
+    the position's angle, the first D - rotary_dim pass through (a head
+    laid out [nope | rope], as latent attention has it).  Half-split
+    pairs (lane i with lane i + rotary_dim/2, the NeoX layout), angles
+    pos * theta^(-2i/rotary_dim) in float32, positions 0..T-1."""
+    b, t, hd = data.shape
+    d = hd // num_heads
+    r = d if rotary_dim is None else rotary_dim
+    half = r // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / r))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x = data.reshape(b, t, num_heads, d)
+    rope = x[..., d - r:].astype(jnp.float32)
+    x1, x2 = rope[..., :half], rope[..., half:]
+    turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                             axis=-1).astype(data.dtype)
+    return jnp.concatenate([x[..., :d - r], turned], axis=-1) \
+        .reshape(b, t, hd)
+
+
+# ---------------------------------------------------------------------------
+# Mixture of experts, token-choice top-k on this chip's share of the experts
+# (parallel/moe.py holds the mathematics; gluon.nn.TokenChoiceMoE the block).
+# ---------------------------------------------------------------------------
+
+
+@register("moe_token_choice", num_outputs=3, aux_writeback={1: 5, 2: 6})
+def _moe_token_choice(data, router_weight, router_correction,
+                      gate_up_weight, down_weight, assignments, elsewhere,
+                      held=(0,),
+                      top_k=1, scale=1.0, norm_topk_prob=True, count=False):
+    """The held experts' part of a token-choice layer for (..., d) tokens.
+    `assignments` (H,) and `elsewhere` (1,) are float32 counters written
+    in place (aux state, as BatchNorm's moving statistics): with `count`
+    they grow by this call's assignments a held expert and by those routed
+    to experts held elsewhere."""
+    from ..parallel.moe import token_choice_moe
+    y, here, away = token_choice_moe(
+        data, router_weight, router_correction, gate_up_weight,
+        down_weight, held=tuple(held), top_k=top_k, scale=scale,
+        norm_topk_prob=norm_topk_prob)
+    if count:
+        assignments = assignments + lax.stop_gradient(here)
+        elsewhere = elsewhere + lax.stop_gradient(away)
+    return y, assignments, elsewhere
+
+
+@register("moe_topk_choice", differentiable=False)
+def _moe_topk_choice(data, router_weight, router_correction, top_k=1):
+    """The experts (global ids, (..., k) int32) `moe_token_choice` picks."""
+    from ..parallel.moe import topk_route
+    idx, _ = topk_route(data.reshape(-1, data.shape[-1]), router_weight,
+                        router_correction, top_k)
+    return idx.reshape(data.shape[:-1] + (top_k,))
+
+
 # ---------------------------------------------------------------------------
 # CTC loss (reference: src/operator/nn/ctc_loss.cc — warp-ctc/cuDNN CTC).
 # TPU-native: the alpha (forward-variable) recursion is a lax.scan over time

@@ -1,26 +1,45 @@
-"""Expert parallelism: gated mixture-of-experts over an 'ep' mesh axis.
+"""Mixture-of-experts layers: two routers, one file.
 
-No reference counterpart (SURVEY.md §2.3 design slot) — TPU-native MoE:
-experts live sharded across the ``ep`` axis (``e_local`` per device);
-tokens are top-1 routed, packed to a fixed per-expert capacity (static
-shapes — XLA requirement), exchanged with TWO ``all_to_all`` collectives
-(dispatch, return), and combined scaled by the gate probability.  Dropped
-tokens (over capacity) contribute zeros, the standard GShard/Switch
-behavior; gradients flow through the gate via the combine weights.
+**Token-choice top-k on one chip's share** (``topk_route``,
+``held_expert_ffn``, ``token_choice_moe``) is the layer a Gluon model
+reaches: ``gluon.nn.TokenChoiceMoE`` calls it through the
+``moe_token_choice`` op, and ``model_zoo.glm_moe_lite`` builds its expert
+blocks from that.  The layer is told which experts it HOLDS, scores every
+token over ALL experts (sigmoid scores, selection by score + bias, weights
+renormalised over the chosen k and scaled), and computes the part of the
+result its own experts give: assignments sorted by expert, one grouped
+(ragged) product a projection, no capacity and no dropped token whatever
+the imbalance.  On one chip it runs without an exchange; what the absent
+experts would add is left out, here and in the reference alike.
+
+**Top-1 with capacity over an ``ep`` mesh axis** (``top1_dispatch``,
+``moe_apply``, ``moe_parallel``) is the older, cross-chip half: experts
+live sharded across ``ep`` (``e_local`` per device); tokens are top-1
+routed, packed to a fixed per-expert capacity into a dense ``(T, E, C)``
+dispatch, exchanged with TWO ``all_to_all`` collectives (dispatch, return)
+and combined scaled by the gate probability.  Over-capacity tokens
+contribute zeros (GShard/Switch).  No Gluon block reaches it; it is called
+directly (``__graft_entry__.dryrun_multichip``).  Its exchange is to be
+re-based onto the token-choice router (ROADMAP Queue 3).
 
 Everything is jittable and differentiable; correctness is pinned against
-a per-token dense reference on the 8-device CPU mesh.
+per-token dense references (tests/test_parallel.py, tests/test_glm_moe_lite.py).
 """
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Sequence
 
+import numpy as _np
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = ["moe_apply", "moe_parallel", "top1_dispatch"]
+from ..base import RECOMPUTE_KEEP
+
+__all__ = ["moe_apply", "moe_parallel", "top1_dispatch", "topk_route",
+           "held_expert_ffn", "token_choice_moe"]
 
 
 def top1_dispatch(gate_logits, n_experts: int, capacity: int):
@@ -102,3 +121,144 @@ def moe_parallel(expert_fn: Callable, mesh: Mesh, *, ep_axis: str = "ep",
         return mapped(x, gate_w, stacked_expert_params)
 
     return apply
+
+
+# ---------------------------------------------------------------------------
+# Token-choice top-k routing over ALL experts, computed for the experts HELD
+# here.  No capacity: an assignment is never dropped.
+# ---------------------------------------------------------------------------
+
+
+def topk_route(x, router_w, bias, top_k: int, scale: float = 1.0,
+               norm_topk_prob: bool = True):
+    """Sigmoid scores of every token over ALL experts and the chosen k.
+
+    x: (N, d) tokens; router_w: (E, d); bias: (E,) selection-only
+    correction (``noaux_tc``: it picks, it does not weigh, and it gets no
+    gradient).  Float32 at the highest matmul precision whatever the
+    inputs' dtype: a near-tie between the k-th and the next score must not
+    be decided by a rounded product.  Returns (idx (N, k) int32, weights
+    (N, k) float32 = scale * s / sum of the chosen s)."""
+    with jax.default_matmul_precision("highest"):
+        logits = jnp.dot(x.astype(jnp.float32),
+                         router_w.astype(jnp.float32).T)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
+                       top_k)
+    # a recomputed block keeps the choice of its forward pass: run again,
+    # a near-tie can fall the other way (base.RECOMPUTE_KEEP)
+    idx = checkpoint_name(idx, RECOMPUTE_KEEP)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk_prob:
+        chosen = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), chosen * scale
+
+
+@jax.custom_vjp
+def _take_rows(x, take, back, n_valid):
+    """out[r] = x[take[r]] for r < n_valid, else 0.  `back` (rows of x, j)
+    lists for every row of x the positions r that took it (any value
+    >= n_valid: none): the transpose is then a gather too, where a plain
+    ``x[take]`` would transpose to a scatter-add, which a TPU serialises."""
+    del back
+    rows = jnp.arange(take.shape[0]) < n_valid
+    return jnp.where(rows[:, None], x[take], jnp.zeros((), x.dtype))
+
+
+def _take_rows_fwd(x, take, back, n_valid):
+    return _take_rows(x, take, back, n_valid), (take, back, n_valid)
+
+
+def _take_rows_bwd(res, g):
+    take, back, n_valid = res
+    return _sum_rows(g, back, take, n_valid), None, None, None
+
+
+@jax.custom_vjp
+def _sum_rows(rows, back, take, n_valid):
+    """out[i] = sum over j of rows[back[i, j]] where back[i, j] < n_valid:
+    the transpose of `_take_rows`, and a gather as well."""
+    ok = back < n_valid
+    got = rows[jnp.where(ok, back, 0)]                  # (I, j, d)
+    return jnp.where(ok[..., None], got, jnp.zeros((), rows.dtype)) \
+        .astype(jnp.float32).sum(1).astype(rows.dtype)
+
+
+def _sum_rows_fwd(rows, back, take, n_valid):
+    return _sum_rows(rows, back, take, n_valid), (back, take, n_valid)
+
+
+def _sum_rows_bwd(res, g):
+    back, take, n_valid = res
+    return _take_rows(g, take, back, n_valid), None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def held_expert_ffn(x, idx, weights, w_gate_up, w_down, held: Sequence[int],
+                    n_experts: int):
+    """The held experts' part of a token-choice layer's result.
+
+    x: (N, d); idx, weights: (N, k) from `topk_route` (over ALL
+    `n_experts`); w_gate_up: (H, d, 2f) and w_down: (H, f, d), the H =
+    len(held) experts stacked in the order of `held` (their global ids).
+    Every expert is a SwiGLU: ``(silu(x Wg) * (x Wu)) Wd``.
+
+    Assignments (token, choice) are sorted by held expert - those routed
+    to experts held elsewhere sort last - and go through ONE ragged
+    product a projection (``lax.ragged_dot``, groups = held experts) over
+    a buffer of ALL N*k sorted rows: whatever the imbalance, every
+    assignment that lands here has its row, so no token is ever dropped.
+    The rows past the held experts' load belong to no group and cost the
+    product next to nothing (on the v5e, forward + backward at 8192
+    tokens, 8 held of 64, top-4: 4.6 ms with 32768 rows against 3.0 ms
+    with 4096, and a second branch with a shorter buffer for the usual
+    load bought 0.7 ms of a layer's 11.4: PERF.md section 6, PR 28).
+    Gather, products and the weighted sum back to tokens are scoped
+    `dispatch`, `experts`, `combine`.
+
+    Returns (y (N, d), counts (H,) assignments a held expert, elsewhere
+    () assignments routed away), the counts as float32."""
+    n, k = idx.shape
+    h = len(held)
+    m = n * k
+    local_of = _np.full((n_experts,), h, _np.int32)
+    local_of[_np.asarray(held)] = _np.arange(h)
+    with jax.named_scope("dispatch"):
+        # sorts and compares only: a TPU serialises a scatter
+        local = jnp.asarray(local_of)[idx.reshape(m)]    # h = held elsewhere
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        position = jnp.argsort(order).astype(jnp.int32)  # order's inverse
+        sizes = (local[:, None] == jnp.arange(h + 1)).sum(0, dtype=jnp.int32)
+        counts, elsewhere = sizes[:h], sizes[h]
+        here = counts.sum()
+        flat_w = weights.reshape(m, 1).astype(jnp.float32)
+        xs = _take_rows(x, order // k, position.reshape(n, k), here)
+        ws = _take_rows(flat_w, order, position.reshape(m, 1), here)
+    with jax.named_scope("experts"):
+        gu = lax.ragged_dot(xs, w_gate_up, counts)
+        f = gu.shape[-1] // 2
+        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+               * gu[:, f:].astype(jnp.float32)).astype(x.dtype)
+        out = lax.ragged_dot(act, w_down, counts)
+    with jax.named_scope("combine"):
+        out = (out.astype(jnp.float32) * ws).astype(x.dtype)
+        y = _sum_rows(out, position.reshape(n, k), order // k, here)
+    return y, counts.astype(jnp.float32), elsewhere.astype(jnp.float32)
+
+
+def token_choice_moe(x, router_w, bias, w_gate_up, w_down, *,
+                     held: Sequence[int], top_k: int, scale: float = 1.0,
+                     norm_topk_prob: bool = True):
+    """`topk_route` (scope `route`) + `held_expert_ffn` on (..., d)
+    tokens.  Returns (y like x, counts (H,), elsewhere ())."""
+    lead = x.shape[:-1]
+    flat = x.reshape(-1, x.shape[-1])
+    with jax.named_scope("route"):
+        idx, weights = topk_route(flat, router_w, bias, top_k, scale,
+                                  norm_topk_prob)
+    y, counts, elsewhere = held_expert_ffn(
+        flat, idx, weights, w_gate_up, w_down, held, router_w.shape[0])
+    return y.reshape(lead + (y.shape[-1],)), counts, elsewhere

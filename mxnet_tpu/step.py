@@ -302,10 +302,9 @@ class CompiledStep:
                     list(zip(frozen_params, frozen_shardings)):
                 nd_ = p._data[ctxs[0]]
                 nd_._set_jax(_place(nd_._jax, s))
-                if p._grad:
-                    g_nd = p._grad.get(ctxs[0])
-                    if g_nd is not None:
-                        g_nd._set_jax(_place(g_nd._jax, s))
+                g_nd = nd_._grad       # None: not allocated yet (lazy)
+                if g_nd is not None:
+                    g_nd._set_jax(_place(g_nd._jax, s))
         exchange = None
         if kv is not None and len(ctxs) > 1:
             # the eager exchange set: every trainable param crosses the
@@ -685,6 +684,34 @@ class CompiledStep:
             decays = jnp.asarray(_np.asarray(decay_rows, _np.float32))  # mxlint: disable=host-sync-in-hot-path
         return rescale, wds, lrs, decays
 
+    def _own_state(self, plan):
+        """Make the parameters, masters and optimizer slots this step's
+        own before they are donated: an array the step did not produce
+        may be aliased elsewhere (``set_data`` of a shared buffer) and is
+        copied - ONE AT A TIME, the copy stored back into its NDArray
+        before the next is made, so the first step of a model whose state
+        fills most of the device (10 GB of 16) holds the state once plus
+        one array, not twice.  Steady state: every array is the last
+        step's output, nothing is copied."""
+        spec, ctx0 = plan["spec"], plan["ctxs"][0]
+        holders = [p.data(ctx0) for p in plan["trainable"]] \
+            + [p.data(ctx0) for p in plan["frozen"]]
+        upd = self._trainer._updaters[0]
+        for pos, i in enumerate(plan["trainable_idx"]):
+            inner, w32 = spec["unpack"](upd.states[i],
+                                        plan["mp_flags"][pos])
+            holders.extend(inner)
+            if w32 is not None:
+                holders.append(w32)
+        for nd_ in holders:
+            if nd_._index is not None or nd_._vshape is not None \
+                    or id(nd_._jax) in self._owned:
+                continue            # a view: `donatable` copies its value
+            mine = jnp.array(nd_._jax, copy=True)
+            nd_._set_jax(mine)
+            self._owned_refs.append(mine)
+            self._owned.add(id(mine))
+
     def _gather_state(self, plan):
         tr = self._trainer
         spec = plan["spec"]
@@ -819,6 +846,7 @@ class CompiledStep:
                                         decay_rows is not None,
                                         metric_info, return_outs)
                     self._cache[key] = fn
+            self._own_state(plan)
             state = self._gather_state(plan)
 
             def donatable(a):

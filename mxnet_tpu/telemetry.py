@@ -83,7 +83,7 @@ from . import profiler as _profiler
 from .base import get_env
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "Registry", "registry",
+    "Counter", "ReadCounter", "Gauge", "Histogram", "Registry", "registry",
     "enabled", "tracing_enabled", "start_tracing", "stop_tracing",
     "Span", "phase", "rpc_span", "current_trace", "observe_phase",
     "FlightRecorder", "flight_recorder", "note_step",
@@ -132,6 +132,22 @@ class Counter:
 
     def snapshot(self) -> Dict[str, Any]:
         return {"type": self.kind, "value": self.value}
+
+
+class ReadCounter(Counter):
+    """A counter whose value lives elsewhere (a device buffer a compiled
+    step accumulates into) and is fetched by `read` only when asked for:
+    a snapshot pays the transfer, the step never does."""
+
+    def __init__(self, name: str, doc: str = "",
+                 labels: Optional[Dict[str, str]] = None, read=None):
+        super().__init__(name, doc, labels)
+        self._read = read
+
+    @property
+    def value(self):
+        got = self._read() if self._read is not None else None
+        return self._value if got is None else got
 
 
 class Gauge(Counter):
@@ -250,6 +266,14 @@ class Registry:
     def counter(self, name: str, doc: str = "",
                 labels: Optional[Dict[str, str]] = None) -> Counter:
         return self._get(Counter, name, doc, labels)
+
+    def read_counter(self, name: str, read, doc: str = "",
+                     labels: Optional[Dict[str, str]] = None) -> ReadCounter:
+        """Get-or-create a :class:`ReadCounter`; `read` replaces the
+        reader of an instrument that exists (a rebuilt model's buffers)."""
+        inst = self._get(ReadCounter, name, doc, labels)
+        inst._read = read
+        return inst
 
     def gauge(self, name: str, doc: str = "",
               labels: Optional[Dict[str, str]] = None) -> Gauge:

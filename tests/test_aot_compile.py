@@ -103,13 +103,13 @@ def _user_kernel():
         [((256, 256), jnp.float32)]
 
 
-def _heads(direction, shape, heads, mask=False):
+def _heads(direction, shape, heads, mask=False, causal=False):
     """multi_head_attention's entry: the packed (B, T, H*D) operands."""
     B, T, _ = shape
 
     def fwd(q, k, v):
         m = jnp.ones((B, 1, T, T), bool) if mask else None
-        return att.attention_heads(q, k, v, heads, mask=m)
+        return att.attention_heads(q, k, v, heads, mask=m, causal=causal)
 
     def bwd(q, k, v):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
@@ -117,6 +117,30 @@ def _heads(direction, shape, heads, mask=False):
 
     return (fwd if direction == "fwd" else bwd), \
         [(shape, jnp.bfloat16)] * 3
+
+
+def _held_experts(direction):
+    """GLM-4.7-Flash's expert layer on one chip's share at the cell's
+    sizes: 8192 tokens, top-4 of 64 experts, 8 held, width 1536
+    (parallel/moe.py: sorts, gathers and two ragged products, which
+    XLA:TPU expands into kernels of its own: `ragged-dot-metadata` and one
+    `ragged-dot-none` a product - forward 2, backward 2 again (recomputed
+    by no one here) + 2 by the rows + 2 by the weights)."""
+    from mxnet_tpu.parallel import moe
+
+    def fwd(x, router, correction, gate_up, down):
+        return moe.token_choice_moe(x, router, correction, gate_up, down,
+                                    held=tuple(range(8)), top_k=4,
+                                    scale=1.8)[0]
+
+    def bwd(*args):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 3, 4))(*args)
+
+    return (fwd if direction == "fwd" else bwd), [
+        ((8192, 2048), jnp.bfloat16), ((64, 2048), jnp.float32),
+        ((64,), jnp.float32), ((8, 2048, 3072), jnp.bfloat16),
+        ((8, 1536, 2048), jnp.bfloat16)]
 
 
 # (id, builder of (fn, [(shape, dtype), ...]), tpu_custom_calls expected,
@@ -143,6 +167,17 @@ CASES = [
      lambda: _flash("fwd", (8, 12, 512, 64), False), 1, False),
     ("bhtd-8x12x512x64-causal-gets-flash-bwd",
      lambda: _flash("bwd", (8, 12, 512, 64), True), 3, False),
+    # GLM-4.7-Flash's latent attention as multi_head_attention hands it
+    # over: causal, 20 heads of 256 lanes, 4096 positions - a head's whole
+    # K and V (2 MB each) resident beside the 256-row blocks
+    ("mla-2x20x4096x256-causal-gets-flash",
+     lambda: _heads("fwd", (2, 4096, 5120), 20, causal=True), 1, False),
+    ("mla-2x20x4096x256-causal-gets-flash-bwd",
+     lambda: _heads("bwd", (2, 4096, 5120), 20, causal=True), 3, False),
+    ("held-experts-8192x2048-top4-8of64",
+     lambda: _held_experts("fwd"), 3, False),
+    ("held-experts-8192x2048-top4-8of64-bwd",
+     lambda: _held_experts("bwd"), 8, False),
     # a mask, or a T that is no block multiple, keeps the composition:
     # the same program text as with the kernels switched off
     ("bert-base-8x12x512x64-masked-gets-xla",

@@ -604,6 +604,19 @@ SPECS.update({
                  rtol=1e-3, atol=1e-3,
                  ref=lambda x, g: x / np.sqrt(
                      (x * x).mean(-1, keepdims=True) + 1e-6)),
+    "rotary_embedding": S(
+        lambda: [f(2, 3, 8)], params={"num_heads": 2, "rotary_dim": 2,
+                                      "theta": 100.0},
+        ref=lambda x: np.concatenate(
+            [x.reshape(2, 3, 2, 4)[..., :2],
+             x.reshape(2, 3, 2, 4)[..., 2:3] * np.cos(np.arange(3))[
+                 None, :, None, None]
+             - x.reshape(2, 3, 2, 4)[..., 3:] * np.sin(np.arange(3))[
+                 None, :, None, None],
+             x.reshape(2, 3, 2, 4)[..., 3:] * np.cos(np.arange(3))[
+                 None, :, None, None]
+             + x.reshape(2, 3, 2, 4)[..., 2:3] * np.sin(np.arange(3))[
+                 None, :, None, None]], -1).reshape(2, 3, 8)),
     "L2Normalization": S(lambda: [f(3, 4)],
                          ref=lambda x: x / np.sqrt(
                              (x * x).sum(1, keepdims=True) + 1e-10)),
@@ -2060,6 +2073,8 @@ TESTED_ELSEWHERE = {
     "RNN": "tests/test_rnn.py",
     "CTCLoss": "tests/test_loss.py",
     "multi_head_attention": "tests/test_transformer.py",
+    "moe_token_choice": "tests/test_glm_moe_lite.py",
+    "moe_topk_choice": "tests/test_glm_moe_lite.py",
     "_contrib_interleaved_matmul_selfatt_qk": "tests/test_transformer.py",
     "_contrib_interleaved_matmul_selfatt_valatt": "tests/test_transformer.py",
     "_contrib_interleaved_matmul_encdec_qk": "tests/test_transformer.py",
