@@ -43,7 +43,8 @@ SIZES = {
     # long causal at head size 128; BERT-base's own: 32 rows a chip of
     # 512 tokens, 12 heads of 64, no mask
     "pallas": dict(cases=(((2, 8, 2048, 128), True),
-                          ((32, 12, 512, 64), False))),
+                          ((32, 12, 512, 64), False),
+                          ((2, 20, 4096, 256), True))),
     "serve_resnet50": dict(model="resnet50_v1", image=224, classes=1000,
                            rows=(1, 3, 8, 2, 16, 5, 4, 1)),
     "serve_decode": dict(prompts=((3, 1, 4, 1, 5), (9, 2, 6),

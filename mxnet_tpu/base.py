@@ -92,8 +92,8 @@ ENV_CATALOG: Dict[str, Any] = {
     "MX_STEP_TIMEOUT": ("", "Seconds a training step may stall before the watchdog thread dumps every thread's stack to stderr and exits the process with code 86, so tools/launch.py --restart on-failure restarts the rank from its last checkpoint; empty disables."),
     "MX_HEARTBEAT_FILE": ("", "Per-rank liveness file the fit loop atomically rewrites every batch; tools/launch.py --hang-timeout sets it per worker and reads the mtime to tell a slow rank (fresh file) from a wedged one (stale file, killed + restarted)."),
     "MX_RECORDIO_TOLERATE_CORRUPT": ("0", "1 = a corrupt/truncated .rec record (e.g. a tail torn by a mid-write crash) is skipped-and-counted (reader.corrupt_skipped) and reads end there, instead of raising OSError with the uri and byte offset."),
-    "MX_FLASH_BLOCK_Q": ("256", "Pallas flash-attention block unit, query rows: the kernels take T in multiples of it; causal calls use it as the block, others up to twice it (sweepable on hardware)."),
-    "MX_FLASH_BLOCK_K": ("256", "Pallas flash-attention block unit, key rows (as MX_FLASH_BLOCK_Q; up to four units of keys are taken as one block)."),
+    "MX_FLASH_BLOCK_Q": ("256", "Pallas flash-attention block unit, query rows: the kernels take T in multiples of it and choose their blocks from the shapes in whole units, up to twice it; a causal call cuts its diagonal to it (sweepable on hardware)."),
+    "MX_FLASH_BLOCK_K": ("256", "Pallas flash-attention block unit, key rows (as MX_FLASH_BLOCK_Q; a non-causal forward takes up to four units of keys as one block)."),
     "MX_TELEMETRY": ("1", "Runtime telemetry (mxnet_tpu/telemetry.py): 1 (default) records per-phase step histograms (data_wait/forward/backward/exchange/optimizer_apply/metric_update/metric_drain/retrace/compiled_step) into the process-wide instrument registry and appends one flight-recorder step record per training step (phase durations, dispatch/wire deltas, retry + NaN-guard hits, throughput); 0 disables both (spans become shared no-ops).  Engine counters (dispatch_count, wire_bytes, compiled_steps) live in the registry regardless - this flag gates only the span/record layer."),
     "MX_TELEMETRY_TRACE": ("", "Directory for per-process distributed trace files: when set, every span (step phases, kvstore client RPCs, server handling incl. retry/replay events, causally linked by wire-propagated trace/span IDs) is buffered and flushed to <dir>/trace-<role>-r<rank>-p<pid>.trace.json at process exit; tools/telemetry_dump.py merges the per-worker files into one chrome-trace timeline.  Empty disables span buffering (tests force it via telemetry.start_tracing())."),
     "MX_TELEMETRY_RING": ("256", "Flight-recorder capacity: the telemetry ring keeps the last N structured step records, dumped to MX_CRASH_DIR on watchdog/NaN/fit failure and summarized (step, throughput, last-exchange bytes) in the heartbeat file's JSON payload for the supervisor's fleet status table."),

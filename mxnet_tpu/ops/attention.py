@@ -30,13 +30,20 @@ does, in the order a call meets it:
 * **Blocks from the shapes** (`_Geometry.blocks`): a non-causal call takes
   512-row blocks where they divide and up to 1024 keys as one block (no
   online rescaling); longer or causal calls stream key blocks with
-  running (max, sum).
+  running (max, sum), in blocks sized from T, the lane width and the
+  fast memory their float32 tiles take.
+* **Causal calls do the causal work** (`_visits`): blocks above the
+  diagonal are never visited, blocks wholly below it run unmasked, and
+  only the blocks the diagonal crosses - cut to the smaller of the two
+  block sizes - pay for the mask.
 * **Backward** recomputes the probabilities from the saved logsumexp
   (FlashAttention-2) on transposed scores, keys down the sublanes, so
-  that `p.T @ dO` and `ds.T @ q` are plain products.  Where one key block
-  holds the sequence (non-causal, T ≤ 512) one kernel yields dq, dk and
-  dv from one pass over the scores; otherwise a second kernel over query
-  blocks makes dq.
+  that `p.T @ dO` and `ds.T @ q` are plain products.  One kernel over key
+  blocks yields dq, dk and dv from one pass over the scores (5 products):
+  dk and dv a key block, dq summed in float32 in fast memory across the
+  key blocks and written once.  q, dO and dq stay resident for that;
+  where they do not fit (`_Geometry.fused_backward`) a second kernel
+  over query blocks makes dq (7 products).
 * **Under a mesh** the kernels are opaque to GSPMD (jax refuses to lower
   a Mosaic kernel it would have to partition), so `CompiledStep` opens
   `attention_partition_scope(layout)` around its forward trace and the
@@ -62,18 +69,27 @@ __all__ = ["attention_core", "attention_heads", "flash_attention",
            "paged_attention_multi"]
 
 # kernel block sizes.  256 rows is the unit: the rule asks for multiples of
-# it, causal calls use it (a larger block wastes more of its masked half),
-# and the env can still sweep it on a chip.  A non-causal call takes the
-# blocks the shapes allow (`_Geometry.blocks`): on the v5e 512-row blocks
-# beat 256 (one BERT-base layer, forward + backward: 1.77 against 2.53 ms,
-# PERF.md section 6, PR 27) and one key block of up to 1024 rows beats a
-# loop with online rescaling (2.53 against 3.46 ms).
+# it, the blocks a call takes are whole units (`_Geometry.blocks` chooses
+# them from the shapes), the diagonal of a causal call is cut to it, and
+# the env can still sweep it on a chip.  On the v5e 512-row blocks beat 256
+# (one BERT-base layer, forward + backward: 1.77 against 2.53 ms, PERF.md
+# section 6, PR 27) and one key block of up to 1024 rows beats a loop with
+# online rescaling (2.53 against 3.46 ms).
 from ..base import get_env
 
 _BLOCK_Q = get_env("MX_FLASH_BLOCK_Q", 256, int)
 _BLOCK_K = get_env("MX_FLASH_BLOCK_K", 256, int)
 
 _LANES = 128            # a vector register's lanes: the packed block width
+
+# Fast memory (VMEM) of one TensorCore, and what Mosaic grants a kernel
+# that asks for nothing.  A kernel whose blocks, scratch and tiles need
+# more than the grant states its need (`_Geometry.mosaic`); the backward
+# keeps q, dO and dq resident only where that need stays under 3/4 of the
+# memory (`_Geometry.fused_backward`).  The v5e's numbers; tier-1 holds
+# them to its compiler (tests/test_aot_compile.py).
+_FAST_MEMORY = 128 << 20
+_FAST_MEMORY_GRANT = 16 << 20
 
 
 def _on_tpu() -> bool:
@@ -238,6 +254,7 @@ class _Geometry:
             self.B, self.H, self.Tq, self.D = q.shape
             self.width = self.D
         self.Tk = k.shape[1] if self.packed else k.shape[2]
+        self.itemsize = q.dtype.itemsize
         self.per_block = self.width // self.D
         if self.H % self.per_block:
             raise ValueError("%d heads of size %d do not fill %d-lane "
@@ -276,15 +293,66 @@ class _Geometry:
 
     def blocks(self, causal):
         """(query rows, key rows of the forward and dq kernels, key rows
-        of the dk/dv kernel) a block.  Causal: the unit.  Otherwise
-        twice the unit where it divides, and the forward takes up to
-        four units of keys as ONE block: no online rescaling (at T = 512
-        a head's whole K and V are 64 KB each)."""
-        if causal:
-            return _BLOCK_Q, _BLOCK_K, _BLOCK_K
-        bq = 2 * _BLOCK_Q if self.Tq % (2 * _BLOCK_Q) == 0 else _BLOCK_Q
-        bk = 2 * _BLOCK_K if self.Tk % (2 * _BLOCK_K) == 0 else _BLOCK_K
-        return bq, (self.Tk if self.Tk <= 4 * _BLOCK_K else bk), bk
+        of the dk/dv kernel) a block: twice the unit where it divides T
+        and a step's tiles at that size take no more than a quarter of
+        fast memory (any width a head has had here).  A non-causal forward
+        takes up to four units of keys as ONE block: no online rescaling
+        (at T = 512 a head's whole K and V are 64 KB each).  A causal call
+        takes the same rows as a long non-causal one (`_visits` says which
+        blocks it visits): on the v5e, T = 4096 at 256 lanes, forward +
+        backward, 512-row blocks took 10.0 ms where 256-row blocks took
+        12.1 and 1024 rows on either side 10.8-10.9 (larger blocks waste
+        more of the diagonal; PERF.md section 6, PR 29)."""
+        def rows(seq, unit):
+            fits = self._working(2 * unit, 2 * unit, 4) \
+                <= _FAST_MEMORY // 4
+            return 2 * unit if seq % (2 * unit) == 0 and fits else unit
+
+        bq, bk = rows(self.Tq, _BLOCK_Q), rows(self.Tk, _BLOCK_K)
+        one_block = not causal and self.Tk <= 4 * _BLOCK_K
+        return bq, (self.Tk if one_block else bk), bk
+
+    def _bytes(self, rows, itemsize=None):
+        return rows * self.width * (itemsize or self.itemsize)
+
+    def fused_backward(self, block_kv, block_q):
+        """Fast memory of the one-kernel backward, in bytes, or None where
+        it passes 3/4 of `_FAST_MEMORY` and the two-kernel form is taken:
+        q, dO and the dq block resident (two buffers each: the pipeline's),
+        their statistics (8 sublanes a value), the float32 dq sum where
+        more than one key block adds to it, and the working set of a
+        step."""
+        need = (6 * self._bytes(self.Tq)
+                + 4 * self.per_block * 8 * self.Tq * 4
+                + (self._bytes(self.Tq, 4) if self.Tk > block_kv else 0)
+                + self._working(block_kv, block_q, 4))
+        return need if need <= _FAST_MEMORY // 4 * 3 else None
+
+    def streamed(self, seq, held, stream):
+        """Fast memory of a kernel that keeps two `seq`-row operands
+        resident (the forward's and the dq kernel's K and V, the dk/dv
+        kernel's q and dO) beside `held`-row blocks."""
+        return (4 * self._bytes(seq)
+                + 4 * self.per_block * 8 * seq * 4
+                + self._working(held, stream, 3))
+
+    def _working(self, held, stream, tiles):
+        """Blocks of `held` rows (four operands, two buffers each), their
+        float32 accumulators with a loop's second copy, and `tiles`
+        float32 score tiles with their casts."""
+        return (8 * self._bytes(held) + 4 * self._bytes(held, 4)
+                + tiles * held * stream * (4 + self.itemsize)
+                + self._bytes(stream, 4))
+
+    @staticmethod
+    def mosaic(need):
+        """pallas_call's compiler parameters for a kernel that needs
+        `need` bytes of fast memory: none under Mosaic's own grant."""
+        if need <= _FAST_MEMORY_GRANT:
+            return {}
+        from jax.experimental.pallas import tpu as pltpu
+        return {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=min(need + need // 4, _FAST_MEMORY // 8 * 7))}
 
 
 def _head_masks(per_block, d):
@@ -355,11 +423,44 @@ def _rows(start_block, block):
     return pl.ds(pl.multiple_of(start_block * block, block), block)
 
 
-def _loop(lo, hi, body, init):
-    """fori_loop, or the body once where the trip count is one."""
-    if isinstance(lo, int) and isinstance(hi, int) and hi - lo == 1:
-        return body(lo, init)
-    return lax.fori_loop(lo, hi, body, init)
+def _loop(lo, count, body, init):
+    """`count` steps of `body` from `lo`: fori_loop, the body once where
+    the count is one, nothing where it is zero."""
+    if isinstance(count, int) and count <= 1:
+        return body(lo, init) if count else init
+    return lax.fori_loop(lo, lo + count, body, init)
+
+
+def _visits(causal, start, held, stream, seq, held_is_query):
+    """What a block of `held` rows from `start` visits of the other,
+    streamed operand of `seq` rows: segments (first block, blocks, rows a
+    block, masked), in the order to run them.  Non-causal: every block of
+    `stream` rows.  Causal, `held_is_query` (forward, dq): the keys before
+    `start` in blocks of `stream` rows, what `stream` does not divide in
+    units (the smaller of the two blocks), then the units the diagonal
+    crosses, masked; key 0 comes first, so the running max is finite from
+    the first step on and no row is ever fully masked in all it has seen.
+    Causal, the held rows are keys (dk/dv): the diagonal's units, masked,
+    then units up to a multiple of `stream`, then the queries after it.
+    Blocks above the diagonal are not visited."""
+    if not causal:
+        return [(0, seq // stream, stream, False)]
+    unit = math.gcd(held, stream)
+    per = stream // unit
+    diagonal = (start // unit, held // unit, unit, True)
+    if held_is_query:
+        whole = start // stream
+        below = [(0, whole, stream, False)]
+        if per > 1:
+            below.append((whole * per, start // unit - whole * per, unit,
+                          False))
+        return below + [diagonal]
+    end = (start + held) // unit
+    whole = (end + per - 1) // per
+    below = [(whole, seq // stream - whole, stream, False)]
+    if per > 1:
+        below.insert(0, (end, whole * per - end, unit, False))
+    return [diagonal] + below
 
 
 # ---------------------------------------------------------------------------
@@ -374,42 +475,38 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     import jax.experimental.pallas as pl
 
     block_q, width = q_ref.shape
-    num_kb = k_ref.shape[0] // block_k
-    q_idx = pl.program_id(2)
+    q0 = pl.program_id(2) * block_q
     fold = _exact_scale(scale)
     q_all = q_ref[:] * scale if fold else q_ref[:]
-    if causal:
-        # only key blocks at or before this query block contribute
-        # (ceil-div: correct for any block_q/block_k ratio).  The first
-        # block holds key 0, which every row sees: the running max is
-        # finite from then on and no row is ever fully masked.
-        num_kb = jnp.minimum(
-            ((q_idx + 1) * block_q + block_k - 1) // block_k, num_kb)
+    visits = _visits(causal, q0, block_q, block_k, k_ref.shape[0], True)
     out = None
     for g, mask in enumerate(_head_masks(width // d, d)):
         q = _only(q_all, mask)
 
-        def body(kb, carry, q=q):
-            m, l, acc = carry
-            rows = _rows(kb, block_k)
-            s = _dot_nt(q, k_ref[rows, :])
-            if not fold:
-                s = s * scale
-            if causal:
-                s = _mask_causal(s, q_idx * block_q, kb * block_k, 0)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-            acc_new = alpha * acc + _dot(p.astype(v_ref.dtype),
-                                         v_ref[rows, :])
-            return m_new, l_new, acc_new
+        def visit(block, masked, q=q):
+            def body(kb, carry):
+                m, l, acc = carry
+                rows = _rows(kb, block)
+                s = _dot_nt(q, k_ref[rows, :])
+                if not fold:
+                    s = s * scale
+                if masked:
+                    s = _mask_causal(s, q0, kb * block, 0)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+                acc_new = alpha * acc + _dot(p.astype(v_ref.dtype),
+                                             v_ref[rows, :])
+                return m_new, l_new, acc_new
+            return body
 
-        m, l, acc = _loop(
-            0, num_kb, body,
-            (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
-             jnp.zeros((block_q, 1), jnp.float32),
-             jnp.zeros((block_q, width), jnp.float32)))
+        carry = (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
+                 jnp.zeros((block_q, 1), jnp.float32),
+                 jnp.zeros((block_q, width), jnp.float32))
+        for lo, count, block, masked in visits:
+            carry = _loop(lo, count, visit(block, masked), carry)
+        m, l, acc = carry
         out = _merge(out, acc / l, mask)
         # logsumexp residual for the backward: p = exp(s - lse)
         lse_ref[g] = _to_row(m + jnp.log(l))
@@ -436,6 +533,7 @@ def _flash_fwd_res(q, k, v, scale, causal, heads=None):
             _sds(geo.shape(geo.Tq), q.dtype, q),
             _sds((geo.B, geo.H, 1, geo.Tq), jnp.float32, q),
         ],
+        **geo.mosaic(geo.streamed(geo.Tk, block_q, block_k)),
     )(q, k, v)
 
 
@@ -450,11 +548,14 @@ def _flash_fwd(q, k, v, scale, causal, heads=None):
 # O(L) memory — the T×T score matrix is never materialized.  The kernel
 # over key blocks works on TRANSPOSED scores (keys down the sublanes), so
 # dv = p.T @ dO and dk = ds.T @ q are plain products and the per-query
-# statistics broadcast as rows.  Where one key block holds the whole
-# sequence it yields dq as well (one product with a transposed operand):
-# scores and probabilities are computed once, 5 products where the two
-# kernels make 7.  Longer sequences add the kernel over query blocks for
-# dq.  delta = rowsum(dO * O), the softmax-jacobian correction term, is one
+# statistics broadcast as rows.  It yields dq as well (one product with a
+# transposed operand): scores and probabilities are computed once, 5
+# products where two kernels make 7.  With one key block dq is written as
+# it comes; with more, each key block adds its part to a float32 sum in
+# fast memory (the key-block axis of the grid runs in order) and the last
+# one writes it out.  Where q, dO and dq do not fit in fast memory
+# (`_Geometry.fused_backward`) the kernel over query blocks makes dq.
+# delta = rowsum(dO * O), the softmax-jacobian correction term, is one
 # small XLA pass outside.
 # ---------------------------------------------------------------------------
 
@@ -464,92 +565,110 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     import jax.experimental.pallas as pl
 
     block_q, width = q_ref.shape
-    num_kb = k_ref.shape[0] // block_k
-    q_idx = pl.program_id(2)
+    q0 = pl.program_id(2) * block_q
     fold = _exact_scale(scale)
     q_all = q_ref[:] * scale if fold else q_ref[:]
     do_all = do_ref[:]
-    if causal:
-        num_kb = jnp.minimum(
-            ((q_idx + 1) * block_q + block_k - 1) // block_k, num_kb)
+    visits = _visits(causal, q0, block_q, block_k, k_ref.shape[0], True)
     out = None
     for g, mask in enumerate(_head_masks(width // d, d)):
         q, do = _only(q_all, mask), _only(do_all, mask)
         lse, delta = _to_col(lse_ref[g]), _to_col(delta_ref[g])
 
-        def body(kb, dq, q=q, do=do, lse=lse, delta=delta):
-            rows = _rows(kb, block_k)
-            k_blk = k_ref[rows, :]
-            s = _dot_nt(q, k_blk)
-            if not fold:
-                s = s * scale
-            if causal:
-                s = _mask_causal(s, q_idx * block_q, kb * block_k, 0)
-            p = jnp.exp(s - lse)
-            dp = _dot_nt(do, v_ref[rows, :])
-            ds = (p * (dp - delta)).astype(k_ref.dtype)
-            return dq + _dot(ds, k_blk)
+        def visit(block, masked, q=q, do=do, lse=lse, delta=delta):
+            def body(kb, dq):
+                rows = _rows(kb, block)
+                k_blk = k_ref[rows, :]
+                s = _dot_nt(q, k_blk)
+                if not fold:
+                    s = s * scale
+                if masked:
+                    s = _mask_causal(s, q0, kb * block, 0)
+                p = jnp.exp(s - lse)
+                dp = _dot_nt(do, v_ref[rows, :])
+                ds = (p * (dp - delta)).astype(k_ref.dtype)
+                return dq + _dot(ds, k_blk)
+            return body
 
-        dq = _loop(0, num_kb, body,
-                   jnp.zeros((block_q, width), jnp.float32))
+        dq = jnp.zeros((block_q, width), jnp.float32)
+        for lo, count, block, masked in visits:
+            dq = _loop(lo, count, visit(block, masked), dq)
         out = _merge(out, dq * scale, mask)
     dq_ref[:] = out.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dq_ref=None, *, scale, causal,
-                          block_q, d):
+                          dk_ref, dv_ref, dq_ref=None, dq_sum=None, *,
+                          scale, causal, block_q, d):
     # lse/delta: (per_block, 1, seq_q); the scores here are (block_k,
-    # block_q).  With dq_ref the block holds every key: dq (seq_q, width)
-    # comes from the same scores.
+    # block_q).  With dq_ref, dq (seq_q, width) comes from the same
+    # scores: written a query block at a time where the one key block
+    # holds every key, else summed over the key blocks in dq_sum (float32
+    # scratch) and written by the last.
     import jax.experimental.pallas as pl
 
     block_k, width = k_ref.shape
-    num_qb = q_ref.shape[0] // block_q
     k_idx = pl.program_id(2)
+    k0 = k_idx * block_k
     fold = _exact_scale(scale)
     k_all = k_ref[:] * scale if fold else k_ref[:]
     v_all = v_ref[:]
-    # causal: only query blocks at or after this key block contribute
-    qb_start = (k_idx * block_k) // block_q if causal else 0
+    visits = _visits(causal, k0, block_k, block_q, q_ref.shape[0], False)
+    if dq_sum is not None:
+        @pl.when(k_idx == 0)
+        def _():
+            dq_sum[:] = jnp.zeros(dq_sum.shape, dq_sum.dtype)
     dk_out = dv_out = None
     for g, mask in enumerate(_head_masks(width // d, d)):
         k, v = _only(k_all, mask), _only(v_all, mask)
 
-        def body(qb, carry, k=k, v=v, g=g, mask=mask):
-            dk, dv = carry
-            rows = _rows(qb, block_q)
-            q_blk, do_blk = q_ref[rows, :], do_ref[rows, :]
-            s = _dot_nt(k, q_blk)
-            if not fold:
-                s = s * scale
-            if causal:
-                s = _mask_causal(s, qb * block_q, k_idx * block_k, 1)
-            p = jnp.exp(s - lse_ref[g, :, rows])
-            dv_new = dv + _dot(p.astype(do_blk.dtype), do_blk)
-            dp = _dot_nt(v, do_blk)
-            ds = (p * (dp - delta_ref[g, :, rows])).astype(q_blk.dtype)
-            if dq_ref is not None:
-                # k carries the folded scale already; else scale here
-                dq = _dot_tn(ds, k) if fold else _dot_tn(ds, k) * scale
-                dq_ref[rows, :] = _merge(
-                    None if g == 0 else dq_ref[rows, :],
-                    dq.astype(dq_ref.dtype), mask)
-            return dk + _dot(ds, q_blk), dv_new
+        def visit(block, masked, k=k, v=v, g=g, mask=mask):
+            def body(qb, carry):
+                dk, dv = carry
+                rows = _rows(qb, block)
+                q_blk, do_blk = q_ref[rows, :], do_ref[rows, :]
+                s = _dot_nt(k, q_blk)
+                if not fold:
+                    s = s * scale
+                if masked:
+                    s = _mask_causal(s, qb * block, k0, 1)
+                p = jnp.exp(s - lse_ref[g, :, rows])
+                dv_new = dv + _dot(p.astype(do_blk.dtype), do_blk)
+                dp = _dot_nt(v, do_blk)
+                ds = (p * (dp - delta_ref[g, :, rows])).astype(q_blk.dtype)
+                if dq_sum is not None:
+                    # the other heads' lanes of k are zero: parts add up
+                    dq_sum[rows, :] += _dot_tn(ds, k)
+                elif dq_ref is not None:
+                    # k carries the folded scale already; else scale here
+                    dq = _dot_tn(ds, k) if fold else _dot_tn(ds, k) * scale
+                    dq_ref[rows, :] = _merge(
+                        None if g == 0 else dq_ref[rows, :],
+                        dq.astype(dq_ref.dtype), mask)
+                return dk + _dot(ds, q_blk), dv_new
+            return body
 
-        dk, dv = _loop(qb_start, num_qb, body,
-                       (jnp.zeros((block_k, width), jnp.float32),
-                        jnp.zeros((block_k, width), jnp.float32)))
+        carry = (jnp.zeros((block_k, width), jnp.float32),
+                 jnp.zeros((block_k, width), jnp.float32))
+        for lo, count, block, masked in visits:
+            carry = _loop(lo, count, visit(block, masked), carry)
+        dk, dv = carry
         dk_out = _merge(dk_out, dk * scale, mask)
         dv_out = _merge(dv_out, dv, mask)
     dk_ref[:] = dk_out.astype(dk_ref.dtype)
     dv_ref[:] = dv_out.astype(dv_ref.dtype)
+    if dq_sum is not None:
+        @pl.when(k_idx == pl.num_programs(2) - 1)
+        def _():
+            dq = dq_sum[:] if fold else dq_sum[:] * scale
+            dq_ref[:] = dq.astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
     """(dq, dk, dv) from the forward's residuals and the cotangent `g`
     of its output.  lse: (B, H, 1, Tq) float32."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     geo = _Geometry(q, k, heads)
     block_q, block_k, block_kv = geo.blocks(causal)
@@ -561,10 +680,12 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
     else:
         delta = prod.sum(-1)
     delta = delta[:, :, None, :]
-    # one key block holds the sequence: its kernel yields dq too
-    fused = not causal and geo.Tk <= 2 * _BLOCK_K
-    if fused:
-        block_kv = geo.Tk
+    # q, dO and dq resident: the dk/dv kernel yields dq too
+    need = geo.fused_backward(block_kv, block_q)
+    fused = need is not None
+    summed = fused and geo.Tk > block_kv
+    if not fused:
+        need = geo.streamed(geo.Tq, block_kv, block_q)
     key_tile = geo.tile(block_kv, True)
     whole_q = geo.tile(geo.Tq, False)
     grads = pl.pallas_call(
@@ -578,6 +699,9 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
         out_shape=[_sds(geo.shape(geo.Tk), k.dtype, q),
                    _sds(geo.shape(geo.Tk), v.dtype, q)]
         + [_sds(geo.shape(geo.Tq), q.dtype, q)] * fused,
+        scratch_shapes=[pltpu.VMEM((geo.Tq, geo.width), jnp.float32)]
+        * summed,
+        **geo.mosaic(need),
     )(q, k, v, g, lse, delta)
     if fused:
         dk, dv, dq = grads
@@ -594,6 +718,7 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
                   geo.stats(block_q, True), geo.stats(block_q, True)],
         out_specs=q_tile,
         out_shape=_sds(geo.shape(geo.Tq), q.dtype, q),
+        **geo.mosaic(geo.streamed(geo.Tk, block_q, block_k)),
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
 

@@ -145,12 +145,12 @@ def _held_experts(direction):
 
 # (id, builder of (fn, [(shape, dtype), ...]), tpu_custom_calls expected,
 #  whether the text must equal attention_impl_scope("xla")'s).  The
-# backward is the dk/dv kernel and the dq kernel - or one kernel for all
-# three where one key block holds the sequence (non-causal, T <= 512).
+# backward is ONE kernel for dq, dk and dv at any length, causal or not -
+# or the dk/dv kernel and the dq kernel where q, dO and dq do not fit in
+# fast memory (`_Geometry.fused_backward`).
 CASES = [
     ("flash-%s-%s-%s" % (d, "x".join(map(str, s)), "causal" if c else "full"),
-     lambda d=d, s=s, c=c: _flash(d, s, c),
-     1 if d == "fwd" else 3 if c or s[2] > 512 else 2, False)
+     lambda d=d, s=s, c=c: _flash(d, s, c), 1 if d == "fwd" else 2, False)
     for s in ((8, 8, 512, 128), (2, 8, 2048, 128))
     for c in (True, False)
     for d in ("fwd", "bwd")
@@ -166,14 +166,24 @@ CASES = [
     ("bhtd-8x12x512x64-gets-flash",
      lambda: _flash("fwd", (8, 12, 512, 64), False), 1, False),
     ("bhtd-8x12x512x64-causal-gets-flash-bwd",
-     lambda: _flash("bwd", (8, 12, 512, 64), True), 3, False),
+     lambda: _flash("bwd", (8, 12, 512, 64), True), 2, False),
     # GLM-4.7-Flash's latent attention as multi_head_attention hands it
     # over: causal, 20 heads of 256 lanes, 4096 positions - a head's whole
-    # K and V (2 MB each) resident beside the 256-row blocks
+    # K and V (2 MB each) resident in the forward; q, dO and dq (2 MB
+    # each, two buffers) and dq's float32 sum (4 MB) in the backward
     ("mla-2x20x4096x256-causal-gets-flash",
      lambda: _heads("fwd", (2, 4096, 5120), 20, causal=True), 1, False),
     ("mla-2x20x4096x256-causal-gets-flash-bwd",
-     lambda: _heads("bwd", (2, 4096, 5120), 20, causal=True), 3, False),
+     lambda: _heads("bwd", (2, 4096, 5120), 20, causal=True), 2, False),
+    # the fast-memory arithmetic, held to what Mosaic accepts: the longest
+    # causal call of 256 lanes whose q, dO, dq and float32 dq sum stay
+    # resident (21,248 rows: 89.3 of the 96 MiB `fused_backward` allows),
+    # and the next one that divides into 512-row blocks, which must fall
+    # back to the dk/dv kernel and the dq kernel
+    ("long-1x1x21248x256-causal-keeps-one-bwd-kernel",
+     lambda: _flash("bwd", (1, 1, 21248, 256), True), 2, False),
+    ("long-1x1x21504x256-causal-falls-back-to-two",
+     lambda: _flash("bwd", (1, 1, 21504, 256), True), 3, False),
     ("held-experts-8192x2048-top4-8of64",
      lambda: _held_experts("fwd"), 3, False),
     ("held-experts-8192x2048-top4-8of64-bwd",

@@ -45,19 +45,36 @@ def test_attention_core_matches_numpy():
 # (B, H, T, D) as attention_core takes it and the packed (B, T, H*D) that
 # multi_head_attention holds.
 FLASH_CASES = [
-    pytest.param(D, causal, dtype, layout,
+    pytest.param(D, causal, dtype, layout, 512,
                  id="d%d-%s-%s-%s" % (D, "causal" if causal else "full",
                                       dtype, layout))
     for D in (64, 128) for causal in (False, True)
-    for dtype in ("float32", "bfloat16") for layout in ("bhtd", "packed")]
+    for dtype in ("float32", "bfloat16") for layout in ("bhtd", "packed")
+] + [
+    # causal calls that cross more than one block in both directions: the
+    # unmasked blocks below the diagonal, the masked ones on it, and the
+    # one backward kernel summing dq over its key blocks.  768 is a
+    # multiple of the 256-row unit only; the others of the 512-row block.
+    pytest.param(D, True, dtype, layout, T,
+                 id="d%d-causal-%s-%s-t%d" % (D, dtype, layout, T))
+    for T in (768, 1024, 2048) for D in (128, 256)
+    for dtype in ("float32", "bfloat16") for layout in ("bhtd", "packed")
+    if T == 1024 or (D, dtype, layout) in (
+        (128, "float32", "bhtd"), (128, "bfloat16", "packed"),
+        (256, "bfloat16", "bhtd"), (256, "float32", "packed"))
+] + [
+    # ... and a long non-causal one: the same sum without a diagonal
+    pytest.param(128, False, "bfloat16", "packed", 2048,
+                 id="d128-full-bfloat16-packed-t2048")]
 
 
-def _flash_case(D, dtype, layout, seed):
+def _flash_case(D, dtype, layout, seed, T=512):
     """(q, k, v, g) as float32 arrays already rounded to `dtype`, the
-    flash call on them in `layout`, and its float32 tolerance."""
+    flash call on them in `layout`, and its float32 tolerance.  The long
+    lengths run one row of one or two heads: interpret mode is slow."""
     import jax.numpy as jnp
     from mxnet_tpu.ops import attention as att
-    B, H, T = 2, 4, 512
+    B, H = (2, 4) if T == 512 else (1, 2 if T <= 1024 else 1)
     rng = np.random.RandomState(seed)
     q, k, v, g = (jnp.asarray(rng.randn(B, H, T, D), dtype)
                   .astype(jnp.float32) for _ in range(4))
@@ -79,11 +96,12 @@ def _flash_case(D, dtype, layout, seed):
     return (q, k, v, g), call
 
 
-@pytest.mark.parametrize("D,causal,dtype,layout", FLASH_CASES)
-def test_flash_forward_matches_jnp_cpu_interpret(D, causal, dtype, layout):
+@pytest.mark.parametrize("D,causal,dtype,layout,T", FLASH_CASES)
+def test_flash_forward_matches_jnp_cpu_interpret(D, causal, dtype, layout,
+                                                 T):
     """Output and logsumexp residual against the composition."""
     from mxnet_tpu.ops import attention as att
-    (q, k, v, _), call = _flash_case(D, dtype, layout, seed=0)
+    (q, k, v, _), call = _flash_case(D, dtype, layout, seed=0, T=T)
     scale = 1.0 / np.sqrt(D)
     out, lse = call(att._flash_fwd, q, k, v, scale, causal)
     assert out.dtype == np.dtype(dtype) or str(out.dtype) == dtype
@@ -103,8 +121,9 @@ def test_flash_forward_matches_jnp_cpu_interpret(D, causal, dtype, layout):
                        else 0.05), np.abs(lse - lse_ref).max()
 
 
-@pytest.mark.parametrize("D,causal,dtype,layout", FLASH_CASES)
-def test_flash_backward_matches_jnp_cpu_interpret(D, causal, dtype, layout):
+@pytest.mark.parametrize("D,causal,dtype,layout,T", FLASH_CASES)
+def test_flash_backward_matches_jnp_cpu_interpret(D, causal, dtype, layout,
+                                                  T):
     """The blockwise Pallas backward (recompute-from-LSE, O(L) memory) must
     produce the same dq/dk/dv as differentiating the jnp composition;
     bf16 inputs (the MXU-native training dtype) give bf16 gradients near
@@ -112,7 +131,7 @@ def test_flash_backward_matches_jnp_cpu_interpret(D, causal, dtype, layout):
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import attention as att
-    (q, k, v, g), call = _flash_case(D, dtype, layout, seed=1)
+    (q, k, v, g), call = _flash_case(D, dtype, layout, seed=1, T=T)
     scale = 1.0 / np.sqrt(D)
 
     def loss(q, k, v):
@@ -129,6 +148,75 @@ def test_flash_backward_matches_jnp_cpu_interpret(D, causal, dtype, layout):
         rel = np.abs(np.asarray(a) - np.asarray(b)).max() \
             / max(np.abs(np.asarray(b)).max(), 1e-6)
         assert rel < (2e-4 if dtype == "float32" else 0.05), (name, rel)
+
+
+@pytest.mark.parametrize("D,causal,dtype,layout,T", [
+    pytest.param(128, True, "float32", "bhtd", 1024, id="causal-t1024"),
+    pytest.param(64, False, "bfloat16", "packed", 512, id="full-t512")])
+def test_flash_backward_two_kernel_form_matches_jnp(monkeypatch, D, causal,
+                                                    dtype, layout, T):
+    """Where q, dO and dq do not fit in fast memory the backward is the
+    dk/dv kernel and the dq kernel; no shape a CPU can interpret is that
+    long, so the memory is taken away here."""
+    from mxnet_tpu.ops import attention as att
+    monkeypatch.setattr(att, "_FAST_MEMORY", 0)
+    x = np.zeros((1, 1, T, D), dtype="float32")
+    geo = att._Geometry(x, x, None)
+    block_q, _, block_kv = geo.blocks(causal)
+    assert geo.fused_backward(block_kv, block_q) is None
+    test_flash_backward_matches_jnp_cpu_interpret(D, causal, dtype, layout,
+                                                  T)
+
+
+def test_flash_blocks_of_the_bert_shapes_are_pinned():
+    """What the BERT cells' calls take - 512 positions, 12 heads of 64,
+    non-causal: 512-row blocks, one key block, so one backward kernel
+    that writes dq as it comes - is what it was before the causal calls
+    got blocks of their own."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as att
+    packed = jnp.zeros((32, 512, 768), jnp.bfloat16)   # multi_head_attention
+    assert att._Geometry(packed, packed, 12).blocks(False) == (512, 512, 512)
+    bhtd = jnp.zeros((32, 12, 512, 64), jnp.bfloat16)  # attention_core
+    assert att._Geometry(bhtd, bhtd, None).blocks(False) == (512, 512, 512)
+    # ... and neither asks Mosaic for more fast memory than it grants
+    geo = att._Geometry(packed, packed, 12)
+    assert att._Geometry.mosaic(geo.fused_backward(512, 512)) == {}
+    assert att._Geometry.mosaic(geo.streamed(512, 512, 512)) == {}
+
+
+def test_flash_with_lse_backpropagates_the_lse_cotangent():
+    """Ring attention's building block, causal over two key blocks, with
+    cotangents on BOTH outputs: the lse term runs the same backward
+    kernel (dq summed over the key blocks) and must match the
+    composition's gradient."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as att
+    B, H, T, D = 1, 2, 1024, 128
+    rng = np.random.RandomState(5)
+    q, k, v, g = (jnp.asarray(rng.randn(B, H, T, D), jnp.float32)
+                  for _ in range(4))
+    h = jnp.asarray(rng.randn(B, H, T), jnp.float32)
+    scale = 1.0 / np.sqrt(D)
+    assert att._Geometry(q, k, None).blocks(True)[2] * 2 == T
+
+    def loss(q, k, v):
+        out, lse = att.flash_attention_with_lse(q, k, v, scale, True)
+        return jnp.sum(out * g) + jnp.sum(lse * h)
+
+    def loss_ref(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+        return jnp.sum(att._attention_jnp(q, k, v, scale, True) * g) \
+            + jnp.sum(jax.nn.logsumexp(s, axis=-1) * h)
+
+    got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        rel = np.abs(np.asarray(a) - np.asarray(b)).max() \
+            / max(np.abs(np.asarray(b)).max(), 1e-6)
+        assert rel < 2e-4, (name, rel)
 
 
 def test_flash_rule_is_stated_once():
