@@ -74,7 +74,7 @@ ENV_CATALOG: Dict[str, Any] = {
     "MX_GRAD_COMPRESS": ("", "Default gradient-wire compression for Trainers constructed without explicit compression_params: 'int8' (per-block symmetric int8 + error feedback, ~3.9x fewer exchange bytes), '2bit' (reference +-threshold/0 levels + error feedback), or 'bf16' (pure cast, half the bytes).  Empty ships full-width floats.  Launch scripts flip it fleet-wide; per-Trainer compression_params always wins."),
     "MX_GRAD_COMPRESS_BLOCK": ("256", "Elements per int8 scale block for 'int8' gradient compression: each block of this many gradient elements shares one f32 scale (max|block|/127), so the wire payload is n + 4n/block bytes per n-element gradient.  Smaller blocks track outliers tighter at more scale overhead."),
     "MX_STEP_COMPILE": ("0", "1 = whole-program compiled train step: loss forward, backward, the bucketed (int8/2bit error-feedback quantized) gradient exchange, the fused multi-tensor optimizer apply and device-side metric accumulation trace into ONE donated jax.jit per step (mxnet_tpu/step.py CompiledStep; Module.fit picks it up automatically).  First call traces, a shape/dtype change retraces, lr/wd arrive as traced scalars so schedulers never recompile.  Eager remains the debug path; the PS/dist_async transport, unsupported optimizers, grad_req='add' and NaN-policy-armed runs fall back to the eager pipeline automatically."),
-    "MX_STEP_SCAN": ("0", "N>1 = scan-window size for the compiled step lane's window consumers (mxnet_tpu.step.scan_window(): bench.py --eager, tools/dispatch_count.py --compiled, and any harness driving CompiledStep.run_window): N prefetched batches stay on device per host round-trip, the step body runs under one lax.scan, and the window costs 1-2 dispatches total (batch transfer + window launch) instead of N; gradient accumulation folds into the scanned body via run_window(accum=k).  Module.fit dispatches per batch regardless (its iterator/callback contract is per-batch).  0/1 = one dispatch per step."),
+    "MX_STEP_SCAN": ("0", "N>1 = scan-window size for the compiled step lane's window consumers (mxnet_tpu.step.scan_window(): tools/dispatch_count.py --compiled and any harness driving CompiledStep.run_window): N prefetched batches stay on device per host round-trip, the step body runs under one lax.scan, and the window costs 1-2 dispatches total (batch transfer + window launch) instead of N; gradient accumulation folds into the scanned body via run_window(accum=k).  Module.fit dispatches per batch regardless (its iterator/callback contract is per-batch).  0/1 = one dispatch per step."),
     "MX_MESH_AXES": ("", "Named mesh axes for the SpecLayout sharded training lane (mxnet_tpu/parallel/speclayout.py), as comma-separated name[=size] tokens, e.g. 'data,fsdp=2' or 'data,fsdp=2,tp=2'.  When set, CompiledStep/Trainer.make_compiled_step build the step as ONE donated SPMD jit over this mesh: the batch splits over data*fsdp, parameters + optimizer state live sheet-sharded (fsdp) / tensor-split (tp) so per-chip state bytes drop ~linearly with the fsdp axis, gradients reduce-scatter onto the parameter shards (int8-quantized per bucket under gradient compression, error-feedback residuals sharded per chip) and XLA all-gathers updated parameters just in time.  An unsized data axis infers -1 (all remaining devices); unsized model axes default to 2.  Empty keeps the replicated step.  Sharding NEVER changes results - only placement and communication."),
     "MX_FSDP": ("", "Size of the fsdp (ZeRO sheet-sharding) mesh axis for the SpecLayout lane.  Overrides the fsdp entry of MX_MESH_AXES; setting MX_FSDP=N alone implies MX_MESH_AXES='data,fsdp=N'.  Per-chip params+optimizer_state bytes in buffer_census() drop ~1/N (acceptance: within 15% of ideal at N=2 and N=4 in dryrun_multichip).  Empty/1 = no fsdp sharding."),
     "MX_EXCHANGE_OVERLAP": ("0", "1 = overlap-scheduled gradient exchange: the Trainer arms per-gradient readiness hooks and each fusion bucket's collective launches the moment backward finalizes the bucket's last member (reverse-parameter-order buckets, so late layers go out first), with results committed at the pre-update drain barrier.  Exchange results are identical to the serialized path (a grad rewritten after launch relaunches its unit at drain); 0 keeps the exchange serialized after backward."),
@@ -119,7 +119,6 @@ ENV_CATALOG: Dict[str, Any] = {
     "MX_SERVE_HBM_BUDGET": ("0", "Census-driven multi-model bin-packing (ISSUE 20): HBM byte budget one serving replica may spend across every co-hosted model (deployed servables + decode engines' target/draft).  ModelHost.deploy measures each candidate AFTER its warm - live param/state bytes plus the peak memory_analysis temp bytes of its registered programs - and refuses admission with a typed in-band '(False, \"budget: ...\")' wire reply when hosted + new would bust the budget.  0 (default) disables the packer (admission is unbounded)."),
     "MX_PROGRAM_CENSUS": ("1", "XLA program census (mxnet_tpu/programs.py): 1 (default) routes every jit-creation site through the process-wide program registry - per-program compile-time histograms (program_compile_seconds{program}), XLA memory_analysis/cost_analysis metadata (program_temp_bytes/program_flops, where the backend provides them), retrace counts with a structured retrace-explainer diff (which arg's shape/dtype/tree structure changed), and the jax.live_arrays() device-buffer census bucketed by owner (params/optimizer_state/ef_residuals/serve/other) riding flight-recorder records and crash dumps.  0 makes register_program a plain jax.jit and disables the census."),
     "MX_LEAK_WARN_BYTES": ("67108864", "Buffer-census leak detector threshold: when total live device bytes grow monotonically across consecutive census checks by more than this many bytes, the census_leak_bytes gauge latches the streak, census.leak_trips increments and a warning names the growing owner buckets.  Any shrink resets the streak; 0 disables the trip (gauges still publish)."),
-    "MX_BENCH_HISTORY": ("", "Path of the bench-trajectory history file tools/bench_compare.py appends each bench.py run to and gates regressions against (>10% throughput or >15% peak-temp-bytes vs the rolling best per metric); empty uses BENCH_HISTORY.jsonl next to bench.py."),
     "MX_FLEET_INTERVAL": ("2.0", "Fleet collector (mxnet_tpu/fleet.py): seconds between scrape rounds over every registered member (serve replicas + PS servers via the METRICS wire verb, training workers via their heartbeat files' JSON payload).  A member that fails its scrape is marked absent on that same round.  0 disables the embedded supervisor collector."),
     "MX_FLEET_RING": ("120", "Fleet collector: bounded time-series ring of merged fleet snapshots (one entry per scrape round, keyed (role, rank, instrument) inside).  The straggler/SLO detectors and tools/fleet_top.py read the ring; the newest entry rides supervisor crash dumps as the `fleet` section."),
     "MX_FLEET_WINDOW": ("5", "Fleet detectors: sliding-window length in scrape rounds for straggler step-time medians and SLO burn (rolling p50/p99, rejection-rate) computation.  Short windows react faster; long windows smooth transients."),
@@ -130,9 +129,6 @@ ENV_CATALOG: Dict[str, Any] = {
     "MX_FLEET_SLO_REJECT_RATE": ("", "Serving SLO target: windowed fleet rejection-rate bound (rejected / (requests+rejected), from merged serve.* counter deltas).  Burn = observed/target into fleet.slo_burn{slo=rejection_rate}; > 1 latches.  Empty disables."),
     "MX_FLEET_SLO_QUEUE": ("", "Serving SLO target: mean fleet queue depth bound (rows, from merged serve.queue_rows gauges).  Burn = observed/target into fleet.slo_burn{slo=queue_depth}; > 1 latches.  Empty disables."),
     "MX_FLEET_SLO_PHASES": ("queue_wait,serve_dispatch", "Comma-separated step_phase_seconds phases whose fleet-merged histograms define the serving latency distribution the SLO p50/p99 trackers read (bucket-wise exact merge; identical boundaries required)."),
-    "MX_COMPILE_CACHE": ("", "Persistent compiled-program cache directory (mxnet_tpu/compile_cache.py): every AOT jit site routed through the program registry serializes its XLA executable here, keyed by (program name, trace signature, function fingerprint, jit spec, backend/topology/jax-version/library-fingerprint envelope), so a warm restart — supervisor respawn, chaos restart, serve replica spawn — DESERIALIZES (~ms) instead of re-tracing and re-compiling (seconds).  jax's own persistent compilation cache — armed for every process, for the light-mode sites an executable store cannot key (the hybridize train lane's vjp closures) and for everything when this is empty — lives where JAX_COMPILATION_CACHE_DIR says if that is set (no directory is then set in code), else under <dir>/xla, else at the fixed <checkout>/.jax_cache (a process pinned by MX_FORCE_CPU=1 gets no default directory: it compiles nothing worth keeping and XLA:CPU's loader logs two error lines per hit).  Any miss, version skew or corrupt entry is counted (compile_cache.misses{reason}) and falls back to a normal compile — the cache can never fail a program.  Writes are temp+rename atomic; concurrent writers are last-write-wins.  Empty disables the executable store only."),
-    "MX_COMPILE_CACHE_SALT": ("", "Extra compile-cache key component: operators set it to partition one shared cache directory (e.g. per experiment branch) without deleting entries; changing it is a guaranteed full-miss restart."),
-    "MX_PREFETCH": ("1", "Async device input pipeline (mxnet_tpu/io/prefetch.py DevicePrefetcher) in the harnesses that support it (bench.py --eager): a background thread device_puts one batch AHEAD of the training loop (double-buffered), so the host->device transfer of batch N+1 overlaps the compute of batch N and the loop's data_wait phase share collapses to the queue handoff.  Bit-parity with the synchronous path (device_put moves bytes, never rounds).  0 keeps the transfer synchronous in the loop (still measured under data_wait)."),
     "MX_PREFETCH_DEPTH": ("2", "DevicePrefetcher queue bound in batches: how many device-resident batches may sit ahead of the consumer (2 = classic double buffering).  The producer blocks (stop-aware bounded polls) at the bound, so prefetch can never balloon memory by more than this many batches."),
     "MX_ELASTIC": ("0", "Elastic membership (mxnet_tpu/kvstore): 1 = a dist_async worker announces itself with the JOIN wire verb at store init (idempotent for ranks the server already seeded) and the Module.fit loop installs a SIGTERM drain handler — on preemption notice the rank finishes its epoch, checkpoints, sends LEAVE and exits 0, so the barrier quorum shrinks instead of timing out.  tools/launch.py --elastic sets it for every worker.  0 keeps the fixed-membership behavior."),
     "MX_ELASTIC_EPOCH": ("0", "The membership epoch a worker incarnation plans its fusion buckets under (the bucket-name CRC salt).  Set by tools/launch.py --elastic on every (re)spawned worker after a resize, so all workers of one incarnation derive identical salted bucket names with no coordination; 0 keeps the historical unsalted names."),

@@ -53,15 +53,9 @@ from ..ndarray.ndarray import NDArray
 from .. import profiler as _profiler
 from .. import telemetry as _telemetry
 
-__all__ = ["DevicePrefetcher", "prefetch_enabled", "prefetch_depth"]
+__all__ = ["DevicePrefetcher", "prefetch_depth"]
 
 _POLL_S = 0.05          # stop-aware bounded wait tick
-
-
-def prefetch_enabled() -> bool:
-    """MX_PREFETCH (default on): async device input prefetch in the
-    harnesses that support it (bench.py --eager)."""
-    return bool(get_env("MX_PREFETCH", dtype=bool))
 
 
 def prefetch_depth() -> int:

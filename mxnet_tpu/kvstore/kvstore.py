@@ -132,7 +132,7 @@ class KVStore:
             out.append(m)
         return out
 
-    # -- wire accounting (tools/bandwidth.py, bench.py --exchange) ---------
+    # -- wire accounting (tools/bandwidth.py) -----------------------------
     def _wire_nbytes(self, n_elems: int, itemsize: int,
                      floating: bool = True) -> int:
         """Bytes an n-element gradient payload occupies in its exchange
@@ -1337,7 +1337,7 @@ class KVStoreDistAsync(KVStore):
     @staticmethod
     def _count_pull_bytes(n) -> None:
         """Pull-leg wire accounting — a counter of its own so the push-
-        leg ``engine.wire_bytes`` the existing benches pin is untouched;
+        leg ``engine.wire_bytes`` tools/bandwidth.py reads is untouched;
         tools/bandwidth.py --hierarchical reads both to compare the flat
         and two-tier exchanges end to end."""
         from .. import telemetry as _telemetry

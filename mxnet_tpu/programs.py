@@ -286,9 +286,6 @@ class ProgramRecord:
         #                                   of a KNOWN signature)
         self.specializations = 0          # fresh-signature compiles of
         #                                   a specializing program
-        self.cache_hits = 0               # executables deserialized from
-        #                                   the persistent compile cache
-        self.deserialize_seconds_total = 0.0
         self._seen_sigs: set = set()
         self.compile_seconds_total = 0.0
         self.compile_seconds_max = 0.0
@@ -323,9 +320,7 @@ class ProgramRecord:
 
     def _absorb_metadata_locked(self, mem, cost) -> None:
         """Fold one executable's memory/cost analysis into the record
-        (caller holds self._lock) — shared by compiled and
-        cache-deserialized builds so their census columns can never
-        diverge."""
+        (caller holds self._lock)."""
         if mem is not None:
             self.memory = mem
             tb = mem["temp_bytes"]
@@ -382,26 +377,6 @@ class ProgramRecord:
             logger.debug("program %r specialized (compile %.3fs): %s",
                          self.name, seconds, _format_diff(diff))
 
-    def note_cache_hit(self, seconds: float, sig: Tuple,
-                       compiled=None) -> None:
-        """Record one executable DESERIALIZED from the persistent
-        compile cache: no compile happened, no retrace is charged —
-        ``compile_seconds_total`` stays the cost actually paid (the
-        warm-restart acceptance number), deserialize time accumulates
-        separately.  The signature still lands in the seen-set so a
-        later genuine rebuild of it is attributed correctly."""
-        mem = _memory_dict(compiled) if compiled is not None else None
-        cost = _cost_dict(compiled) if compiled is not None else None
-        with self._lock:
-            self.cache_hits += 1
-            self.deserialize_seconds_total += seconds
-            self._seen_sigs.add(sig)
-            self.last_sig = sig
-            if compiled is not None:
-                self.executable = compiled
-            self._absorb_metadata_locked(mem, cost)
-        self._publish_metadata_gauges(mem, cost)
-
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
             return {
@@ -411,9 +386,6 @@ class ProgramRecord:
                 "compiles": self.compiles,
                 "retraces": self.retraces,
                 "specializations": self.specializations,
-                "cache_hits": self.cache_hits,
-                "deserialize_seconds": round(
-                    self.deserialize_seconds_total, 6),
                 "compile_seconds": {
                     "total": round(self.compile_seconds_total, 6),
                     "max": round(self.compile_seconds_max, 6),
@@ -447,8 +419,7 @@ def find_record(name: str) -> Optional[ProgramRecord]:
 
 
 def program_table() -> Dict[str, Dict[str, Any]]:
-    """{program name: record snapshot} — what bench.py embeds and crash
-    dumps carry."""
+    """{program name: record snapshot} — what crash dumps carry."""
     with _records_lock:
         recs = list(_records.values())
     return {rec.name: rec.snapshot() for rec in recs}
@@ -456,8 +427,8 @@ def program_table() -> Dict[str, Dict[str, Any]]:
 
 def program_summary() -> Dict[str, Any]:
     """Roll-up across every registered program: total compile seconds,
-    total retraces, peak temp bytes — the numbers the bench sentinel
-    gates on."""
+    total retraces, peak temp bytes — what the benchmark's driver reads
+    (``compiles``, ``compile_seconds_total``)."""
     table = program_table()
     total_s = sum(t["compile_seconds"]["total"] for t in table.values())
     peak_temp = [t["temp_bytes_peak"] for t in table.values()
@@ -468,9 +439,6 @@ def program_summary() -> Dict[str, Any]:
         "retraces": sum(t["retraces"] for t in table.values()),
         "specializations": sum(t["specializations"]
                                for t in table.values()),
-        "cache_hits": sum(t["cache_hits"] for t in table.values()),
-        "deserialize_seconds_total": round(
-            sum(t["deserialize_seconds"] for t in table.values()), 6),
         "compile_seconds_total": round(total_s, 6),
         "peak_temp_bytes": max(peak_temp) if peak_temp else None,
     }
@@ -708,11 +676,6 @@ class Program:
         self._aot = aot
         self._cache: Dict[Tuple, Any] = {}
         self._cache_lock = threading.Lock()
-        # signatures whose executable came off the persistent compile
-        # cache (under _cache_lock) — per-INSTANCE, so warm()-style
-        # callers can tell a deserialized build from a cold compile
-        # without racing on process-global counters
-        self._from_cache_sigs: set = set()
 
     @property
     def jit_kw(self) -> Dict[str, Any]:
@@ -743,27 +706,6 @@ class Program:
             return len(self._cache)
 
     def _compile(self, sig, args, kwargs):
-        # persistent compile cache (ISSUE 13): a warm restart
-        # deserializes the executable this process's predecessor built —
-        # no trace, no lower, no XLA compile.  Any miss (absent entry,
-        # version/topology skew, corrupt payload) falls through to the
-        # normal compile below, which then publishes the entry.
-        from . import compile_cache as _cc
-        ckey = None
-        if _cc.enabled():
-            ckey = _cc.cache_key(self._name, sig, fn=self._fn,
-                                 jit_kw=self._jit_kw)
-            t0 = time.perf_counter()
-            cached = _cc.load(self._name, ckey)
-            if cached is not None:
-                dt = time.perf_counter() - t0
-                with self._cache_lock:
-                    kept = self._cache.setdefault(sig, cached)
-                    self._from_cache_sigs.add(sig)
-                    self._noted = self._seq
-                if kept is cached:
-                    self.record.note_cache_hit(dt, sig, compiled=kept)
-                return kept
         t0 = time.perf_counter()
         try:
             compiled = self._jit.lower(*args, **kwargs).compile()
@@ -789,30 +731,25 @@ class Program:
             # executable the cache kept records the build — compiles
             # stays exact
             self.record.note_compile(dt, sig, compiled=kept)
-            if ckey is not None:
-                _cc.store(self._name, ckey, kept)
         return kept
 
     def ensure_compiled(self, *args, **kwargs):
-        """Build (or warm-load from the persistent compile cache) the
-        executable for this argument signature WITHOUT dispatching it.
+        """Build the executable for this argument signature WITHOUT
+        dispatching it.
 
         Returns a truthy provenance string when an AOT executable is
-        ready — ``"hit"`` (deserialized from the persistent cache, this
-        instance, this signature), ``"compiled"`` (built cold) or
-        ``"ready"`` (already in the in-memory table) — and False in
-        light mode or after an AOT fallback, where the caller must
-        dispatch normally."""
+        ready — ``"compiled"`` (built now) or ``"ready"`` (already in
+        the in-memory table) — and False in light mode or after an AOT
+        fallback, where the caller must dispatch normally."""
         if not self._aot:
             return False
         sig = signature_of(args, kwargs)
         with self._cache_lock:
             if sig in self._cache:
-                return "hit" if sig in self._from_cache_sigs else "ready"
+                return "ready"
         if self._compile(sig, args, kwargs) is None:
             return False
-        with self._cache_lock:
-            return "hit" if sig in self._from_cache_sigs else "compiled"
+        return "compiled"
 
     def __call__(self, *args, **kwargs):
         if self._aot:
@@ -866,8 +803,8 @@ def register_program(name: str, fn: Callable, mode: str = "aot",
 # Program contracts (ISSUE 11): the registry's declarative face
 # ---------------------------------------------------------------------------
 
-# bumped when the manifest JSON layout changes; tools/bench_compare.py
-# --check-schema validates checked-in manifests against this version
+# bumped when the manifest JSON layout changes; python -m tools.mxlint
+# --check-manifest validates checked-in manifests against this version
 CONTRACT_SCHEMA = 1
 
 
@@ -1006,8 +943,8 @@ def contracts() -> List[ProgramContract]:
 
 def contract_manifest() -> Dict[str, Any]:
     """The declared (not built) manifest — what ships in
-    tools/mxlint/contracts.json and what bench_compare --check-schema
-    validates."""
+    tools/mxlint/contracts.json and what ``python -m tools.mxlint
+    --check-manifest`` validates."""
     return {"schema": CONTRACT_SCHEMA,
             "contracts": [c.manifest_entry() for c in contracts()]}
 

@@ -34,7 +34,7 @@ import numpy as _np
 
 
 def _build_servables(args):
-    """Every --demo/--demo-conv/--model spec as (servable, example) —
+    """Every --demo/--model spec as (servable, example) —
     multi-model co-hosting (ISSUE 20): the FIRST spec is the default
     model, the rest are admitted through ``ServeServer.add_model``
     under the MX_SERVE_HBM_BUDGET packer and addressed by the wire
@@ -49,11 +49,6 @@ def _build_servables(args):
         specs.append((Servable(demo_block(), name="demo-mlp",
                                version=1, buckets=buckets),
                       demo_example()))
-    if args.demo_conv:
-        from .demo import demo_conv_block, demo_conv_example
-        specs.append((Servable(demo_conv_block(), name="demo-conv",
-                               version=1, buckets=buckets),
-                      demo_conv_example()))
     for prefix in (args.model or ()):
         sv = Servable.from_checkpoint(prefix, epoch=args.epoch,
                                       input_names=args.inputs.split(","),
@@ -91,12 +86,8 @@ def main(argv=None) -> int:
     ap.add_argument("--dtype", default="float32")
     ap.add_argument("--demo", action="store_true",
                     help="serve the built-in deterministic demo MLP "
-                         "(smokes/benches; tools/serve_load.py verifies "
+                         "(smokes; tools/serve_load.py verifies "
                          "its outputs)")
-    ap.add_argument("--demo-conv", action="store_true",
-                    help="serve the compile-heavy deterministic conv "
-                         "demo (resnet18 @ 64x64) — the warm-spawn "
-                         "bench lane's compile-bound replica")
     ap.add_argument("--decode", action="store_true",
                     help="also host the deterministic demo LM behind "
                          "the GENERATE verb (continuous-batching "
@@ -200,10 +191,9 @@ def main(argv=None) -> int:
             raise SystemExit("serve: need --model PREFIX, --demo or "
                              "--decode")
     warm_s = time.perf_counter() - t_warm0
-    # warm-start visibility (ISSUE 13): with MX_COMPILE_CACHE set, a
-    # respawned replica deserializes its whole bucket table instead of
-    # compiling it — the banner (and the METRICS verb the fleet/bench
-    # scrape) carries the receipts
+    # warm-start visibility: a respawned replica finds its bucket
+    # table's XLA compiles in jax's persistent cache — the banner (and
+    # the METRICS verb) carries the receipts
     from ..compile_cache import stats as _cc_stats
     cs = _cc_stats()
     if sv is not None:
@@ -212,8 +202,8 @@ def main(argv=None) -> int:
               % (sv.name, sv.version, device, sv.param_platform(),
                  len(sv.buckets.sizes),
                  list(sv.buckets.sizes), warm_s,
-                 "" if cs["enabled"] else " off",
-                 cs["hits"], cs["misses"], port),
+                 "" if cs["dir"] else " off",
+                 cs["xla_hits"], cs["xla_misses"], port),
               file=sys.stderr, flush=True)
         if len(specs) > 1:
             rep = state.host.packing_report()

@@ -1,4 +1,4 @@
-"""Deterministic demo model for serve smokes/benches.
+"""Deterministic demo model for serve smokes.
 
 Both sides of a chaos run build this independently — the replicas host
 it, the load driver (tools/serve_load.py) recomputes expected outputs
@@ -33,34 +33,6 @@ def demo_block():
 def demo_example(rows: int = 1) -> list:
     """A warm/probe input batch of the demo signature."""
     return [_np.zeros((rows, DEMO_IN), _np.float32)]
-
-
-# Compile-heavy conv demo (ISSUE 13 warm-spawn lane): a real convnet
-# whose per-bucket XLA compile dwarfs interpreter+jax import, so the
-# cold-vs-warm spawn bench measures what the compile cache buys — the
-# TPU-realistic regime where replica ready-to-traffic time is compile
-# bound.  Deterministic like the MLP (seeded init), so correctness
-# stays assertable across processes.
-DEMO_CONV_SHAPE = (3, 64, 64)
-DEMO_CONV_CLASSES = 100
-
-
-def demo_conv_block():
-    """Seeded resnet18 @ 3x64x64 → 100 classes."""
-    import mxnet_tpu as mx
-    from ..gluon.model_zoo import vision
-    from ..ndarray.ndarray import NDArray
-    import jax.numpy as jnp
-    mx.random.seed(DEMO_SEED)
-    net = vision.resnet18_v1(classes=DEMO_CONV_CLASSES)
-    net.initialize(mx.init.Xavier())
-    # finish deferred init (BatchNorm shapes) before functionalize
-    net(NDArray(jnp.zeros((1,) + DEMO_CONV_SHAPE, jnp.float32)))
-    return net
-
-
-def demo_conv_example(rows: int = 1) -> list:
-    return [_np.zeros((rows,) + DEMO_CONV_SHAPE, _np.float32)]
 
 
 def demo_requests(n: int, rows: int = 1, seed: int = 0) -> list:

@@ -222,39 +222,18 @@ class Servable:
     def warm(self, example: Sequence, outputs_expected: bool = True):
         """Pre-trace + pre-run EVERY bucket for `example`'s signature
         (`example` = per-input arrays; leading batch dim arbitrary).
-        Returns self so ``deploy(Servable(...).warm(x))`` chains.
-
-        Warm start (ISSUE 13): with ``MX_COMPILE_CACHE`` set, each
-        bucket's executable deserializes from the persistent store
-        instead of compiling; a deserialized bucket skips its per-
-        bucket proving run — one end-to-end validation dispatch (the
-        smallest bucket) still proves the model answers — so replica
-        ready-to-traffic time is deserialize-bound, not compile- or
-        compute-bound."""
+        Returns self so ``deploy(Servable(...).warm(x))`` chains.  A
+        respawned replica finds each bucket's XLA compile in jax's
+        persistent cache (compile_cache.py)."""
         example = [_np.asarray(a) for a in example]
         sig = self.signature_of(example)
-        validated = False
         for bucket in self.buckets:
             zeros = [_np.zeros((bucket,) + trail, dtype=dt)
                      for trail, dt in sig]
-            prog = None
-            if validated:
-                prog = self.program(bucket, sig)
-                ensure = getattr(prog, "ensure_compiled", None)
-                # "hit" is per-Program-instance, per-signature — a
-                # concurrent deploy's cache traffic cannot make a
-                # cold-compiled bucket skip its proving run
-                if ensure is not None and \
-                        ensure(self._param_values, tuple(zeros)) == "hit":
-                    continue    # deserialized: skip the proving run
-            # hand the already-resolved program through so the probe
-            # never double-counts bucket_hits (exact accounting is the
-            # table's contract)
-            outs = self.dispatch(bucket, zeros, warming=True, _prog=prog)
+            outs = self.dispatch(bucket, zeros, warming=True)
             if outputs_expected:
                 for o in outs:
                     jax.block_until_ready(o)
-            validated = True
         with self._lock:
             self._warm_sig = sig
         return self
@@ -266,17 +245,12 @@ class Servable:
 
     # -- dispatch -----------------------------------------------------------
     def dispatch(self, bucket: int, padded_inputs: Sequence,
-                 warming: bool = False, _prog=None) -> Tuple:
+                 warming: bool = False) -> Tuple:
         """Run the bucket program over already-padded inputs; returns the
         output leaves as jax arrays (async — callers sync when they
-        scatter).  One device-program launch, counted.  ``_prog`` lets
-        warm() pass its already-resolved program so the warm probe does
-        not inflate bucket-hit accounting."""
+        scatter).  One device-program launch, counted."""
         from ..engine import engine as _engine
-        prog = _prog
-        if prog is None:
-            sig = self.signature_of(padded_inputs)
-            prog = self.program(bucket, sig)
+        prog = self.program(bucket, self.signature_of(padded_inputs))
         outs = prog(self._param_values, tuple(padded_inputs))
         _engine.count_dispatch(1)
         if not warming:

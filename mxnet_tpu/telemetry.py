@@ -679,7 +679,7 @@ def rpc_span(name: str, trace_id: Optional[str] = None,
 
 def phase_snapshot() -> Dict[str, Dict[str, float]]:
     """{phase: {count, avg_ms, total_ms, max_ms}} from the per-phase
-    histograms — what bench.py embeds in its JSON report."""
+    histograms."""
     out: Dict[str, Dict[str, float]] = {}
     for inst in registry.instruments():
         if inst.name != "step_phase_seconds" or \

@@ -1,827 +1,97 @@
-"""Persistent compiled-program cache + async input pipeline (ISSUE 13).
+"""The one compile cache: jax's persistent compilation cache, armed and
+counted by mxnet_tpu/compile_cache.py.
 
-Covers the tentpole's safety contract — every cache-poisoning/skew path
-is non-fatal and counted, warm loads are bit-identical — and the
-prefetcher's parity/lifecycle guarantees:
-
-* executable store roundtrip: second Program deserializes, no compile,
-  identical outputs; donation aliasing survives deserialization
-* corrupt entry / truncated write / envelope skew / unpicklable
-  payload: counted miss (+error), normal compile, correct answers
-* concurrent writers: last-write-wins via atomic rename, no torn reads
-* key hygiene: function edits and jit-spec changes change the key;
-  repeated runs of one process produce the identical key (no memory
-  addresses, no set-ordering leakage)
-* CompiledStep / Servable warm-from-cache continue the exact cold
-  trajectory
-* DevicePrefetcher: bit-parity loss trajectory, bounded queue, error
-  transparency, clean shutdown, data_wait telemetry
-* mxlint reinjection: a host sync in the prefetch handoff and disk I/O
-  in the batcher loop both trip host-sync-in-hot-path
+* a second process sharing ``JAX_COMPILATION_CACHE_DIR`` finds the first
+  one's XLA compiles (``compile_cache.xla_hits`` > 0) for a CompiledStep
+  and for a Servable's bucket table, and continues the exact trajectory
+* ``tools/launch.py --compile-cache DIR`` exports that variable,
+  absolute and created, to every rank
 """
 import json
 import os
-import pickle
+import subprocess
 import sys
-import threading
-import time
 
-import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
 
-import jax                                                   # noqa: E402
-import jax.numpy as jnp                                      # noqa: E402
+_CHILD = r"""
+import json, sys
+import numpy as np
+import mxnet_tpu as mx
+from mxnet_tpu import compile_cache, gluon, nd, programs
 
-import mxnet_tpu as mx                                       # noqa: E402
-from mxnet_tpu import compile_cache as cc                    # noqa: E402
-from mxnet_tpu import gluon, nd, programs, telemetry         # noqa: E402
-from mxnet_tpu.base import environment                       # noqa: E402
-from mxnet_tpu.io.prefetch import DevicePrefetcher           # noqa: E402
-
-_uid = [0]
-
-
-def _name(tag):
-    _uid[0] += 1
-    return "test.cc.%s.%d.%d" % (tag, os.getpid(), _uid[0])
-
-
-def _cache_env(tmp_path):
-    d = str(tmp_path / "xcache")
-    os.makedirs(d, exist_ok=True)
-    return environment("MX_COMPILE_CACHE", d)
-
-
-def _stats_delta(fn):
-    before = cc.stats()
-    out = fn()
-    after = cc.stats()
-    delta = {k: after[k] - before[k]
-             for k in ("hits", "misses", "errors", "writes")}
-    return out, delta
-
-
-# ---------------------------------------------------------------------------
-# store roundtrip
-# ---------------------------------------------------------------------------
-
-def test_roundtrip_second_program_deserializes(tmp_path):
-    def fn(x, y):
-        return x @ y + 1.0
-
-    a = jnp.ones((4, 8), jnp.float32)
-    b = jnp.ones((8, 2), jnp.float32)
-    name = _name("roundtrip")
-    with _cache_env(tmp_path):
-        p1 = programs.register_program(name, fn)
-        out1, d1 = _stats_delta(lambda: p1(a, b))
-        assert d1["writes"] == 1 and d1["hits"] == 0
-        rec = programs.find_record(name)
-        assert rec.compiles == 1 and rec.cache_hits == 0
-
-        # a FRESH wrapper (new process stand-in): loads, never compiles
-        name2 = _name("roundtrip2")
-        p2 = programs.Program(name2, "aot", fn, {}, aot=True)
-        # same fn/sig/jit_kw → same key as p1's entry
-        assert cc.cache_key(name, programs.signature_of((a, b)), fn=fn,
-                            jit_kw={}) == \
-            cc.cache_key(name, programs.signature_of((a, b)), fn=fn,
-                         jit_kw={})
-        out2, d2 = _stats_delta(lambda: p1_clone_dispatch(p2, a, b))
-        assert d2["hits"] == 0  # different name → different key: compiles
-    np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
-
-
-def p1_clone_dispatch(p, a, b):
-    return p(a, b)
-
-
-def test_same_name_fresh_wrapper_hits_and_matches(tmp_path):
-    def fn(x):
-        return jnp.tanh(x) * 3.0
-
-    x = jnp.linspace(-2, 2, 32).reshape(4, 8)
-    name = _name("hit")
-    with _cache_env(tmp_path):
-        p1 = programs.register_program(name, fn)
-        out1 = p1(x)
-        rec1 = programs.find_record(name)
-        assert rec1.compiles == 1
-
-        p2 = programs.Program(name + ".warm", "aot", fn, {}, aot=True)
-        # force the same on-disk key by construction: identical
-        # name is what real warm restarts share — emulate by pointing
-        # the fresh wrapper at the original name
-        p2._name = name
-        out2, delta = _stats_delta(lambda: p2(x))
-        assert delta["hits"] == 1
-        assert delta["writes"] == 0
-        rec = programs.find_record(name)
-        assert rec.cache_hits == 1
-        # deserialize time tracked separately; no compile charged
-        assert rec.compiles == 1
-        assert rec.snapshot()["deserialize_seconds"] > 0
-    np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
-
-
-def test_donation_survives_deserialization(tmp_path):
-    def fn(x, y):
-        return x + y
-
-    name = _name("donate")
-    with _cache_env(tmp_path):
-        p1 = programs.register_program(name, fn, donate_argnums=(0,))
-        x = jnp.ones((16,), jnp.float32)
-        p1(x, x + 1)
-
-        p2 = programs.Program(name, "aot", fn, {"donate_argnums": (0,)},
-                              aot=True)
-        # same aval as the cold call (jnp.full would flip weak_type and
-        # honestly be a different trace)
-        xd = jnp.ones((16,), jnp.float32) * 5.0
-        out, delta = _stats_delta(lambda: p2(xd, xd + 1))
-        assert delta["hits"] == 1
-        jax.block_until_ready(out)
-        assert xd.is_deleted()      # the aliasing rode the serialization
-        np.testing.assert_array_equal(np.asarray(out), np.full(16, 11.0))
-
-
-def test_cache_off_writes_nothing(tmp_path):
-    # MX_COMPILE_CACHE unset: register_program is cacheless — no files,
-    # no counters moving
-    with environment("MX_COMPILE_CACHE", None):
-        assert not cc.enabled()
-        before = cc.stats()
-        p = programs.register_program(_name("off"), lambda x: x * 2)
-        p(jnp.ones((3,)))
-        after = cc.stats()
-    assert after["hits"] == before["hits"]
-    assert after["misses"] == before["misses"]
-    assert after["writes"] == before["writes"]
-    assert not (tmp_path / "xcache").exists() or \
-        not list((tmp_path / "xcache").rglob("*.xcache"))
-
-
-# ---------------------------------------------------------------------------
-# poisoning / skew: all non-fatal, all counted
-# ---------------------------------------------------------------------------
-
-def _single_entry(tmp_path):
-    entries = [p for p in (tmp_path / "xcache").rglob("*.xcache")]
-    assert len(entries) == 1, entries
-    return entries[0]
-
-
-def test_corrupt_entry_falls_back_and_counts(tmp_path):
-    def fn(x):
-        return x - 7.0
-
-    name = _name("corrupt")
-    x = jnp.ones((8,), jnp.float32)
-    with _cache_env(tmp_path):
-        programs.register_program(name, fn)(x)
-        entry = _single_entry(tmp_path)
-        entry.write_bytes(b"\x00garbage not a pickle")
-        p2 = programs.Program(name, "aot", fn, {}, aot=True)
-        out, delta = _stats_delta(lambda: p2(x))
-        assert delta["hits"] == 0
-        assert delta["misses"] == 1 and delta["errors"] == 1
-        np.testing.assert_array_equal(np.asarray(out), np.full(8, -6.0))
-        # the poisoned entry was removed and the recompile re-published
-        assert _single_entry(tmp_path).read_bytes()[:1] != b"\x00"
-
-
-def test_truncated_write_falls_back(tmp_path):
-    def fn(x):
-        return x * x
-
-    name = _name("trunc")
-    x = jnp.full((4,), 3.0)
-    with _cache_env(tmp_path):
-        programs.register_program(name, fn)(x)
-        entry = _single_entry(tmp_path)
-        blob = entry.read_bytes()
-        entry.write_bytes(blob[:len(blob) // 3])    # torn tail
-        p2 = programs.Program(name, "aot", fn, {}, aot=True)
-        out, delta = _stats_delta(lambda: p2(x))
-        assert delta["misses"] == 1 and delta["errors"] == 1
-        np.testing.assert_array_equal(np.asarray(out), np.full(4, 9.0))
-
-
-def test_envelope_skew_is_a_miss_not_a_wrong_load(tmp_path):
-    def fn(x):
-        return x + 100.0
-
-    name = _name("skew")
-    x = jnp.zeros((4,))
-    with _cache_env(tmp_path):
-        programs.register_program(name, fn)(x)
-        entry = _single_entry(tmp_path)
-        doc = pickle.loads(entry.read_bytes())
-        doc["envelope"] = dict(doc["envelope"], jax="0.0.1-other")
-        entry.write_bytes(pickle.dumps(doc))
-        p2 = programs.Program(name, "aot", fn, {}, aot=True)
-        out, delta = _stats_delta(lambda: p2(x))
-        assert delta["hits"] == 0
-        assert delta["misses"] == 1
-        np.testing.assert_array_equal(np.asarray(out), np.full(4, 100.0))
-
-
-def test_unserializable_out_tree_counts_error_keeps_working(tmp_path):
-    # the hybridize-train class of program: a function rides the out
-    # tree (jax.tree_util.Partial with a local closure) — store() must
-    # count an error and the program must keep dispatching
-    def fn(x):
-        def local_fn(y):
-            return y * x.sum()
-        return x * 2, jax.tree_util.Partial(local_fn, x)
-
-    name = _name("unser")
-    x = jnp.ones((4,))
-    with _cache_env(tmp_path):
-        p = programs.register_program(name, fn)
-        _, delta = _stats_delta(lambda: p(x))
-        assert delta["writes"] == 0
-        assert delta["errors"] >= 1     # serialize failed, counted
-        rec = programs.find_record(name)
-        assert rec is not None          # ...and the dispatch succeeded
-
-
-def test_concurrent_writers_last_write_wins_no_torn_reads(tmp_path):
-    d = str(tmp_path / "xcache")
-    os.makedirs(d, exist_ok=True)
-
-    def fn(x):
-        return x * 4.0
-
-    x = jnp.ones((64,), jnp.float32)
-    name = _name("race")
-    with environment("MX_COMPILE_CACHE", d):
-        sig = programs.signature_of((x,))
-        key = cc.cache_key(name, sig, fn=fn, jit_kw={})
-        compiled = jax.jit(fn).lower(x).compile()
-        errs = []
-
-        def writer():
-            try:
-                for _ in range(10):
-                    assert cc.store(name, key, compiled)
-            except Exception as e:      # pragma: no cover
-                errs.append(e)
-
-        def reader():
-            try:
-                for _ in range(30):
-                    got = cc.load(name, key)
-                    if got is not None:
-                        np.testing.assert_array_equal(
-                            np.asarray(got(x)), np.full(64, 4.0))
-            except Exception as e:      # pragma: no cover
-                errs.append(e)
-
-        threads = [threading.Thread(target=writer) for _ in range(3)] + \
-                  [threading.Thread(target=reader) for _ in range(2)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=60)
-        assert not errs, errs
-        # exactly one published entry; any .tmp droppings are stale
-        entries = [p for p in (tmp_path / "xcache").rglob("*.xcache")]
-        assert len(entries) == 1
-        got = cc.load(name, key)
-        assert got is not None
-
-
-# ---------------------------------------------------------------------------
-# key hygiene
-# ---------------------------------------------------------------------------
-
-def test_function_edit_changes_key():
-    def fn_a(x):
-        return x + 1
-
-    def fn_b(x):
-        return x + 2
-
-    sig = programs.signature_of((jnp.ones((3,)),))
-    assert cc.function_fingerprint(fn_a) != cc.function_fingerprint(fn_b)
-    with environment("MX_COMPILE_CACHE", "/tmp/x"):
-        assert cc.cache_key("p", sig, fn=fn_a) != \
-            cc.cache_key("p", sig, fn=fn_b)
-
-
-def test_jit_spec_changes_key():
-    def fn(x):
-        return x + 1
-
-    sig = programs.signature_of((jnp.ones((3,)),))
-    with environment("MX_COMPILE_CACHE", "/tmp/x"):
-        assert cc.cache_key("p", sig, fn=fn, jit_kw={}) != \
-            cc.cache_key("p", sig, fn=fn,
-                         jit_kw={"donate_argnums": (0,)})
-
-
-def test_closure_and_default_values_change_key():
-    # trace bodies bake closed-over host config (weight decays, flags)
-    # into the executable invisibly to the trace signature — the
-    # fingerprint MUST see them or a warm restart deserializes the
-    # other config's program
-    def make(c):
-        def fn(x):
-            return x * c
-        return fn
-
-    assert cc.function_fingerprint(make(2.0)) != \
-        cc.function_fingerprint(make(3.0))
-    assert cc.function_fingerprint(make(2.0)) == \
-        cc.function_fingerprint(make(2.0))
-
-    def fd_a(x, k=2):
-        return x + k
-
-    def fd_b(x, k=3):
-        return x + k
-
-    fd_b.__name__ = "fd_a"      # identical but for the default
-    assert cc.function_fingerprint(fd_a) != cc.function_fingerprint(fd_b)
-
-    # nested: the divergent value sits one closure level down
-    def outer(c):
-        def mid(x):
-            def inner(y):
-                return y * c
-            return inner(x)
-        return mid
-
-    assert cc.function_fingerprint(outer(1)) != \
-        cc.function_fingerprint(outer(2))
-
-
-def test_compiled_step_wd_change_changes_key():
-    # end-to-end: two CompiledStep bodies with identical shapes but
-    # different weight decay must never share a cache entry
+def step():
     from mxnet_tpu.gluon import nn
-
-    def build(wd):
-        mx.random.seed(0)
-        net = nn.HybridSequential()
-        net.add(nn.Dense(16, in_units=8, activation="relu"))
-        net.add(nn.Dense(4, in_units=16))
-        net.initialize(mx.init.Xavier())
-        tr = gluon.Trainer(net.collect_params(), "sgd",
-                           {"learning_rate": 0.1, "momentum": 0.9,
-                            "wd": wd})
-        cs = tr.make_compiled_step(net,
-                                   gluon.loss.SoftmaxCrossEntropyLoss())
-        plan = cs._plan()
-        rescale, wds, _lr, _d = cs._lr_rows(plan, 1, 8)
-        return cs._build_fn(plan, 1, 1, rescale, wds, False, None,
-                            False)._fn
-
-    assert cc.function_fingerprint(build(0.0)) != \
-        cc.function_fingerprint(build(0.01))
-
-
-def test_partial_and_frozenset_fingerprints_are_stable():
-    import functools
-
-    def body(x, mode):
-        if mode in {"a", "b", "c"}:
-            return x + 1
-        return x
-
-    f1 = functools.partial(body, mode="a")
-    f2 = functools.partial(body, mode="a")
-    f3 = functools.partial(body, mode="b")
-    assert cc.function_fingerprint(f1) == cc.function_fingerprint(f2)
-    assert "0x" not in cc._stable_repr(f1)
-    assert cc.function_fingerprint(f1) != cc.function_fingerprint(f3)
-
-
-def test_salt_partitions_the_key():
-    def fn(x):
-        return x
-
-    sig = programs.signature_of((jnp.ones((2,)),))
-    with environment("MX_COMPILE_CACHE", "/tmp/x"):
-        k1 = cc.cache_key("p", sig, fn=fn)
-        with environment("MX_COMPILE_CACHE_SALT", "exp-7"):
-            k2 = cc.cache_key("p", sig, fn=fn)
-    assert k1 != k2
-
-
-def test_signature_token_distinguishes_shape_dtype_sharding():
-    a = programs.signature_of((jnp.ones((4, 2), jnp.float32),))
-    b = programs.signature_of((jnp.ones((4, 3), jnp.float32),))
-    c = programs.signature_of((jnp.ones((4, 2), jnp.bfloat16),))
-    toks = {cc.signature_token(s) for s in (a, b, c)}
-    assert len(toks) == 3
-
-
-# ---------------------------------------------------------------------------
-# warm-start consumers
-# ---------------------------------------------------------------------------
-
-def _mlp_trainer(seed=0):
-    from mxnet_tpu.gluon import nn
-    mx.random.seed(seed)
-    np.random.seed(seed)
+    mx.random.seed(0)
     net = nn.HybridSequential()
     net.add(nn.Dense(16, in_units=8, activation="relu"))
     net.add(nn.Dense(4, in_units=16))
     net.initialize(mx.init.Xavier())
     tr = gluon.Trainer(net.collect_params(), "sgd",
                        {"learning_rate": 0.1, "momentum": 0.9})
-    return net, tr
-
-
-def _run_cstep(steps=4):
-    net, tr = _mlp_trainer()
-    cstep = tr.make_compiled_step(net,
-                                  gluon.loss.SoftmaxCrossEntropyLoss())
+    cstep = tr.make_compiled_step(net, gluon.loss.SoftmaxCrossEntropyLoss())
     rng = np.random.RandomState(0)
     x = nd.array(rng.randn(8, 8).astype(np.float32))
     y = nd.array(rng.randint(0, 4, 8).astype(np.float32))
-    losses = []
-    for _ in range(steps):
-        loss = cstep.step(x, y)
-        losses.append(float(loss.mean().asnumpy()))
-    return losses
+    out = [cstep.step(x, y).mean().asnumpy().item().hex() for _ in range(4)]
+    assert cstep.compiled, cstep.fallback_reason
+    return out
 
-
-def test_compiled_step_warm_from_cache_exact_trajectory(tmp_path):
-    with _cache_env(tmp_path):
-        cold = _run_cstep()
-        w0 = cc.stats()
-        warm = _run_cstep()     # fresh CompiledStep → fresh Program →
-        #                         disk load instead of compile
-        delta_hits = cc.stats()["hits"] - w0["hits"]
-    assert delta_hits >= 1
-    assert warm == cold         # bit-identical trajectory
-
-
-def test_servable_warm_from_cache_skips_compiles(tmp_path):
+def servable():
     from mxnet_tpu.serve.demo import demo_block, demo_example
     from mxnet_tpu.serve.servable import BucketTable, Servable
-    buckets = BucketTable([1, 2, 4])
-    with _cache_env(tmp_path):
-        sv1 = Servable(demo_block(), name=_name("sv"), version=1,
-                       buckets=buckets)
-        sv1.warm(demo_example())
-        w0 = cc.stats()
-        assert w0["writes"] >= 3
+    sv = Servable(demo_block(), name="demo-mlp", version=1,
+                  buckets=BucketTable([1, 2, 4]))
+    sv.warm(demo_example())
+    x = np.random.RandomState(3).randn(2, 16).astype(np.float32)
+    return np.asarray(sv.dispatch(2, [x])[0]).tobytes().hex()
 
-        sv2 = Servable(demo_block(), name=sv1.name, version=2,
-                       buckets=buckets)
-        sv2.warm(demo_example())
-        w1 = cc.stats()
-        assert w1["hits"] - w0["hits"] == 3
-        # warm answers == cold answers
-        x = np.random.RandomState(3).randn(2, 16).astype(np.float32)
-        pad = np.zeros((2, 16), np.float32)
-        o1 = sv1.dispatch(2, [x])
-        o2 = sv2.dispatch(2, [x])
-        np.testing.assert_array_equal(np.asarray(o1[0]),
-                                      np.asarray(o2[0]))
-        assert pad is not None
+result = {"step": step, "servable": servable}[sys.argv[1]]()
+print(json.dumps({"result": result, "stats": compile_cache.stats(),
+                  "summary": programs.program_summary()}))
+"""
 
 
-# ---------------------------------------------------------------------------
-# census / telemetry wiring
-# ---------------------------------------------------------------------------
-
-def test_cache_hit_census_columns_and_summary(tmp_path):
-    def fn(x):
-        return x * 2 + 1
-
-    name = _name("census")
-    x = jnp.ones((4,))
-    with _cache_env(tmp_path):
-        programs.register_program(name, fn)(x)
-        p2 = programs.Program(name, "aot", fn, {}, aot=True)
-        p2(x)
-        snap = programs.find_record(name).snapshot()
-        assert snap["cache_hits"] == 1
-        assert snap["deserialize_seconds"] > 0
-        summary = programs.program_summary()
-        assert summary["cache_hits"] >= 1
-        assert "deserialize_seconds_total" in summary
-        st = cc.stats()
-        assert st["enabled"] and st["hits"] >= 1
-        # counters ride the registry exposition (fleet rollup merges
-        # registry snapshots generically, so presence here == presence
-        # in the merged fleet face)
-        reg_snap = telemetry.registry.snapshot()
-        assert any(e.get("name") == "compile_cache.hits"
-                   for e in reg_snap.values() if isinstance(e, dict))
-        prom = telemetry.registry.to_prometheus()
-        assert "mx_compile_cache_hits" in prom
+def _child(case, cache_dir):
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=cache_dir,
+               PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    r = subprocess.run([sys.executable, "-c", _CHILD, case], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-4000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
-def test_specializing_record_semantics():
-    name = _name("spec")
-    p = programs.register_program(name, lambda x: x + 1, mode="light",
-                                  specializing=True)
-    p(jnp.ones((2,)))
-    p(jnp.ones((3,)))           # fresh shape: specialization, NOT retrace
-    rec = programs.find_record(name)
-    assert rec.compiles == 2
-    assert rec.retraces == 0
-    assert rec.specializations == 1
-    snap = rec.snapshot()
-    assert snap["specializing"] and snap["specializations"] == 1
+@pytest.mark.parametrize("case", ["step", "servable"])
+def test_second_process_hits_jax_cache_and_matches(case, tmp_path):
+    cache_dir = str(tmp_path / "xla")
+    cold = _child(case, cache_dir)
+    warm = _child(case, cache_dir)
+    assert cold["stats"]["dir"] == warm["stats"]["dir"] == cache_dir
+    assert cold["stats"]["xla_misses"] > 0      # it compiled, and wrote
+    assert warm["stats"]["xla_hits"] > 0
+    assert warm["stats"]["xla_hits"] >= cold["stats"]["xla_misses"]
+    for key in ("cache_hits", "deserialize_seconds",
+                "deserialize_seconds_total"):
+        assert key not in warm["summary"]
+    # a warm process still traces: the census counts the same builds
+    assert warm["summary"]["compiles"] == cold["summary"]["compiles"]
+    assert warm["result"] == cold["result"]     # to the last digit
 
 
-def test_strict_record_semantics_unchanged():
-    name = _name("strict")
-    p = programs.register_program(name, lambda x: x + 1, mode="light")
-    p(jnp.ones((2,)))
-    p(jnp.ones((3,)))
-    rec = programs.find_record(name)
-    assert rec.retraces == 1 and rec.specializations == 0
-
-
-def test_hybridize_imperative_pass_builds_no_child_programs():
-    # ISSUE 13 retrace chase: the deferred-init imperative pass of a
-    # hybridized parent must not build per-child hybrid programs —
-    # the whole-net trace on the SECOND call covers them
-    from mxnet_tpu.gluon import nn
-    before = set(programs.program_table())
-    mx.random.seed(0)
-    net = nn.HybridSequential()
-    net.add(nn.Dense(8, activation="relu"))     # deferred in_units
-    net.add(nn.Dense(4))
-    net.initialize(mx.init.Xavier())
-    net.hybridize()
-    x = nd.array(np.random.RandomState(0).randn(2, 6).astype(np.float32))
-    net(x)                      # imperative pass (finishes deferred init)
-    new = set(programs.program_table()) - before
-    assert not any(n.startswith("hybrid.Dense") for n in new), new
-    net(x)                      # whole-net trace
-    new = set(programs.program_table()) - before
-    assert any(n.startswith("hybrid.HybridSequential") for n in new), new
-
-
-# ---------------------------------------------------------------------------
-# DevicePrefetcher
-# ---------------------------------------------------------------------------
-
-def _mlp_loss_traj(use_prefetch, steps=6):
-    from mxnet_tpu.gluon import nn
-    mx.random.seed(0)
-    np.random.seed(0)
-    net = nn.Sequential()
-    net.add(nn.Dense(16, in_units=8, activation="relu"))
-    net.add(nn.Dense(4, in_units=16))
-    net.initialize(mx.init.Xavier())
-    tr = gluon.Trainer(net.collect_params(), "sgd",
-                       {"learning_rate": 0.05, "momentum": 0.9})
-    loss_fn = gluon.loss.L2Loss()
-    rng = np.random.RandomState(7)
-    batches = [(rng.randn(8, 8).astype(np.float32),
-                rng.randn(8, 4).astype(np.float32))
-               for _ in range(steps)]
-    from mxnet_tpu import autograd
-
-    def one(xb, yb):
-        with autograd.record():
-            loss = loss_fn(net(xb), yb)
-        loss.backward()
-        tr.step(batch_size=8)
-        return float(loss.mean().asnumpy())
-
-    if use_prefetch:
-        with DevicePrefetcher(iter(batches)) as pf:
-            return [one(nd.NDArray(xb), nd.NDArray(yb)) for xb, yb in pf]
-    return [one(nd.array(xb), nd.array(yb)) for xb, yb in batches]
-
-
-def test_prefetch_bit_parity_loss_trajectory():
-    assert _mlp_loss_traj(False) == _mlp_loss_traj(True)
-
-
-def test_prefetch_bounded_queue_and_order():
-    produced = []
-
-    def src():
-        for i in range(50):
-            produced.append(i)
-            yield (np.full((2,), i, np.float32),)
-
-    pf = DevicePrefetcher(src(), depth=2)
-    first = next(pf)
-    time.sleep(0.3)
-    assert len(produced) <= 5           # depth + in-flight margin
-    assert float(first[0][0]) == 0.0
-    out = [float(b[0][0]) for b in pf]
-    assert out == [float(i) for i in range(1, 50)]
-    pf.close()
-
-
-def test_prefetch_error_surfaces_on_consumer():
-    def bad():
-        yield (np.zeros((1,)),)
-        raise RuntimeError("disk on fire")
-
-    pf = DevicePrefetcher(bad())
-    next(pf)
-    with pytest.raises(mx.base.MXNetError, match="disk on fire"):
-        next(pf)
-    pf.close()
-
-
-def test_prefetch_close_idempotent_and_bounded():
-    def src():
-        while True:
-            yield (np.zeros((1,)),)
-
-    pf = DevicePrefetcher(src(), depth=1)
-    next(pf)
-    t0 = time.monotonic()
-    pf.close()
-    pf.close()
-    assert time.monotonic() - t0 < 5
-    with pytest.raises(mx.base.MXNetError):
-        next(pf)
-
-
-def test_prefetch_data_wait_phase_observed():
-    inst0 = telemetry.registry.find("step_phase_seconds",
-                                    {"phase": "data_wait"})
-    c0 = inst0.snapshot()["count"] if inst0 is not None else 0
-    with environment("MX_TELEMETRY", "1"):
-        with DevicePrefetcher([(np.zeros((1,)),)] * 3) as pf:
-            for _ in pf:
-                pass
-    inst = telemetry.registry.find("step_phase_seconds",
-                                   {"phase": "data_wait"})
-    assert inst is not None
-    assert inst.snapshot()["count"] >= c0 + 3
-
-
-def test_prefetch_ndarray_leaves_roundtrip():
-    x = nd.array(np.arange(6, dtype=np.float32).reshape(2, 3))
-    with DevicePrefetcher([(x,)]) as pf:
-        (out,) = next(pf)
-    assert isinstance(out, nd.NDArray)
-    np.testing.assert_array_equal(out.asnumpy(), x.asnumpy())
-
-
-def test_prefetch_depth_env(tmp_path):
-    with environment("MX_PREFETCH_DEPTH", "5"):
-        from mxnet_tpu.io.prefetch import prefetch_depth
-        assert prefetch_depth() == 5
-    with environment("MX_PREFETCH_DEPTH", "0"):
-        assert __import__(
-            "mxnet_tpu.io.prefetch", fromlist=["prefetch_depth"]
-        ).prefetch_depth() == 1
-
-
-# ---------------------------------------------------------------------------
-# env catalog + mxlint reinjection
-# ---------------------------------------------------------------------------
-
-def test_new_env_vars_cataloged():
-    from mxnet_tpu.base import ENV_CATALOG
-    for var in ("MX_COMPILE_CACHE", "MX_COMPILE_CACHE_SALT",
-                "MX_PREFETCH", "MX_PREFETCH_DEPTH"):
-        assert var in ENV_CATALOG, var
-
-
-def _lint_source(code, path):
-    from tools.mxlint import lint_source
-    return lint_source(code, path)
-
-
-def _rules_of(diags):
-    return {d.rule for d in diags}
-
-
-def test_reinjected_sync_in_prefetch_handoff_trips():
-    p = os.path.join(REPO, "mxnet_tpu", "io", "prefetch.py")
-    with open(p) as f:
-        code = f.read()
-    anchor = "_telemetry.observe_phase(\"data_wait\", " \
-             "self._clock() - t0)"
-    assert anchor in code, "prefetch handoff moved; update this test"
-    bad = code.replace(
-        anchor, anchor + "\n        _dbg = item[0].asnumpy()")
-    diags = _lint_source(bad, "mxnet_tpu/io/prefetch.py")
-    assert "host-sync-in-hot-path" in _rules_of(diags)
-
-
-def test_reinjected_disk_io_in_batcher_loop_trips():
-    # the satellite's contract verbatim: no disk I/O inside the batcher
-    # loop — an open() reintroduced between dequeue and dispatch trips
-    # host-sync-in-hot-path
-    p = os.path.join(REPO, "mxnet_tpu", "serve", "batcher.py")
-    with open(p) as f:
-        code = f.read()
-    anchor = "batch = self._collect()"
-    assert anchor in code, "Batcher._loop moved; update this test"
-    bad = code.replace(
-        anchor,
-        anchor + "\n            open('/tmp/spill', 'a').write('x')")
-    diags = _lint_source(bad, "mxnet_tpu/serve/batcher.py")
-    assert "host-sync-in-hot-path" in _rules_of(diags)
-
-
-# ---------------------------------------------------------------------------
-# bench_compare gated series (ISSUE 13 satellite)
-# ---------------------------------------------------------------------------
-
-def _bc():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_compare_cc_test",
-        os.path.join(REPO, "tools", "bench_compare.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _hist_rows(rows):
-    return [(i + 1, r) for i, r in enumerate(rows)]
-
-
-def test_bench_compare_gates_retrace_budget():
-    bc = _bc()
-    rec = {"metric": "m", "device": "cpu", "host": "h", "unit": "x",
-           "value": 10.0, "retraces": 9, "retrace_budget": 4,
-           "retraces_over_budget": True}
-    ok, findings = bc.gate(rec, _hist_rows([dict(rec, value=10.0,
-                                                 retraces_over_budget=False)]),
-                           0.10, 0.15)
-    assert not ok
-    assert any("RETRACE BUDGET" in f for f in findings)
-
-
-def test_bench_compare_gates_compile_seconds_per_warmth_class():
-    bc = _bc()
-    base = {"metric": "m", "device": "cpu", "host": "h", "unit": "x",
-            "value": 10.0}
-    history = _hist_rows([
-        dict(base, compile_seconds_total=20.0, cache_hits=0),   # cold best
-        dict(base, compile_seconds_total=0.5, cache_hits=7),    # warm best
-    ])
-    # a cold run near the cold best passes — the warm 0.5s is NOT its bar
-    ok, _ = bc.gate(dict(base, compile_seconds_total=21.0, cache_hits=0),
-                    history, 0.10, 0.15)
-    assert ok
-    # a cold run regressing >10% vs the cold best fails
-    ok, findings = bc.gate(dict(base, compile_seconds_total=25.0,
-                                cache_hits=0), history, 0.10, 0.15)
-    assert not ok and any("COMPILE-TIME" in f for f in findings)
-    # a warm run regressing vs the warm best fails
-    ok, findings = bc.gate(dict(base, compile_seconds_total=2.0,
-                                cache_hits=7), history, 0.10, 0.15)
-    assert not ok and any("warm" in f for f in findings)
-
-
-def test_bench_compare_gates_warm_spawn_seconds():
-    bc = _bc()
-    base = {"metric": "serve_warm_spawn_speedup", "device": "cpu",
-            "host": "h", "unit": "x", "value": 8.0}
-    history = _hist_rows([dict(base, warm_spawn_seconds=3.5)])
-    ok, _ = bc.gate(dict(base, warm_spawn_seconds=3.6), history,
-                    0.10, 0.15)
-    assert ok
-    ok, findings = bc.gate(dict(base, warm_spawn_seconds=5.0), history,
-                           0.10, 0.15)
-    assert not ok and any("WARM-SPAWN" in f for f in findings)
-
-
-def test_bench_compare_extracts_issue13_fields():
-    bc = _bc()
-    report = {
-        "metric": "m", "value": 1.0, "unit": "x", "device": "cpu",
-        "retrace_budget": 4, "retraces_over_budget": False,
-        "warm_spawn_seconds": 3.5, "cold_spawn_seconds": 28.0,
-        "prefetch": {"data_wait_share_pct": 0.2},
-        "census": {"summary": {"compile_seconds_total": 1.2,
-                               "peak_temp_bytes": 10, "retraces": 0,
-                               "programs": 5, "cache_hits": 7}},
-    }
-    rec = bc.extract_record(report)
-    assert rec["retrace_budget"] == 4
-    assert rec["warm_spawn_seconds"] == 3.5
-    assert rec["cache_hits"] == 7
-    assert rec["data_wait_share_pct"] == 0.2
-
-
-def test_reinjected_open_in_compile_cache_key_trips():
-    p = os.path.join(REPO, "mxnet_tpu", "compile_cache.py")
-    with open(p) as f:
-        code = f.read()
-    anchor = "h.update(signature_token(sig).encode())"
-    assert code.count(anchor) == 1, "cache_key moved; update this test"
-    bad = code.replace(
-        anchor,
-        "with open('/tmp/keylog', 'a') as _f:\n"
-        "        _f.write(name)\n    " + anchor)
-    diags = _lint_source(bad, "mxnet_tpu/compile_cache.py")
-    assert "host-sync-in-hot-path" in _rules_of(diags)
+def test_launch_compile_cache_exports_jax_dir_to_every_rank(tmp_path):
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "launch.py"),
+         "-n", "2", "--launcher", "local", "--compile-cache", "cc", "--",
+         sys.executable, "-c",
+         "import os; print('RANK_DIR', os.environ['MX_PROCESS_ID'], "
+         "os.environ['JAX_COMPILATION_CACHE_DIR'], flush=True)"],
+        capture_output=True, text=True, timeout=120,
+        cwd=str(tmp_path))      # the flag takes a relative path too
+    assert r.returncode == 0, (r.stdout, r.stderr)
+    want = os.path.join(os.path.realpath(str(tmp_path)), "cc")
+    assert os.path.isdir(want)
+    seen = sorted(line.split()[1:] for line in r.stdout.splitlines()
+                  if line.startswith("RANK_DIR"))
+    assert seen == [["0", want], ["1", want]]

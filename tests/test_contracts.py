@@ -15,7 +15,8 @@ Layers, bottom-up:
     in tests/test_mxlint.py with the other AST-rule fixtures.);
   * the CLI (`python -m tools.mxlint --contracts`): exit contract,
     --format json schema, --select narrowing, and the manifest
-    round-trip that tools/bench_compare.py --check-schema validates.
+    round-trip that `python -m tools.mxlint --check-manifest`
+    validates without importing jax.
 """
 import json
 import os
@@ -35,7 +36,6 @@ import jax.numpy as jnp                                 # noqa: E402
 
 from mxnet_tpu import programs                          # noqa: E402
 from tools.mxlint import contracts as lane              # noqa: E402
-from tools import bench_compare                         # noqa: E402
 
 
 def _name(tag):
@@ -104,23 +104,26 @@ def test_pruned_donation_noted_not_flagged(shipped):
 
 
 def test_contract_schema_constants_agree():
-    assert bench_compare.CONTRACT_SCHEMA == programs.CONTRACT_SCHEMA
+    assert lane.CONTRACT_SCHEMA == programs.CONTRACT_SCHEMA
 
 
 # ---------------------------------------------------------------------------
 # reinjection: each check trips
 # ---------------------------------------------------------------------------
 
-def test_reinjected_dropped_donation_trips():
-    """A donated f32 buffer whose only same-shape output is bf16: XLA
-    cannot alias it, jax warns at lowering, and the lane must flag it —
-    this is the exact failure that doubles HBM on TPU while CPU stays
-    green."""
+@pytest.mark.parametrize("body, witness", [
+    # same element count, other byte size: jax (0.9) hands the donor to
+    # XLA without a warning and XLA places it in no output
+    (lambda w, g: (w - g).astype(jnp.bfloat16), "in no output"),
+    # no output of that size at all: jax warns at lowering
+    (lambda w, g: (w - g)[:32], "not usable"),
+], ids=["other_dtype", "other_size"])
+def test_reinjected_dropped_donation_trips(body, witness):
+    """A donated f32 buffer that no output can take over: XLA cannot
+    alias it and the lane must flag it, whichever of jax and XLA gave up
+    on it — this is the exact failure that doubles HBM on TPU while CPU
+    stays green."""
     name = _name("drop")
-
-    def body(w, g):
-        return (w - g).astype(jnp.bfloat16)
-
     sds = jax.ShapeDtypeStruct((64,), jnp.float32)
     programs.declare_contract(
         name,
@@ -130,7 +133,7 @@ def test_reinjected_dropped_donation_trips():
     diags, results, _ = lane.verify([name], root=REPO)
     assert [d.rule for d in diags] == [lane.RULE_DONATION]
     assert "donations dropped" in diags[0].message
-    assert "not usable" in diags[0].message          # jax's warning rides
+    assert witness in diags[0].message      # who dropped it rides along
     (r,) = results
     assert r.donated_expected == 1 and r.aliased == 0 and r.dropped == 1
 
@@ -236,8 +239,22 @@ def test_broken_builder_is_a_finding_not_a_crash():
 # manifest + CLI
 # ---------------------------------------------------------------------------
 
-def test_manifest_roundtrip_and_bench_compare_validation(tmp_path,
-                                                         shipped):
+def _check_manifest_cli(path):
+    """`python -m tools.mxlint --check-manifest` in a process that must
+    not import jax (nor the runtime): (exit code, stderr)."""
+    code = ("import sys\n"
+            "from tools.mxlint.__main__ import main\n"
+            "rc = main(['--check-manifest', sys.argv[1]])\n"
+            "assert 'jax' not in sys.modules, 'imported jax'\n"
+            "assert 'mxnet_tpu' not in sys.modules, 'imported mxnet_tpu'\n"
+            "sys.exit(rc)\n")
+    r = subprocess.run([sys.executable, "-c", code, path], cwd=REPO,
+                       capture_output=True, text=True, timeout=120)
+    return r.returncode, r.stderr
+
+
+def test_manifest_roundtrip_and_check_manifest_validation(tmp_path,
+                                                          shipped):
     _diags, results, _verified = shipped
     doc = lane.manifest(results)
     assert doc["schema"] == programs.CONTRACT_SCHEMA
@@ -249,18 +266,22 @@ def test_manifest_roundtrip_and_bench_compare_validation(tmp_path,
         ["adam", "adam_mp"]
     p = tmp_path / "contracts.json"
     p.write_text(json.dumps(doc))
-    assert bench_compare.check_contract_manifest(str(p)) == 0
+    assert lane.check_contract_manifest(str(p)) == 0
     # schema drift fails
     bad = dict(doc, schema=99)
     p.write_text(json.dumps(bad))
-    assert bench_compare.check_contract_manifest(str(p)) == 1
+    assert lane.check_contract_manifest(str(p)) == 1
+    rc, err = _check_manifest_cli(str(p))
+    assert rc == 1 and "contract schema 99" in err, err
     # a case row missing a required field fails
     bad = json.loads(json.dumps(doc))
     next(iter(bad["programs"].values()))["cases"][0].pop("aliased")
     p.write_text(json.dumps(bad))
-    assert bench_compare.check_contract_manifest(str(p)) == 1
+    assert lane.check_contract_manifest(str(p)) == 1
+    rc, err = _check_manifest_cli(str(p))
+    assert rc == 1 and "missing field 'aliased'" in err, err
     # absent manifest is fine (fresh checkout before the first run)
-    assert bench_compare.check_contract_manifest(
+    assert lane.check_contract_manifest(
         str(tmp_path / "absent.json")) == 0
 
 
@@ -268,8 +289,10 @@ def test_checked_in_manifest_is_valid():
     assert os.path.isfile(lane.DEFAULT_MANIFEST), \
         "tools/mxlint/contracts.json missing — run " \
         "python -m tools.mxlint --contracts --write-manifest"
-    assert bench_compare.check_contract_manifest(lane.DEFAULT_MANIFEST) \
-        == 0
+    assert lane.check_contract_manifest(lane.DEFAULT_MANIFEST) == 0
+    # the CLI tools/lint.sh calls: same verdict, and no jax in its process
+    rc, err = _check_manifest_cli(lane.DEFAULT_MANIFEST)
+    assert rc == 0, err
 
 
 def test_budget_table_renders_every_case(shipped):

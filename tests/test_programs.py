@@ -3,9 +3,9 @@ metadata matching jax's own AOT analysis, graceful None in light mode),
 retrace-explainer diff correctness for shape/dtype/tree-structure
 changes, the device-buffer census with owner attribution + leak
 detector, crash-dump/flight-recorder wiring, the serve METRICS verb
-over a real socket, engine.snapshot() consistency, the bench_compare
-regression sentinel, and the mxlint reinjection proving a host sync in
-the census hot path trips the rule."""
+over a real socket, engine.snapshot() consistency, specializing /
+strict record semantics, and the mxlint reinjection proving a host sync
+in the census hot path trips the rule."""
 import json
 import os
 import socket
@@ -496,7 +496,7 @@ def test_compiled_step_registers_program_and_explains_invalidation():
 
 
 # ---------------------------------------------------------------------------
-# engine snapshot + bench sentinel
+# engine snapshot + record semantics
 # ---------------------------------------------------------------------------
 
 def test_engine_snapshot_consistent_group():
@@ -515,71 +515,70 @@ def test_engine_snapshot_consistent_group():
     assert s1["programs"] >= 0
 
 
-def _run_compare(history, report, *extra):
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_compare.py"),
-         "-", "--history", history] + list(extra),
-        input=json.dumps(report), capture_output=True, text=True,
-        timeout=120)
-    return r.returncode, r.stdout
+def test_ensure_compiled_builds_without_dispatch():
+    name = _name("ensure")
+    p = programs.register_program(name, lambda x: x * 2)
+    x = jnp.ones((4,))
+    assert p.ensure_compiled(x) == "compiled"
+    assert p.ensure_compiled(x) == "ready"
+    assert programs.find_record(name).compiles == 1
+    np.testing.assert_array_equal(np.asarray(p(x)), 2 * np.ones(4))
+    assert programs.find_record(name).compiles == 1   # no second build
+    snap = programs.find_record(name).snapshot()
+    assert "cache_hits" not in snap and "deserialize_seconds" not in snap
+    light = programs.register_program(_name("ensure.light"),
+                                      lambda x: x + 1, mode="light")
+    assert light.ensure_compiled(x) is False
 
 
-def test_bench_compare_seeds_passes_and_gates(tmp_path):
-    history = str(tmp_path / "hist.jsonl")
-    report = {"metric": "m", "value": 50.0, "unit": "img/s",
-              "device": "cpu",
-              "census": {"summary": {"compile_seconds_total": 1.0,
-                                     "peak_temp_bytes": 1 << 20,
-                                     "retraces": 0, "programs": 3}}}
-    rc, out = _run_compare(history, report)
-    assert rc == 0, out
-    rc, out = _run_compare(history, report)          # same run: passes
-    assert rc == 0, out
-    assert len(open(history).read().splitlines()) == 2
-    # the synthetic 2x step-time regression MUST gate non-zero
-    rc, out = _run_compare(history, report, "--inject-slowdown", "2.0")
-    assert rc == 1, out
-    assert "THROUGHPUT REGRESSION" in out
-    # injected runs never pollute the history
-    assert len(open(history).read().splitlines()) == 2
-    # a small wobble within tolerance passes
-    ok = dict(report, value=47.0)
-    rc, _ = _run_compare(history, ok)
-    assert rc == 0
-    # >15% peak-temp-bytes growth gates
-    fat = dict(report)
-    fat["census"] = {"summary": {"compile_seconds_total": 1.0,
-                                 "peak_temp_bytes": int(1.3 * (1 << 20)),
-                                 "retraces": 0, "programs": 3}}
-    rc, out = _run_compare(history, fat)
-    assert rc == 1
-    assert "MEMORY REGRESSION" in out
+def test_specializing_record_semantics():
+    name = _name("spec")
+    p = programs.register_program(name, lambda x: x + 1, mode="light",
+                                  specializing=True)
+    p(jnp.ones((2,)))
+    p(jnp.ones((3,)))           # fresh shape: specialization, NOT retrace
+    rec = programs.find_record(name)
+    assert rec.compiles == 2
+    assert rec.retraces == 0
+    assert rec.specializations == 1
+    snap = rec.snapshot()
+    assert snap["specializing"] and snap["specializations"] == 1
 
 
-def test_bench_compare_check_schema(tmp_path):
-    history = str(tmp_path / "hist.jsonl")
-    report = {"metric": "m", "value": 1.0, "unit": "x"}
-    rc, _ = _run_compare(history, report)
-    assert rc == 0
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_compare.py"),
-         "--check-schema", "--history", history],
-        capture_output=True, text=True, timeout=120)
-    assert r.returncode == 0, r.stderr
-    with open(history, "a") as f:
-        f.write("{broken\n")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_compare.py"),
-         "--check-schema", "--history", history],
-        capture_output=True, text=True, timeout=120)
-    assert r.returncode == 1
-    assert "unparseable" in r.stderr
+def test_strict_record_semantics_unchanged():
+    name = _name("strict")
+    p = programs.register_program(name, lambda x: x + 1, mode="light")
+    p(jnp.ones((2,)))
+    p(jnp.ones((3,)))
+    rec = programs.find_record(name)
+    assert rec.retraces == 1 and rec.specializations == 0
+
+
+def test_hybridize_imperative_pass_builds_no_child_programs():
+    # the deferred-init imperative pass of a hybridized parent must not
+    # build per-child hybrid programs — the whole-net trace on the
+    # SECOND call covers them
+    from mxnet_tpu import nd
+    from mxnet_tpu.gluon import nn
+    before = set(programs.program_table())
+    mx.random.seed(0)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(8, activation="relu"))     # deferred in_units
+    net.add(nn.Dense(4))
+    net.initialize(mx.init.Xavier())
+    net.hybridize()
+    x = nd.array(np.random.RandomState(0).randn(2, 6).astype(np.float32))
+    net(x)                      # imperative pass (finishes deferred init)
+    new = set(programs.program_table()) - before
+    assert not any(n.startswith("hybrid.Dense") for n in new), new
+    net(x)                      # whole-net trace
+    new = set(programs.program_table()) - before
+    assert any(n.startswith("hybrid.HybridSequential") for n in new), new
 
 
 def test_env_catalog_covers_new_flags():
     from mxnet_tpu.base import ENV_CATALOG
-    for var in ("MX_PROGRAM_CENSUS", "MX_LEAK_WARN_BYTES",
-                "MX_BENCH_HISTORY"):
+    for var in ("MX_PROGRAM_CENSUS", "MX_LEAK_WARN_BYTES"):
         assert var in ENV_CATALOG
 
 
@@ -608,7 +607,6 @@ def test_reinjected_sync_in_census_call_path_trips_hot_path_rule():
 
 def test_shipped_programs_lints_clean():
     from tools.mxlint import lint_paths
-    diags = lint_paths([os.path.join(REPO, "mxnet_tpu", "programs.py"),
-                        os.path.join(REPO, "tools", "bench_compare.py")],
+    diags = lint_paths([os.path.join(REPO, "mxnet_tpu", "programs.py")],
                        root=REPO)
     assert [d for d in diags] == [], diags

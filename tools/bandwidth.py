@@ -79,8 +79,7 @@ def _hierarchical_main(args):
     cross-slice tier), runs the same int8-pushed payload through the
     flat return leg (full-width fp32 pull) and the two-tier one (PULLQ
     int8 pull), and asserts the two-tier run moves fewer cross-slice
-    wire bytes per step.  Exits nonzero when it does not — the
-    bench_compare gate."""
+    wire bytes per step.  Exits nonzero when it does not."""
     import socket as _socket
     import threading
 

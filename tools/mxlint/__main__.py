@@ -28,8 +28,7 @@ def _default_paths():
     fleet_top.py emits the FLEET wire verb the exhaustiveness rule
     pins (ISSUE 12)."""
     out = ["mxnet_tpu"]
-    for extra in ("launch.py", "telemetry_dump.py", "bench_compare.py",
-                  "fleet_top.py"):
+    for extra in ("launch.py", "telemetry_dump.py", "fleet_top.py"):
         if os.path.isfile(os.path.join("tools", extra)):
             out.append(os.path.join("tools", extra))
     return out
@@ -68,6 +67,11 @@ def main(argv=None) -> int:
                     default=None, metavar="FILE",
                     help="with --contracts: write the contract manifest "
                     "JSON (default tools/mxlint/contracts.json)")
+    ap.add_argument("--check-manifest", nargs="?", const="DEFAULT",
+                    default=None, metavar="FILE",
+                    help="validate the shape of the contract manifest "
+                    "(default tools/mxlint/contracts.json) and exit; "
+                    "imports no jax")
     ap.add_argument("--protocol", action="store_true",
                     help="run the wire-protocol verifier instead of the "
                     "AST rules: extract per-verb effect summaries from "
@@ -76,6 +80,13 @@ def main(argv=None) -> int:
                     "schedules (ISSUE 19; see tools/mxlint/protocol.py). "
                     "No baseline: findings are fix-or-suppress-with-why")
     args = ap.parse_args(argv)
+
+    if args.check_manifest:
+        from . import contracts as _contracts
+        path = args.check_manifest
+        if path == "DEFAULT":
+            path = _contracts.DEFAULT_MANIFEST
+        return _contracts.check_contract_manifest(path)
 
     if args.protocol:
         # pure-stdlib like the AST lanes, but its own pipeline: verb

@@ -12,18 +12,18 @@ checked:
 
 * **donation-aliasing** — every leaf the contract declares donated
   actually appears in the executable's input→output aliasing
-  (``tf.aliasing_output`` in the lowered module).  XLA silently DROPS a
+  (``input_output_alias`` of the compiled module).  XLA silently DROPS a
   donation whose shape/dtype matches no output; CPU never exercises
   donation at runtime, so the first symptom used to be doubled HBM on
-  TPU.  jax's "Some donated buffers were not usable" lowering warning
-  is captured and attached to the finding.  Donated-but-*unused* args
+  TPU.  jax's "Some donated buffers were not usable" lowering warning,
+  or the donor XLA left unplaced, is attached to the finding.
+  Donated-but-*unused* args
   (jax prunes them; e.g. the bf16 weights of an mp Adam apply, whose
   new values derive from the fp32 masters) are counted separately and
   NOTED, not flagged — a pruned donation is a no-op, not a leak.
 * **hbm-budget** — the compiled ``memory_analysis`` temp bytes fit the
-  contract's declared ``temp_budget_bytes``: the static HBM-creep gate
-  (the dynamic twin is tools/bench_compare.py's peak-temp history
-  gate).  Budget bumps are reviewed like baseline entries —
+  contract's declared ``temp_budget_bytes``: the static HBM-creep
+  gate.  Budget bumps are reviewed like baseline entries —
   docs/TESTING.md §5.
 * **trace-closure** — for contracts with a closure spec, every
   reachable workload point (each admissible serve batch size, each
@@ -35,14 +35,15 @@ checked:
 Exit contract matches the AST lane: 0 clean, 1 findings, 2 internal
 error.  ``--format json`` emits the machine schema
 (``contract_schema``); ``--write-manifest`` refreshes the checked-in
-``tools/mxlint/contracts.json`` (validated by
-``tools/bench_compare.py --check-schema``).
+``tools/mxlint/contracts.json`` (validated, without importing jax, by
+``python -m tools.mxlint --check-manifest``).
 """
 from __future__ import annotations
 
 import json
 import os
 import re
+import sys
 import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
@@ -67,15 +68,32 @@ DECLARING_MODULES = (
     "mxnet_tpu.kvstore.kvstore",
 )
 
-_ALIAS_RE = re.compile(r"tf\.aliasing_output")
-# sharded lowerings (inputs carrying NamedShardings, ISSUE 14) mark
-# donations as `jax.buffer_donor = true` instead: the in/out aliasing
-# decision is deferred to XLA (shardings may legally differ), so the
-# donor attribute is the strongest device-free witness that the
-# declared donation reached the executable — jax's not-usable warning
-# still fires at compile when a donor cannot be consumed
-_DONOR_RE = re.compile(r"jax\.buffer_donor\s*=\s*true")
+# The witness is the COMPILED module's header, not the lowered text:
+# jax (0.9) hands XLA every donation it cannot pair itself — sharded
+# inputs, and any donated leaf with an output of the same element COUNT
+# whatever its dtype — as `jax.buffer_donor`, without a warning.  What
+# XLA then aliased is in the executable's `input_output_alias={...}`;
+# a donor it found no output for stays behind in `buffer_donor={...}`.
+_ALIASED_RE = re.compile(r"(?:may|must)-alias\)")
+_LEFT_DONOR_RE = re.compile(r"\(\d+, \{[\d, ]*\}\)")
 _DROP_WARNING = "donated buffers were not usable"
+
+
+def _header_section(header: str, key: str) -> str:
+    """The text of ``key={...}`` in an HloModule header line, up to the
+    next attribute's ``=`` ('' when absent)."""
+    return header.partition(key + "={")[2].partition("=")[0]
+
+
+def _compiled_aliasing(compiled) -> Tuple[int, int]:
+    """(donations XLA aliased into an output, donors it could not
+    place) of one executable."""
+    header = next(line for line in compiled.as_text().splitlines()
+                  if line.startswith("HloModule"))
+    return (len(_ALIASED_RE.findall(
+                _header_section(header, "input_output_alias"))),
+            len(_LEFT_DONOR_RE.findall(
+                _header_section(header, "buffer_donor"))))
 
 
 def _ensure_device_free():
@@ -181,29 +199,30 @@ def _verify_case(contract, case, root: str):
     t0 = time.perf_counter()
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
-        lowered = case.lower()
-        txt = lowered.as_text()
-        compiled = lowered.compile()
+        compiled = case.lower().compile()
     res.compile_seconds = time.perf_counter() - t0
 
     drop_msgs = [str(w.message) for w in rec
                  if _DROP_WARNING in str(w.message)]
-    res.aliased = len(_ALIAS_RE.findall(txt)) + \
-        len(_DONOR_RE.findall(txt))
+    res.aliased, left_donors = _compiled_aliasing(compiled)
     missing = max(0, res.donated_expected - res.aliased)
-    if drop_msgs:
-        # jax could not alias a LIVE donated buffer (shape/dtype matched
-        # no output): the TPU would carry both generations of it.
-        # Count the dropped buffers from the WARNING (it names each
-        # aval), not from expected-aliased: an alias from a jit-spec
-        # donation the contract does not declare could mask the
-        # subtraction to zero while the drop is real.
+    if drop_msgs or left_donors:
+        # a LIVE donated buffer matched no output (jax warned at
+        # lowering, or XLA left the donor unplaced): the TPU would
+        # carry both generations of it.  Count the dropped buffers from
+        # those witnesses, not from expected-aliased: an alias from a
+        # jit-spec donation the contract does not declare could mask
+        # the subtraction to zero while the drop is real.
         warned = sum(m.count("ShapedArray") for m in drop_msgs)
-        res.dropped = max(missing, warned, 1)
+        res.dropped = max(warned + left_donors, 1)
+        if not drop_msgs:
+            drop_msgs = ["XLA placed %d donated buffer(s) in no output "
+                         "(same element count, other byte size?)"
+                         % left_donors]
         diags.append(Diagnostic(
             RULE_DONATION, path, line, 0,
             "program %r (case %s): %d of %d declared donations dropped "
-            "at lowering — %s; on TPU the undonated buffer stays live "
+            "— %s; on TPU the undonated buffer stays live "
             "next to its replacement (CPU hides this).  Make the donated "
             "leaf's shape+dtype match an output, or shrink the declared "
             "donate_argnums" % (case.program, case.label, res.dropped,
@@ -211,7 +230,7 @@ def _verify_case(contract, case, root: str):
                                 "; ".join(drop_msgs)[:300]),
             snippet="contract %s" % contract.name))
     else:
-        # no lowering warning: any shortfall is donated-but-unused args
+        # no dropped donation: any shortfall is donated-but-unused args
         # jax pruned from the computation — a no-op donation, noted in
         # the table, not a finding
         res.pruned = missing
@@ -376,7 +395,7 @@ def budget_table(results: List[CaseResult]) -> str:
 def manifest(results: List[CaseResult]) -> Dict[str, Any]:
     """The contract-manifest document: declared contracts + this run's
     measured table.  ``schema`` is programs.CONTRACT_SCHEMA — what
-    bench_compare --check-schema validates.  Each program keeps EVERY
+    :func:`check_contract_manifest` validates.  Each program keeps EVERY
     measured case (optimizer.fused_adam has both the plain and the mp
     lowering) — a flat {program: row} map would silently drop all but
     the last."""
@@ -402,7 +421,6 @@ def run_cli(fmt: str = "text",
         # out would silently erase every other program's snapshot rows
         # (and still pass check_contract_manifest — it validates shape,
         # not coverage)
-        import sys
         print("mxlint --contracts: --write-manifest cannot be combined "
               "with --select (it would drop the unselected programs' "
               "rows)", file=sys.stderr)
@@ -414,7 +432,6 @@ def run_cli(fmt: str = "text",
             if unknown:
                 # a typo'd --select must read as a usage error, never
                 # as "0 contracts, clean"
-                import sys
                 print("mxlint --contracts: unknown contract(s): %s "
                       "(have %s)" % (", ".join(sorted(unknown)),
                                      ", ".join(sorted(known))),
@@ -422,7 +439,6 @@ def run_cli(fmt: str = "text",
                 return 2
         diags, results, verified = verify(contract_names, root=root)
     except Exception as e:    # import errors etc: internal, never "clean"
-        import sys
         print("mxlint --contracts: internal error: %s: %s"
               % (type(e).__name__, e), file=sys.stderr)
         return 2
@@ -441,7 +457,6 @@ def run_cli(fmt: str = "text",
             "programs": doc["programs"],
         }, indent=1, sort_keys=True))
     else:
-        import sys
         for d in diags:
             print("%s:%d:%d: %s: %s" % (d.path, d.line, d.col, d.rule,
                                         d.message))
@@ -452,3 +467,90 @@ def run_cli(fmt: str = "text",
                  len(diags), "" if len(diags) == 1 else "s"),
               file=sys.stderr)
     return 1 if diags else 0
+
+
+# ---------------------------------------------------------------------------
+# the checked-in manifest's shape (jax-free: python -m tools.mxlint
+# --check-manifest, tools/lint.sh)
+# ---------------------------------------------------------------------------
+
+# must track mxnet_tpu.programs.CONTRACT_SCHEMA; pinned here so the check
+# imports no jax (tests/test_contracts.py asserts the two agree)
+CONTRACT_SCHEMA = 1
+CONTRACT_FIELDS = ("name", "donate_argnums", "temp_budget_bytes")
+# each program row carries a `cases` list (one entry per lowering —
+# e.g. fused_adam's plain AND mp cases); every case needs these
+CONTRACT_PROGRAM_FIELDS = ("program", "cases")
+CONTRACT_CASE_FIELDS = ("program", "label", "donated_expected",
+                        "aliased", "temp_bytes", "budget")
+
+
+def check_contract_manifest(path) -> int:
+    """Validate the checked-in program-contract manifest (absent is OK —
+    the contracts lane may not have been run on this checkout)."""
+    if not os.path.exists(path):
+        return 0
+    try:
+        with open(path) as f:
+            doc = json.load(f)
+    except ValueError as e:
+        print("mxlint: %s: unparseable contract manifest: %s"
+              % (path, e), file=sys.stderr)
+        return 1
+    if not isinstance(doc, dict):
+        print("mxlint: %s: contract manifest is not an object"
+              % path, file=sys.stderr)
+        return 1
+    bad = []
+    progs = doc.get("programs")
+    if progs is not None and not isinstance(progs, dict):
+        bad.append("'programs' is not an object")
+        doc = dict(doc, programs={})
+    if doc.get("schema") != CONTRACT_SCHEMA:
+        bad.append("contract schema %r != expected %d (regenerate with "
+                   "python -m tools.mxlint --contracts --write-manifest, "
+                   "or bump CONTRACT_SCHEMA in both places)"
+                   % (doc.get("schema"), CONTRACT_SCHEMA))
+    declared = doc.get("contracts", [])
+    if not isinstance(declared, list):
+        bad.append("'contracts' is not a list")
+        declared = []
+    for entry in declared:
+        if not isinstance(entry, dict):
+            # type corruption must be a finding, not a TypeError
+            bad.append("contract entry %r is not an object" % (entry,))
+            continue
+        for field in CONTRACT_FIELDS:
+            if field not in entry:
+                bad.append("contract entry %r missing field %r"
+                           % (entry.get("name", "?"), field))
+    for pname, row in (doc.get("programs") or {}).items():
+        if not isinstance(row, dict):
+            # type corruption must be a finding, not a TypeError
+            bad.append("program row %r is not an object" % pname)
+            continue
+        for field in CONTRACT_PROGRAM_FIELDS:
+            if field not in row:
+                bad.append("program row %r missing field %r"
+                           % (pname, field))
+        cases = row.get("cases") or []
+        if not isinstance(cases, list):
+            bad.append("program %r 'cases' is not a list" % pname)
+            cases = []
+        for case in cases:
+            if not isinstance(case, dict):
+                bad.append("program %r has a non-object case" % pname)
+                continue
+            for field in CONTRACT_CASE_FIELDS:
+                if field not in case:
+                    bad.append("program %r case %r missing field %r"
+                               % (pname, case.get("label", "?"), field))
+    if bad:
+        for why in bad:
+            print("mxlint: %s: %s" % (path, why), file=sys.stderr)
+        return 1
+    print("mxlint: contract manifest OK (%d contracts, %d programs, "
+          "schema %d)"
+          % (len(doc.get("contracts", [])),
+             len(doc.get("programs") or {}), CONTRACT_SCHEMA))
+    return 0

@@ -117,22 +117,13 @@ HOT_PATH_ROOTS: List[Tuple[str, List[str]]] = [
     # The tests/test_mxlint.py reinjection test trips this entry.
     ("mxnet_tpu/programs.py",
      ["Program.__call__", "Program._compile", "ProgramRecord.note_compile",
-      "ProgramRecord.note_cache_hit",
       "signature_of", "diff_signatures", "buffer_census",
       "LeakDetector.check"]),
-    # the persistent compile cache's KEY helpers (ISSUE 13) run under
-    # Program._compile per executable build — pure hash/string work over
-    # host metadata by contract (the disk I/O itself lives in
-    # load/store, which only the cold path reaches; the open()-in-hot-
-    # path check above guards the rest of the runtime).  The
-    # tests/test_compile_cache.py reinjection test trips this entry.
-    ("mxnet_tpu/compile_cache.py",
-     ["cache_key", "signature_token", "function_fingerprint"]),
     # the async input pipeline's consumer handoff (ISSUE 13): __next__
     # runs once per training step between batches — a device sync or
     # host pull here re-serializes exactly the overlap the prefetcher
     # exists to create (the device_put lives on the producer thread by
-    # design).  The tests/test_compile_cache.py reinjection test trips
+    # design).  The tests/test_prefetch.py reinjection test trips
     # this entry.
     ("mxnet_tpu/io/prefetch.py",
      ["DevicePrefetcher.__next__", "DevicePrefetcher._put"]),

@@ -1,6 +1,5 @@
-"""Validate/convert a dataset drop into the layout the parity gates and
-benches expect (VERDICT r4 next-item #4; the activation contract of
-tests/test_real_data.py and bench.py --real-data).
+"""Validate/convert a dataset drop into the layout the parity gates
+expect (the activation contract of tests/test_real_data.py).
 
 One command turns "I have the files somewhere" into "the gates run":
 
@@ -15,7 +14,7 @@ tests/test_real_data.py):
   voc/VOC2007/Annotations/*.xml                 (SSD config 4)
   voc/VOC2007/JPEGImages/*.jpg
   voc/VOC2007/ImageSets/Main/trainval.txt + test.txt
-  imagenet/train.rec (+ train.idx)              (optional: bench configs)
+  imagenet/train.rec (+ train.idx)              (optional)
 
 Conversions performed (source dir searched recursively):
   - idx/ptb/voc files found anywhere are hard-linked/copied into place;
@@ -116,7 +115,7 @@ def check(target):
         print("imagenet: train.rec present (%d MB)"
               % (os.path.getsize(rec) >> 20))
     else:
-        print("imagenet: absent (resnet bench keeps its synthetic pack)")
+        print("imagenet: absent")
     return problems
 
 
