@@ -8,6 +8,7 @@ jax/jaxlib, so "handle plumbing" reduces to ordinary Python objects.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 from typing import Any, Callable, Dict, Optional
@@ -154,11 +155,78 @@ ENV_CATALOG: Dict[str, Any] = {
 
 # ``jax.ad_checkpoint.checkpoint_name`` tag of a value a recomputed block
 # (``gluon.Block.recompute``) must KEEP from its forward pass instead of
-# computing it again: a discrete decision (a router's top-k choice) that a
-# second run of the same arithmetic, fused and rounded otherwise by XLA,
-# can make the other way - the backward pass would then differentiate
-# another function than the forward pass ran (PERF.md section 6, PR 28).
+# computing it again.  Two kinds of value earn it: a discrete decision (a
+# router's top-k choice) that a second run of the same arithmetic, fused
+# and rounded otherwise by XLA, can make the other way - the backward pass
+# would then differentiate another function than the forward pass ran
+# (PERF.md section 6, PR 28) - and a kernel's result that costs far more
+# time to make again than bytes to hold (the flash kernels' output and
+# logsumexp, an expert layer's sort order: PERF.md section 6, PR 31).
 RECOMPUTE_KEEP = "mx_recompute_keep"
+
+# trace-time state of the thread: how many recomputed blocks enclose the
+# code being traced (`blocks`), and the tallies of the programs being
+# traced, outermost first (`tallies`)
+_recompute_trace = threading.local()
+
+
+@contextlib.contextmanager
+def recomputed_block_trace():
+    """``with`` around the trace of one recomputed block's forward."""
+    _recompute_trace.blocks = getattr(_recompute_trace, "blocks", 0) + 1
+    try:
+        yield
+    finally:
+        _recompute_trace.blocks -= 1
+
+
+class recompute_tally:
+    """``with`` around one trace of a program: `values` and `bytes` (per
+    shard under ``shard_map``) of what it tags `recompute_keep`.  A
+    whole program's tally counts under a recomputed block only.  An
+    operator's own program (`always`) counts whatever it tags: jax keeps
+    its trace and hands it to later calls, inside a recomputed block or
+    not, and each call's tally joins the enclosing program's."""
+
+    def __init__(self, always: bool = False):
+        self.always, self.values, self.bytes = always, 0, 0
+
+    def __enter__(self):
+        if not hasattr(_recompute_trace, "tallies"):
+            _recompute_trace.tallies = []
+        _recompute_trace.tallies.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        _recompute_trace.tallies.pop()
+        return False
+
+    def join(self, values: int, nbytes: int) -> None:
+        self.values += values
+        self.bytes += nbytes
+
+
+def recompute_counting() -> Optional[recompute_tally]:
+    """The innermost open tally if it counts here, else None."""
+    tallies = getattr(_recompute_trace, "tallies", None)
+    if tallies and (tallies[-1].always
+                    or getattr(_recompute_trace, "blocks", 0)):
+        return tallies[-1]
+    return None
+
+
+def recompute_keep(x, count: bool = True):
+    """`x` tagged ``RECOMPUTE_KEEP``: outside ``jax.checkpoint`` an
+    identity.  Its bytes go to the census of the program being traced
+    (``programs.program_summary()``: ``recompute_kept_values``,
+    ``recompute_kept_bytes``), which reads what its recomputed blocks
+    keep.  `count` False where the same value is tagged a second time in
+    a derivative rule, which jax traces later and once for equal calls."""
+    from jax.ad_checkpoint import checkpoint_name
+    tally = recompute_counting() if count else None
+    if tally is not None:
+        tally.join(1, x.size * x.dtype.itemsize)
+    return checkpoint_name(x, RECOMPUTE_KEEP)
 
 
 def get_env(name: str, default: Any = None, dtype: Callable = str) -> Any:

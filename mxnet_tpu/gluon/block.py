@@ -30,7 +30,7 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 
-from ..base import MXNetError, RECOMPUTE_KEEP
+from ..base import MXNetError, RECOMPUTE_KEEP, recomputed_block_trace
 from ..device import Context, current_context, cpu
 from ..ndarray import ndarray as _nd_mod
 from ..ndarray.ndarray import NDArray
@@ -270,13 +270,29 @@ class Block:
     def recompute(self, active: bool = True) -> "Block":
         """Mark this block as RECOMPUTED in the backward pass: inside a
         whole-program trace (``CompiledStep``, a hybridized ancestor) its
-        forward runs under ``jax.checkpoint``, so only its inputs - and
-        what an operator tags ``base.RECOMPUTE_KEEP``: a router's choices
-        - are kept for the backward pass, which runs the forward again
-        (the ops of that second run carry
+        forward runs under ``jax.checkpoint`` and the backward pass runs
+        it again (the ops of that second run carry
         ``checkpoint/rematted_computation`` on their path).  An eager call
-        ignores the mark.  Returns the block, so a model's definition can
-        write ``self.layer = Layer(...).recompute()``."""
+        ignores the mark.
+
+        What the block keeps from its first run: its inputs, and what an
+        operator tags ``base.recompute_keep`` -
+
+        * a router's choices (``parallel.moe.topk_route``): a discrete
+          decision a second run can make the other way (k int32 a token);
+        * the expert layer's sort order, its inverse and the group sizes
+          (``held_expert_ffn``: 2k int32 a token), not sorted again;
+        * the flash kernels' output and logsumexp
+          (``ops/attention.py``): as many bytes as the block's input and
+          4 a head and row, so that the second run holds no forward
+          kernel - its q, k and v are made again by the projections.
+
+        Everything else - norms, projections, the experts' products, the
+        composition's scores where the kernels do not run - is made
+        again.  ``programs.program_summary()`` counts what a program's
+        recomputed blocks keep (``recompute_kept_values``,
+        ``recompute_kept_bytes``).  Returns the block, so a model's
+        definition can write ``self.layer = Layer(...).recompute()``."""
         object.__setattr__(self, "_recompute", bool(active))
         return self
 
@@ -307,7 +323,7 @@ class Block:
                 inner[id(p)] = fresh[-1]
             rebuilt = _rebuild(template,
                                [NDArray(v, ctx=ctx) for v in in_vals], [0])
-            with _ParamOverrideScope(inner), \
+            with _ParamOverrideScope(inner), recomputed_block_trace(), \
                     (_ops_random.trace_key_scope(key) if keyed
                      else contextlib.nullcontext()):
                 out = self._call_impl(*rebuilt, **kwargs)

@@ -75,7 +75,7 @@ __all__ = ["attention_core", "attention_heads", "flash_attention",
 # (one BERT-base layer, forward + backward: 1.77 against 2.53 ms, PERF.md
 # section 6, PR 27) and one key block of up to 1024 rows beats a loop with
 # online rescaling (2.53 against 3.46 ms).
-from ..base import get_env
+from ..base import get_env, recompute_keep
 
 _BLOCK_Q = get_env("MX_FLASH_BLOCK_Q", 256, int)
 _BLOCK_K = get_env("MX_FLASH_BLOCK_K", 256, int)
@@ -723,6 +723,19 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
     return dq, dk, dv
 
 
+def _kept(results, count=True):
+    """The forward kernel's (out, lse) as a recomputed block keeps them
+    (``base.recompute_keep``).  The forward rules hand the tagged values
+    back as the primal output AND as the residuals, so every output of
+    the forward call is known to the block's backward pass and its
+    second run holds no forward kernel: out's bytes (as many as the
+    block's input, which is kept anyway) for a pass whose time grows
+    with T*T.  q, k and v are made again by the projections.  The
+    primal functions tag too, and count: jax traces a forward rule when
+    the call is differentiated, later than the operator that made it."""
+    return tuple(recompute_keep(x, count) for x in results)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention_with_lse(q, k, v, scale, causal):
     """Blockwise flash attention returning (out, lse) — the ring-attention
@@ -731,13 +744,14 @@ def flash_attention_with_lse(q, k, v, scale, causal):
         dq += scale * g_lse ⊙ (P K)          (P K = this kernel with v:=k)
         dk += scale * Pᵀ (g_lse ⊙ q)          (the dkv kernel's dv pass)
     so the merge weights backpropagate without materializing P."""
-    return _flash_fwd(q, k, v, scale, causal)
+    out, lse = _kept(_flash_fwd_res(q, k, v, scale, causal))
+    return out, lse[:, :, 0]
 
 
 def _flash_lse_vjp_fwd(q, k, v, scale, causal):
     # symbolic_zeros=True wraps primals in CustomVJPPrimal
     q, k, v = (x.value if hasattr(x, "value") else x for x in (q, k, v))
-    out, lse = _flash_fwd_res(q, k, v, scale, causal)
+    out, lse = _kept(_flash_fwd_res(q, k, v, scale, causal), count=False)
     return (out, lse[:, :, 0]), (q, k, v, out, lse)
 
 
@@ -775,11 +789,12 @@ flash_attention_with_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd,
 def flash_attention(q, k, v, scale, causal, heads=None):
     """Blockwise flash attention: (B, H, T, D) operands, or with `heads`
     the packed (B, T, heads*D) ones.  The output is laid out like q."""
-    return _flash_fwd_res(q, k, v, scale, causal, heads)[0]
+    return _kept(_flash_fwd_res(q, k, v, scale, causal, heads))[0]
 
 
 def _flash_vjp_fwd(q, k, v, scale, causal, heads):
-    out, lse = _flash_fwd_res(q, k, v, scale, causal, heads)
+    out, lse = _kept(_flash_fwd_res(q, k, v, scale, causal, heads),
+                     count=False)
     return out, (q, k, v, out, lse)
 
 

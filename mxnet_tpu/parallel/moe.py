@@ -33,10 +33,9 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..base import RECOMPUTE_KEEP
+from ..base import recompute_keep
 
 __all__ = ["moe_apply", "moe_parallel", "top1_dispatch", "topk_route",
            "held_expert_ffn", "token_choice_moe"]
@@ -147,7 +146,7 @@ def topk_route(x, router_w, bias, top_k: int, scale: float = 1.0,
                        top_k)
     # a recomputed block keeps the choice of its forward pass: run again,
     # a near-tie can fall the other way (base.RECOMPUTE_KEEP)
-    idx = checkpoint_name(idx, RECOMPUTE_KEEP)
+    idx = recompute_keep(idx)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
         chosen = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
@@ -232,6 +231,10 @@ def held_expert_ffn(x, idx, weights, w_gate_up, w_down, held: Sequence[int],
         order = jnp.argsort(local, stable=True).astype(jnp.int32)
         position = jnp.argsort(order).astype(jnp.int32)  # order's inverse
         sizes = (local[:, None] == jnp.arange(h + 1)).sum(0, dtype=jnp.int32)
+        # a recomputed block sorts once: its second run and its backward
+        # pass read the forward's order (0.26 MB against two sorts)
+        order, position, sizes = map(recompute_keep,
+                                     (order, position, sizes))
         counts, elsewhere = sizes[:h], sizes[h]
         here = counts.sum()
         flat_w = weights.reshape(m, 1).astype(jnp.float32)

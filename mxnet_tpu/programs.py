@@ -68,7 +68,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-from .base import get_env
+from .base import get_env, recompute_counting, recompute_tally
 from . import telemetry as _telemetry
 
 __all__ = [
@@ -119,6 +119,17 @@ def signature_of(args: tuple, kwargs: Optional[dict] = None) -> Tuple:
     Reads shapes/avals only; never touches device data."""
     leaves, treedef = jax.tree_util.tree_flatten((args, kwargs or {}))
     return (treedef, tuple(_leaf_sig(x) for x in leaves))
+
+
+def _avals_of(args: tuple, kwargs: dict) -> Tuple:
+    """What a trace depends on, whether it is given arrays, numpy ones,
+    Python scalars or tracers; a static argument stands for itself."""
+    def aval(x):
+        try:
+            return jax.typeof(x)
+        except TypeError:
+            return x
+    return tuple(aval(x) for x in jax.tree_util.tree_leaves((args, kwargs)))
 
 
 class _SigLeaf:
@@ -295,6 +306,10 @@ class ProgramRecord:
         self.temp_bytes_peak: Optional[int] = None
         self.last_sig: Optional[Tuple] = None
         self.last_retrace: Optional[Dict[str, Any]] = None
+        # what the recomputed blocks of the latest trace keep for their
+        # backward pass (base.recompute_keep)
+        self.recompute_kept_values = 0
+        self.recompute_kept_bytes = 0
         # newest AOT executable (alive in Program._cache anyway): what
         # program_scopes() reads, on demand only
         self.executable = None
@@ -336,9 +351,10 @@ class ProgramRecord:
             self._g_flops.set(cost["flops"])
 
     def note_compile(self, seconds: float, sig: Tuple,
-                     compiled=None) -> None:
+                     compiled=None, kept=None) -> None:
         """Record one executable build: timing, optional AOT metadata,
-        and the retrace explainer's signature diff."""
+        the retrace explainer's signature diff, and what the trace's
+        recomputed blocks keep (`kept`: a ``base.recompute_tally``)."""
         mem = _memory_dict(compiled) if compiled is not None else None
         cost = _cost_dict(compiled) if compiled is not None else None
         diff = None
@@ -366,6 +382,9 @@ class ProgramRecord:
             self.last_sig = sig
             if compiled is not None:
                 self.executable = compiled
+            if kept is not None:
+                self.recompute_kept_values = kept.values
+                self.recompute_kept_bytes = kept.bytes
             self._absorb_metadata_locked(mem, cost)
         self._h_compile.observe(seconds)
         self._publish_metadata_gauges(mem, cost)
@@ -395,6 +414,8 @@ class ProgramRecord:
                 "memory": dict(self.memory) if self.memory else None,
                 "cost": dict(self.cost) if self.cost else None,
                 "temp_bytes_peak": self.temp_bytes_peak,
+                "recompute_kept_values": self.recompute_kept_values,
+                "recompute_kept_bytes": self.recompute_kept_bytes,
                 "last_retrace": self.last_retrace,
             }
 
@@ -428,7 +449,8 @@ def program_table() -> Dict[str, Dict[str, Any]]:
 def program_summary() -> Dict[str, Any]:
     """Roll-up across every registered program: total compile seconds,
     total retraces, peak temp bytes — what the benchmark's driver reads
-    (``compiles``, ``compile_seconds_total``)."""
+    (``compiles``, ``compile_seconds_total``) — and what the programs'
+    recomputed blocks keep (``gluon.Block.recompute``)."""
     table = program_table()
     total_s = sum(t["compile_seconds"]["total"] for t in table.values())
     peak_temp = [t["temp_bytes_peak"] for t in table.values()
@@ -441,6 +463,10 @@ def program_summary() -> Dict[str, Any]:
                                for t in table.values()),
         "compile_seconds_total": round(total_s, 6),
         "peak_temp_bytes": max(peak_temp) if peak_temp else None,
+        "recompute_kept_values": sum(t["recompute_kept_values"]
+                                     for t in table.values()),
+        "recompute_kept_bytes": sum(t["recompute_kept_bytes"]
+                                    for t in table.values()),
     }
 
 
@@ -658,12 +684,18 @@ class Program:
         self._record: Optional[ProgramRecord] = None
         self._seq = 0
         self._noted = 0     # compiles already recorded (under _cache_lock)
+        # what each trace tags base.recompute_keep, by the avals it was
+        # traced for: an AOT program's goes into its census row, a light
+        # one's to the program that calls it inside a recomputed block
+        self._kept: Dict[Tuple, Any] = {}
 
         def _trace_probe(*a, **k):
             # runs at TRACE time only (host side); the attribute write
             # is the point — it marks "this dispatch compiled"
             self._seq += 1
-            return fn(*a, **k)
+            with recompute_tally(always=not aot) as kept:
+                self._kept[_avals_of(a, k)] = kept
+                return fn(*a, **k)
 
         functools.update_wrapper(_trace_probe, fn, updated=())
         # the XLA module is called what the census calls the program
@@ -730,7 +762,9 @@ class Program:
             # two racing cold-callers both compile; the one whose
             # executable the cache kept records the build — compiles
             # stays exact
-            self.record.note_compile(dt, sig, compiled=kept)
+            self.record.note_compile(
+                dt, sig, compiled=kept,
+                kept=self._kept.get(_avals_of(args, kwargs)))
         return kept
 
     def ensure_compiled(self, *args, **kwargs):
@@ -772,6 +806,12 @@ class Program:
                 self._noted = self._seq
             for _ in range(claimed):
                 self.record.note_compile(dt, signature_of(args, kwargs))
+        outer = recompute_counting()
+        if outer is not None:
+            # traced now or handed an earlier trace: the same tags
+            kept = self._kept.get(_avals_of(args, kwargs))
+            if kept is not None:
+                outer.join(kept.values, kept.bytes)
         return out
 
 

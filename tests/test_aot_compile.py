@@ -25,6 +25,7 @@ import jax.numpy as jnp                           # noqa: E402
 from jax.sharding import SingleDeviceSharding     # noqa: E402
 
 from mxnet_tpu import tpu_kernel                  # noqa: E402
+from mxnet_tpu.base import RECOMPUTE_KEEP         # noqa: E402
 from mxnet_tpu.ops import attention as att        # noqa: E402
 from mxnet_tpu.serve.decode import DecodeConfig   # noqa: E402
 
@@ -143,8 +144,27 @@ def _held_experts(direction):
         ((8, 1536, 2048), jnp.bfloat16)]
 
 
-# (id, builder of (fn, [(shape, dtype), ...]), tpu_custom_calls expected,
-#  whether the text must equal attention_impl_scope("xla")'s).  The
+def _recomputed(forward, argnums=(0, 1, 2)):
+    """`forward`'s case as a recomputed block runs it: forward + backward
+    under ``jax.checkpoint`` with ``Block.recompute``'s policy.  The
+    value is returned with the gradients: a forward pass nobody reads is
+    dropped and only the second run remains."""
+    fwd, args = forward
+    keep = jax.checkpoint_policies.save_only_these_names(RECOMPUTE_KEEP)
+
+    def step(*a):
+        value, grads = jax.value_and_grad(
+            lambda *a: jax.checkpoint(fwd, policy=keep)(*a)
+            .astype(jnp.float32).sum(), argnums=argnums)(*a)
+        return (value,) + grads
+
+    return step, args
+
+
+# (id, builder of (fn, [(shape, dtype), ...]) - and True for the rows over
+#  the four chips' data,fsdp = 2x2 inside attention_partition_scope -,
+#  tpu_custom_calls expected or (what else to count in the text, how
+#  many), whether the text must equal attention_impl_scope("xla")'s).  The
 # backward is ONE kernel for dq, dk and dv at any length, causal or not -
 # or the dk/dv kernel and the dq kernel where q, dO and dq do not fit in
 # fast memory (`_Geometry.fused_backward`).
@@ -175,6 +195,16 @@ CASES = [
      lambda: _heads("fwd", (2, 4096, 5120), 20, causal=True), 1, False),
     ("mla-2x20x4096x256-causal-gets-flash-bwd",
      lambda: _heads("bwd", (2, 4096, 5120), 20, causal=True), 2, False),
+    # ... and inside a recomputed block: the forward kernel's out and
+    # logsumexp are kept, so the second run holds no forward kernel (3
+    # calls if they were not) - on one chip, and per shard under the
+    # four chips' layout
+    ("mla-2x20x4096x256-causal-recomputed-runs-the-forward-kernel-once",
+     lambda: _recomputed(_heads("fwd", (2, 4096, 5120), 20, causal=True)),
+     2, False),
+    ("mla-8x20x4096x256-causal-recomputed-on-four-chips",
+     lambda: _recomputed(_heads("fwd", (8, 4096, 5120), 20, causal=True))
+     + (True,), 2, False),
     # the fast-memory arithmetic, held to what Mosaic accepts: the longest
     # causal call of 256 lanes whose q, dO, dq and float32 dq sum stay
     # resident (21,248 rows: 89.3 of the 96 MiB `fused_backward` allows),
@@ -188,6 +218,14 @@ CASES = [
      lambda: _held_experts("fwd"), 3, False),
     ("held-experts-8192x2048-top4-8of64-bwd",
      lambda: _held_experts("bwd"), 8, False),
+    # the router's top-k and the dispatch's order and its inverse are
+    # sorts; a recomputed block keeps all three results and its second
+    # run sorts nothing (5 sorts if order and inverse were not kept)
+    ("held-experts-8192x2048-top4-8of64-sorts",
+     lambda: _held_experts("fwd"), (" sort(", 3), False),
+    ("held-experts-8192x2048-top4-8of64-recomputed-sorts-once",
+     lambda: _recomputed(_held_experts("fwd"), (0, 1, 3, 4)),
+     (" sort(", 3), False),
     # a mask, or a T that is no block multiple, keeps the composition:
     # the same program text as with the kernels switched off
     ("bert-base-8x12x512x64-masked-gets-xla",
@@ -205,20 +243,38 @@ CASES = [
 @pytest.mark.parametrize("build,custom_calls,as_xla",
                          [pytest.param(b, n, x, id=i)
                           for i, b, n, x in CASES])
-def test_compiles_for_v5e(chip, monkeypatch, build, custom_calls, as_xla):
+def test_compiles_for_v5e(topo, chip, monkeypatch, build, custom_calls,
+                          as_xla):
     # the code asks jax.default_backend(), sees the CPU here and would
     # take interpret mode: steer it in the test, not through an option
     monkeypatch.setattr(att, "_on_tpu", lambda: True)
-    fn, args = build()
+    fn, args, *four_chips = build()
+    what, count = custom_calls if isinstance(custom_calls, tuple) \
+        else ("tpu_custom_call", custom_calls)
+    layout, out = None, {}
+    if four_chips:
+        layout, chip = _rows_over_four_chips(topo)
+        out = {"out_shardings": (None,) + (chip,) * len(args)}
     abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
                 for shape, dtype in args]
-    lowered = jax.jit(fn).lower(*abstract)
-    assert lowered.compile().as_text().count("tpu_custom_call") \
-        == custom_calls
+    with att.attention_partition_scope(layout):
+        lowered = jax.jit(fn, **out).lower(*abstract)
+    assert lowered.compile().as_text().count(what) == count
     if as_xla:
         with att.attention_impl_scope("xla"):
             assert jax.jit(fn).lower(*abstract).as_text() \
                 == lowered.as_text()
+
+
+def _rows_over_four_chips(topo):
+    """(the layout of data,fsdp = 2x2 over the described chips, the
+    sharding of a batch's rows over all four)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.parallel import SpecLayout
+    layout = SpecLayout.infer(Mesh(np.array(topo.devices).reshape(2, 2),
+                                   ("data", "fsdp")))
+    return layout, NamedSharding(layout.mesh, P(("data", "fsdp")))
 
 
 def test_flash_is_partitioned_by_batch_on_four_chips(topo, monkeypatch):
@@ -228,13 +284,8 @@ def test_flash_is_partitioned_by_batch_on_four_chips(topo, monkeypatch):
     a [..,512,768] operand.  Without the scope there is no program at
     all: jax refuses to lower a Mosaic kernel it would have to partition
     itself."""
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from mxnet_tpu.parallel import SpecLayout
     monkeypatch.setattr(att, "_on_tpu", lambda: True)
-    layout = SpecLayout.infer(Mesh(np.array(topo.devices).reshape(2, 2),
-                                   ("data", "fsdp")))
-    rows = NamedSharding(layout.mesh, P(("data", "fsdp")))
+    layout, rows = _rows_over_four_chips(topo)
     fn, args = _heads("bwd", (128, 512, 768), 12)
     abstract = [jax.ShapeDtypeStruct(s, d, sharding=rows) for s, d in args]
 

@@ -380,8 +380,8 @@ def test_rms_norm_and_swiglu_blocks():
 
 # -- recomputation ------------------------------------------------------------
 
-def _one_sgd_step(recompute, seq):
-    net, config = _net("float32", seed=21)
+def _one_sgd_step(recompute, seq, **over):
+    net, config = _net("float32", seed=21, **over)
     if not recompute:
         for block in list(net.blocks) + [net.mtp.block]:
             block.recompute(False)
@@ -411,6 +411,42 @@ def test_recomputation_changes_no_gradient_and_frees_memory():
         np.testing.assert_array_equal(marked[name], plain[name],
                                       err_msg=name)
     assert temp_m < temp_p, (temp_m, temp_p)
+    assert "rematted_computation" in text_m
+    assert "rematted_computation" not in text_p
+
+
+def test_a_recomputed_block_keeps_what_its_kernels_made():
+    """Heads of 64 lanes at T = 256 with the flash kernels forced
+    (interpreted here): the marked net's SGD step equals the unmarked
+    net's bit for bit, and the census counts what the recomputed blocks
+    keep from the shapes (that the second run holds no forward kernel is
+    tests/test_aot_compile.py's: interpreted kernels have no name)."""
+    from mxnet_tpu.ops.attention import attention_impl_scope
+    heads = {"qk_nope_head_dim": 48, "qk_rope_head_dim": 16,
+             "v_head_dim": 64}
+    rows, seq, h, d = 2, 256, CONFIG["num_attention_heads"], 64
+    with attention_impl_scope("pallas"):
+        marked, loss_m, _, text_m = _one_sgd_step(True, seq, **heads)
+        kept = programs.find_record("step.step").snapshot()
+        summary = programs.program_summary()
+        plain, loss_p, _, text_p = _one_sgd_step(False, seq, **heads)
+        unmarked = programs.find_record("step.step").snapshot()
+    np.testing.assert_array_equal(loss_m, loss_p)
+    for name in marked:
+        np.testing.assert_array_equal(marked[name], plain[name],
+                                      err_msg=name)
+    # every MLA block (1 dense + 2 expert + the MTP module's) keeps the
+    # kernel's out and logsumexp; every expert layer the router's idx
+    # (twice: the benchmark's net also returns its routing) and the
+    # dispatch's order, position and sizes
+    n, k, held = rows * seq, CONFIG["num_experts_per_tok"], 2
+    attention = 4 * (rows * seq * h * d + rows * h * seq) * 4
+    experts = 3 * (2 * n * k + 2 * n * k + held + 1) * 4
+    assert kept["recompute_kept_values"] == 4 * 2 + 3 * 5
+    assert kept["recompute_kept_bytes"] == attention + experts
+    assert summary["recompute_kept_bytes"] >= attention + experts
+    assert unmarked["recompute_kept_values"] == 0
+    assert unmarked["recompute_kept_bytes"] == 0
     assert "rematted_computation" in text_m
     assert "rematted_computation" not in text_p
 

@@ -12,6 +12,11 @@ holds, `--shape B,H,T,D` (no `--heads`) `attention_core`'s.  Every
 `--blocks` entry (query rows, key rows of the forward, key rows of the
 backward) replaces what `_Geometry.blocks` would choose; `--two-kernel 1`
 makes the backward fall back to its two kernels.
+`--recompute 1` adds the rung of a recomputed block (PERF.md section 6,
+PR 31): the layer forward + backward under `jax.checkpoint`, once with
+`Block.recompute`'s policy - the kernels' out and logsumexp are kept and
+the second run holds no forward kernel - and once with nothing kept, each
+with the kernel calls of its compiled program.
 `--root DIR` measures another checkout's `mxnet_tpu` (the parent's, under
 `_archive/`).  Each line: the variant, milliseconds (median of `--reps`
 timings of `--inner` calls each) and the worst relative error of out, dq,
@@ -36,6 +41,7 @@ def main():
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--blocks", nargs="+", default=["default"])
     ap.add_argument("--two-kernel", nargs="+", type=int, default=[0])
+    ap.add_argument("--recompute", type=int, default=0)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--inner", type=int, default=10)
     ap.add_argument("--platform", default="tpu")
@@ -46,6 +52,7 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from mxnet_tpu.base import RECOMPUTE_KEEP
     from mxnet_tpu.ops import attention as att
 
     device = jax.devices()[0]
@@ -106,6 +113,11 @@ def main():
     wdq, wdk, wdv = (np.concatenate([np.asarray(r[0][i], np.float32)
                                      for r in rows]) for i in range(3))
     wout = np.concatenate([np.asarray(r[1], np.float32) for r in rows])
+    # a recomputed block's policy, and the one that keeps nothing
+    policies = (
+        ("kept", jax.checkpoint_policies.save_only_these_names(
+            RECOMPUTE_KEEP)),
+        ("recomputed", jax.checkpoint_policies.nothing_saveable))
     chosen = att._Geometry.blocks
     fused = getattr(att._Geometry, "fused_backward", None)
     os.makedirs(os.path.dirname(os.path.abspath(opts.out)), exist_ok=True)
@@ -135,6 +147,13 @@ def main():
                         .count("tpu_custom_call")
                     line["forward_ms"] = timed(fwd, q, k, v)
                     line["forward_backward_ms"] = timed(grad, q, k, v, g)
+                    for name, policy in policies if opts.recompute else ():
+                        again = grads(jax.checkpoint(flash, policy=policy))
+                        line["checkpoint_%s_custom_calls" % name] = \
+                            again.lower(q, k, v, g).compile().as_text() \
+                            .count("tpu_custom_call")
+                        line["checkpoint_%s_ms" % name] = \
+                            timed(again, q, k, v, g)
                 except Exception as e:        # a block Mosaic refuses
                     line["error"] = str(e)[:300]
                 print(json.dumps(line), flush=True)
