@@ -14,6 +14,8 @@ from . import random     # noqa: F401
 from . import optimizer  # noqa: F401
 from . import rnn       # noqa: F401
 from . import attention  # noqa: F401
+from . import ssm        # noqa: F401
+from . import ssm        # noqa: F401
 from . import linalg     # noqa: F401
 from . import extra      # noqa: F401
 from . import detection  # noqa: F401

@@ -12,8 +12,9 @@ does, in the order a call meets it:
 * **The rule** (`flash_rule`, stated once; `use_flash` adds the backend
   and the `set_attention_impl` / `attention_impl_scope` override): no
   mask, `Tq` and `Tk` multiples of the 256-row unit, head size 64 or a
-  multiple of 128, bf16 or float32, and `Tq == Tk` when causal.  Anything
-  else takes the jnp composition, which XLA fuses.
+  multiple of 128 (a multiple of 128 where query heads share key/value
+  heads), bf16 or float32, and `Tq == Tk` when causal.  Anything else
+  takes the jnp composition, which XLA fuses.
 * **Two layouts, one set of kernels.**  `attention_core` takes
   `(B, H, T, D)`: one head a block.  `attention_heads` takes the
   `(B, T, H·D)` tensors a projection produces (what
@@ -22,6 +23,12 @@ does, in the order a call meets it:
   tile is lane-dense and no transpose surrounds the call.  (On the v5e a
   `(B, H, T, 64)` operand is padded to 128 lanes in HBM and costs its four
   transposes: one BERT-base layer took 1.94 ms that way against 1.21.)
+* **Grouped key/value heads** (k and v with fewer heads than q: H / Hkv
+  query heads read one key/value head).  The kernels' key and value
+  blocks are indexed by ``head // group``: the one head in HBM is read for
+  each of its query heads and no repeated copy is ever made.  The
+  backward yields dk and dv a QUERY head (float32) and one XLA pass sums
+  each group's - the transpose of the sharing.
 * **bf16 into the MXU**: operands stay in their own dtype, products
   accumulate in float32 (`preferred_element_type`), probabilities are
   cast to the input dtype before the second product — the precision of
@@ -174,24 +181,28 @@ class attention_partition_scope(_Scope):
         super().__init__(_LAYOUT_SCOPE, layout)
 
 
-def flash_rule(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16):
+def flash_rule(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16,
+               group=1):
     """THE dispatch rule: may this call run the flash kernels?  A
-    function of what the call shows and of nothing else."""
+    function of what the call shows and of nothing else.  `group`: query
+    heads a key/value head (1: as many of each); a shared key/value head
+    fills its own 128-lane block."""
     return (mask is None and Tq % _BLOCK_Q == 0 and Tk % _BLOCK_K == 0
-            and (D == 64 or D % _LANES == 0)
+            and (D % _LANES == 0 or (D == 64 and group == 1))
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32))
             and (not causal or Tq == Tk))
 
 
-def use_flash(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16):
+def use_flash(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16,
+              group=1):
     """`flash_rule` under the configured implementation: "xla" never,
     "pallas" wherever the rule holds (the CPU interprets), otherwise on a
     TPU only."""
     impl = current_attention_impl()
     if impl == "xla":
         return False
-    return flash_rule(Tq, Tk, D, causal, mask, dtype) and \
+    return flash_rule(Tq, Tk, D, causal, mask, dtype, group) and \
         (impl == "pallas" or _on_tpu())
 
 
@@ -201,7 +212,11 @@ def use_flash(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16):
 
 
 def _attention_jnp(q, k, v, scale, causal, mask=None):
-    """q,k,v: (B, H, T, D)."""
+    """q: (B, H, T, D); k, v: (B, Hkv, T, D), H / Hkv query heads a
+    key/value head."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
@@ -242,7 +257,8 @@ class _Geometry:
     of a (B, H, 1, T) float32 array - one value a LANE, so HBM holds them
     compactly (a trailing dim of 1 or 8 would be padded to 128 lanes:
     100 MB a BERT-base layer where 0.8 MB do); the grid is
-    (B, H // per_block, row blocks)."""
+    (B, H // per_block, row blocks).  k and v may hold fewer heads:
+    `group` query heads read one (`kv_tile`)."""
 
     def __init__(self, q, k, heads):
         self.packed = heads is not None
@@ -254,33 +270,48 @@ class _Geometry:
             self.B, self.H, self.Tq, self.D = q.shape
             self.width = self.D
         self.Tk = k.shape[1] if self.packed else k.shape[2]
+        self.Hkv = k.shape[2] // self.D if self.packed else k.shape[1]
+        self.group = self.H // self.Hkv
         self.itemsize = q.dtype.itemsize
         self.per_block = self.width // self.D
         if self.H % self.per_block:
             raise ValueError("%d heads of size %d do not fill %d-lane "
                              "blocks" % (self.H, self.D, self.width))
+        if self.H != self.group * self.Hkv or \
+                (self.group > 1 and self.per_block > 1):
+            raise ValueError("%d query heads on %d key/value heads of "
+                             "size %d" % (self.H, self.Hkv, self.D))
 
     def grid(self, rows, block):
         return (self.B, self.H // self.per_block, rows // block)
 
-    def shape(self, rows):
-        """Shape of a q-like array with `rows` positions."""
-        return (self.B, rows, self.H * self.D) if self.packed \
-            else (self.B, self.H, rows, self.D)
+    def shape(self, rows, heads=None):
+        """Shape of a q-like array with `rows` positions (of `heads`
+        heads: k and v have Hkv)."""
+        heads = self.H if heads is None else heads
+        return (self.B, rows, heads * self.D) if self.packed \
+            else (self.B, heads, rows, self.D)
 
-    def tile(self, rows, blocked):
+    def tile(self, rows, blocked, group=1):
         """A (rows, width) tile: the grid's row block when `blocked`,
-        else the whole sequence (resident across the row blocks)."""
+        else the whole sequence (resident across the row blocks); of the
+        head block ``h // group``."""
         import jax.experimental.pallas as pl
         if self.packed:
             return pl.BlockSpec(
                 (None, rows, self.width),
-                (lambda b, h, i: (b, i, h)) if blocked
-                else (lambda b, h, i: (b, 0, h)))
+                (lambda b, h, i: (b, i, h // group)) if blocked
+                else (lambda b, h, i: (b, 0, h // group)))
         return pl.BlockSpec(
             (None, None, rows, self.D),
-            (lambda b, h, i: (b, h, i, 0)) if blocked
-            else (lambda b, h, i: (b, h, 0, 0)))
+            (lambda b, h, i: (b, h // group, i, 0)) if blocked
+            else (lambda b, h, i: (b, h // group, 0, 0)))
+
+    def kv_tile(self, rows, blocked):
+        """`tile` of k or v: the key/value head the grid's query head
+        reads.  Consecutive query heads of a group name the same block,
+        which the pipeline then does not fetch again."""
+        return self.tile(rows, blocked, self.group)
 
     def stats(self, rows, blocked):
         """(per_block, 1, rows) statistics: of the grid's row block when
@@ -526,8 +557,8 @@ def _flash_fwd_res(q, k, v, scale, causal, heads=None):
         kernel,
         interpret=_interpret(),
         grid=geo.grid(geo.Tq, block_q),
-        in_specs=[geo.tile(block_q, True), geo.tile(geo.Tk, False),
-                  geo.tile(geo.Tk, False)],
+        in_specs=[geo.tile(block_q, True), geo.kv_tile(geo.Tk, False),
+                  geo.kv_tile(geo.Tk, False)],
         out_specs=[geo.tile(block_q, True), geo.stats(block_q, True)],
         out_shape=[
             _sds(geo.shape(geo.Tq), q.dtype, q),
@@ -686,8 +717,12 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
     summed = fused and geo.Tk > block_kv
     if not fused:
         need = geo.streamed(geo.Tq, block_kv, block_q)
-    key_tile = geo.tile(block_kv, True)
+    key_tile = geo.kv_tile(block_kv, True)
     whole_q = geo.tile(geo.Tq, False)
+    # dk and dv come out a QUERY head; a group's are summed below (in
+    # float32, which is then what the kernel writes)
+    grad_tile = geo.tile(block_kv, True)
+    grad_type = jnp.float32 if geo.group > 1 else None
     grads = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, d=geo.D),
@@ -695,20 +730,20 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
         grid=geo.grid(geo.Tk, block_kv),
         in_specs=[whole_q, key_tile, key_tile, whole_q,
                   geo.stats(geo.Tq, False), geo.stats(geo.Tq, False)],
-        out_specs=[key_tile, key_tile] + [whole_q] * fused,
-        out_shape=[_sds(geo.shape(geo.Tk), k.dtype, q),
-                   _sds(geo.shape(geo.Tk), v.dtype, q)]
+        out_specs=[grad_tile, grad_tile] + [whole_q] * fused,
+        out_shape=[_sds(geo.shape(geo.Tk), grad_type or k.dtype, q),
+                   _sds(geo.shape(geo.Tk), grad_type or v.dtype, q)]
         + [_sds(geo.shape(geo.Tq), q.dtype, q)] * fused,
         scratch_shapes=[pltpu.VMEM((geo.Tq, geo.width), jnp.float32)]
         * summed,
         **geo.mosaic(need),
     )(q, k, v, g, lse, delta)
+    dk, dv = (_group_sum(geo, x, like) for x, like in
+              zip(grads[:2], (k, v)))
     if fused:
-        dk, dv, dq = grads
-        return dq, dk, dv
-    dk, dv = grads
+        return grads[2], dk, dv
     q_tile = geo.tile(block_q, True)
-    whole_k = geo.tile(geo.Tk, False)
+    whole_k = geo.kv_tile(geo.Tk, False)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
                           block_k=block_k, d=geo.D),
@@ -721,6 +756,19 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
         **geo.mosaic(geo.streamed(geo.Tk, block_q, block_k)),
     )(q, k, v, g, lse, delta)
     return dq, dk, dv
+
+
+def _group_sum(geo, grad, like):
+    """A query head's dk or dv summed over the heads that share the
+    key/value head: `like`'s shape and dtype."""
+    if geo.group == 1:
+        return grad
+    if geo.packed:
+        grad = grad.reshape(geo.B, geo.Tk, geo.Hkv, geo.group, geo.D).sum(3)
+    else:
+        grad = grad.reshape(geo.B, geo.Hkv, geo.group, geo.Tk,
+                            geo.D).sum(2)
+    return grad.reshape(like.shape).astype(like.dtype)
 
 
 def _kept(results, count=True):
@@ -829,6 +877,15 @@ def _flash(q, k, v, scale, causal, heads=None):
 
     layout = _LAYOUT_SCOPE.value
     spec = P() if layout is None else _partition_spec(layout, q.shape, heads)
+    if layout is not None and k.shape != q.shape:
+        # fewer key/value heads: tp takes the head axis only where it
+        # divides them too
+        kv_heads = None if heads is None \
+            else heads * k.shape[2] // q.shape[2]
+        if _partition_spec(layout, k.shape, kv_heads) != spec:
+            axis = 1 if heads is None else 2
+            spec = P(*(None if i == axis else e
+                       for i, e in enumerate(spec)))
     if all(e is None for e in spec):
         return call(q, k, v)
     if heads is not None and spec[2] is not None:
@@ -839,13 +896,14 @@ def _flash(q, k, v, scale, causal, heads=None):
 
 def attention_core(q, k, v, scale=None, causal=False, mask=None):
     """Dispatch: Pallas flash where `use_flash` says so, jnp composition
-    otherwise.  q,k,v: (B, H, T, D).  Both paths trace under the scope
-    ``attention_core``, so a device trace names the attention whatever
-    implements it."""
+    otherwise.  q: (B, H, T, D); k, v: (B, Hkv, T, D) with H a multiple
+    of Hkv (grouped key/value heads: query heads ``g * H/Hkv ..`` read
+    head g).  Both paths trace under the scope ``attention_core``, so a
+    device trace names the attention whatever implements it."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     flash = use_flash(q.shape[2], k.shape[2], q.shape[3], causal, mask,
-                      q.dtype)
+                      q.dtype, q.shape[1] // k.shape[1])
     with jax.named_scope("attention_core"):
         if flash:
             return _flash(q, k, v, scale, causal)
@@ -854,20 +912,28 @@ def attention_core(q, k, v, scale=None, causal=False, mask=None):
 
 def attention_heads(q, k, v, num_heads, scale=None, causal=False, mask=None):
     """attention_core for the (B, T, H*D) tensors a projection produces.
-    Where the flash kernels run they read those tensors as they are (two
-    64-wide heads to a 128-lane block): no transpose on either side.
-    Otherwise: split, transpose, `attention_core`, and back."""
+    k and v may be (B, T, Hkv*D) with H a multiple of Hkv: grouped
+    key/value heads, ``H/Hkv`` query heads on each.  Where the flash
+    kernels run they read those tensors as they are (two 64-wide heads to
+    a 128-lane block; a shared key/value head, a block of its own, for
+    each of its query heads): no transpose and no repeated copy on either
+    side.  Otherwise: split, transpose, `attention_core`, and back."""
     B, Tq, HD = q.shape
     D = HD // num_heads
+    kv_heads = k.shape[2] // D
+    if num_heads % kv_heads or v.shape[2] != k.shape[2]:
+        raise ValueError("attention_heads: %d query heads of size %d on "
+                         "k %s, v %s" % (num_heads, D, k.shape, v.shape))
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    if use_flash(Tq, k.shape[1], D, causal, mask, q.dtype) \
+    if use_flash(Tq, k.shape[1], D, causal, mask, q.dtype,
+                 num_heads // kv_heads) \
             and num_heads % (max(D, _LANES) // D) == 0:
         with jax.named_scope("attention_core"):
             return _flash(q, k, v, scale, causal, heads=num_heads)
     qh = q.reshape(B, Tq, num_heads, D).transpose(0, 2, 1, 3)
-    kh = k.reshape(B, -1, num_heads, D).transpose(0, 2, 1, 3)
-    vh = v.reshape(B, -1, num_heads, D).transpose(0, 2, 1, 3)
+    kh = k.reshape(B, -1, kv_heads, D).transpose(0, 2, 1, 3)
+    vh = v.reshape(B, -1, kv_heads, D).transpose(0, 2, 1, 3)
     out = attention_core(qh, kh, vh, scale=scale, causal=causal, mask=mask)
     return out.transpose(0, 2, 1, 3).reshape(B, Tq, HD)
 

@@ -4,12 +4,16 @@
 ``held_expert_ffn``, ``token_choice_moe``) is the layer a Gluon model
 reaches: ``gluon.nn.TokenChoiceMoE`` calls it through the
 ``moe_token_choice`` op, and ``model_zoo.glm_moe_lite`` builds its expert
-blocks from that.  The layer is told which experts it HOLDS, scores every
-token over ALL experts (sigmoid scores, selection by score + bias, weights
-renormalised over the chosen k and scaled), and computes the part of the
-result its own experts give: assignments sorted by expert, one grouped
-(ragged) product a projection, no capacity and no dropped token whatever
-the imbalance.  On one chip it runs without an exchange; what the absent
+blocks from that, ``model_zoo.nemotron_h`` its latent expert layers.  The
+layer is told which experts it HOLDS, scores every token over ALL experts
+(sigmoid scores, selection by score + bias, weights renormalised over the
+chosen k and scaled), and computes the part of the result its own experts
+give: assignments sorted by expert, one grouped (ragged) product a
+projection, no capacity and no dropped token whatever the imbalance.  An
+expert is what its weights' shapes say - a gated SwiGLU or an ungated
+squared-ReLU MLP - of whatever width its input has (the model width, or
+a latent the layer projects to), which need not be the width the router
+reads.  On one chip it runs without an exchange; what the absent
 experts would add is left out, here and in the reference alike.
 
 **Top-1 with capacity over an ``ep`` mesh axis** (``top1_dispatch``,
@@ -196,33 +200,50 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
-def held_expert_ffn(x, idx, weights, w_gate_up, w_down, held: Sequence[int],
-                    n_experts: int):
+EXPERT_ACTIVATIONS = ("swiglu", "relu2")
+
+
+def held_expert_ffn(x, idx, weights, w_in, w_down, held: Sequence[int],
+                    n_experts: int, activation: str = "swiglu"):
     """The held experts' part of a token-choice layer's result.
 
-    x: (N, d); idx, weights: (N, k) from `topk_route` (over ALL
-    `n_experts`); w_gate_up: (H, d, 2f) and w_down: (H, f, d), the H =
-    len(held) experts stacked in the order of `held` (their global ids).
-    Every expert is a SwiGLU: ``(silu(x Wg) * (x Wu)) Wd``.
+    x: (N, d), what the experts read; idx, weights: (N, k) from
+    `topk_route` (over ALL `n_experts`); w_in and w_down (H, f, d), the H
+    = len(held) experts stacked in the order of `held` (their global
+    ids).  `activation` says what an expert is: ``"swiglu"``, w_in (H, d,
+    2f) the gate's columns first, ``(silu(x Wg) * (x Wu)) Wd``;
+    ``"relu2"``, w_in (H, d, f), no gate, ``relu(x W1)^2 Wd``.
 
     Assignments (token, choice) are sorted by held expert - those routed
     to experts held elsewhere sort last - and go through ONE ragged
     product a projection (``lax.ragged_dot``, groups = held experts) over
-    a buffer of ALL N*k sorted rows: whatever the imbalance, every
-    assignment that lands here has its row, so no token is ever dropped.
-    The rows past the held experts' load belong to no group and cost the
-    product next to nothing (on the v5e, forward + backward at 8192
-    tokens, 8 held of 64, top-4: 4.6 ms with 32768 rows against 3.0 ms
-    with 4096, and a second branch with a shorter buffer for the usual
-    load bought 0.7 ms of a layer's 11.4: PERF.md section 6, PR 28).
+    a buffer of the first ``N * min(k, H)`` sorted rows.  That is the
+    exact no-drop bound: a token's k choices are k DIFFERENT experts, so
+    at most min(k, H) of them are held here, whatever the router does -
+    every assignment that lands here has its row and no token is ever
+    dropped.  (Top-4 with 8 held: min = k, all N*k rows, as before the
+    bound was stated; top-22 with 8 held of 512: 8 N rows where N*k would
+    be 22 N, of which 0.34 N are used at an even load.)  The rows past
+    the held experts' load belong to no group and cost the product next
+    to nothing (on the v5e, forward + backward at 8192 tokens, 8 held of
+    64, top-4: 4.6 ms with 32768 rows against 3.0 ms with 4096, and a
+    second branch with a shorter buffer for the usual load bought 0.7 ms
+    of a layer's 11.4: PERF.md section 6, PR 28).  Where k > H the sum
+    back to tokens reads, for each token, only the min(k, H) places its
+    held assignments can have (its places sorted, the held ones first).
     Gather, products and the weighted sum back to tokens are scoped
     `dispatch`, `experts`, `combine`.
 
     Returns (y (N, d), counts (H,) assignments a held expert, elsewhere
     () assignments routed away), the counts as float32."""
+    if activation not in EXPERT_ACTIVATIONS:
+        raise ValueError("held_expert_ffn: activation %r is none of %r"
+                         % (activation, EXPERT_ACTIVATIONS))
     n, k = idx.shape
     h = len(held)
     m = n * k
+    here_most = min(k, h)           # of one token's assignments
+    rows = n * here_most
     local_of = _np.full((n_experts,), h, _np.int32)
     local_of[_np.asarray(held)] = _np.arange(h)
     with jax.named_scope("dispatch"):
@@ -238,30 +259,44 @@ def held_expert_ffn(x, idx, weights, w_gate_up, w_down, held: Sequence[int],
         counts, elsewhere = sizes[:h], sizes[h]
         here = counts.sum()
         flat_w = weights.reshape(m, 1).astype(jnp.float32)
-        xs = _take_rows(x, order // k, position.reshape(n, k), here)
-        ws = _take_rows(flat_w, order, position.reshape(m, 1), here)
+        taken = order[:rows]
+        places = position.reshape(n, k)
+        if here_most < k:
+            # a token's places in the sorted order, the held ones (those
+            # before `here`) first: min(k, H) columns hold them all
+            places = jnp.sort(places, axis=-1)[:, :here_most]
+        xs = _take_rows(x, taken // k, places, here)
+        ws = _take_rows(flat_w, taken, position.reshape(m, 1), here)
     with jax.named_scope("experts"):
-        gu = lax.ragged_dot(xs, w_gate_up, counts)
-        f = gu.shape[-1] // 2
-        act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-               * gu[:, f:].astype(jnp.float32)).astype(x.dtype)
+        up = lax.ragged_dot(xs, w_in, counts)
+        if activation == "swiglu":
+            f = up.shape[-1] // 2
+            act = (jax.nn.silu(up[:, :f].astype(jnp.float32))
+                   * up[:, f:].astype(jnp.float32)).astype(x.dtype)
+        else:
+            act = jnp.square(jax.nn.relu(up))
         out = lax.ragged_dot(act, w_down, counts)
     with jax.named_scope("combine"):
         out = (out.astype(jnp.float32) * ws).astype(x.dtype)
-        y = _sum_rows(out, position.reshape(n, k), order // k, here)
+        y = _sum_rows(out, places, taken // k, here)
     return y, counts.astype(jnp.float32), elsewhere.astype(jnp.float32)
 
 
-def token_choice_moe(x, router_w, bias, w_gate_up, w_down, *,
+def token_choice_moe(x, router_w, bias, w_in, w_down, *,
                      held: Sequence[int], top_k: int, scale: float = 1.0,
-                     norm_topk_prob: bool = True):
-    """`topk_route` (scope `route`) + `held_expert_ffn` on (..., d)
-    tokens.  Returns (y like x, counts (H,), elsewhere ())."""
-    lead = x.shape[:-1]
-    flat = x.reshape(-1, x.shape[-1])
+                     norm_topk_prob: bool = True,
+                     activation: str = "swiglu", expert_input=None):
+    """`topk_route` (scope `route`) of the (..., d) tokens `x` +
+    `held_expert_ffn` on `expert_input` (..., d'), default `x` itself: a
+    latent expert layer routes on the model width and computes in its
+    latent.  Returns (y like `expert_input`, counts (H,), elsewhere
+    ())."""
+    read = x if expert_input is None else expert_input
+    lead = read.shape[:-1]
     with jax.named_scope("route"):
-        idx, weights = topk_route(flat, router_w, bias, top_k, scale,
-                                  norm_topk_prob)
+        idx, weights = topk_route(x.reshape(-1, x.shape[-1]), router_w,
+                                  bias, top_k, scale, norm_topk_prob)
     y, counts, elsewhere = held_expert_ffn(
-        flat, idx, weights, w_gate_up, w_down, held, router_w.shape[0])
+        read.reshape(-1, read.shape[-1]), idx, weights, w_in, w_down, held,
+        router_w.shape[0], activation)
     return y.reshape(lead + (y.shape[-1],)), counts, elsewhere

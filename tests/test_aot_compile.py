@@ -104,9 +104,11 @@ def _user_kernel():
         [((256, 256), jnp.float32)]
 
 
-def _heads(direction, shape, heads, mask=False, causal=False):
-    """multi_head_attention's entry: the packed (B, T, H*D) operands."""
-    B, T, _ = shape
+def _heads(direction, shape, heads, mask=False, causal=False, kv_heads=None):
+    """multi_head_attention's entry: the packed (B, T, H*D) operands; k
+    and v of `kv_heads` heads where query heads share them."""
+    B, T, HD = shape
+    kv_shape = (B, T, HD // heads * (kv_heads or heads))
 
     def fwd(q, k, v):
         m = jnp.ones((B, 1, T, T), bool) if mask else None
@@ -117,7 +119,49 @@ def _heads(direction, shape, heads, mask=False, causal=False):
                         argnums=(0, 1, 2))(q, k, v)
 
     return (fwd if direction == "fwd" else bwd), \
-        [(shape, jnp.bfloat16)] * 3
+        [(shape, jnp.bfloat16)] + [(kv_shape, jnp.bfloat16)] * 2
+
+
+def _latent_experts(direction):
+    """Nemotron-3-Super's expert layer on one chip's share at the cell's
+    sizes: 8192 tokens of 4096 lanes routed top-22 over 512 experts, 8
+    held, ungated squared-ReLU experts of width 2688 in a 1024-lane
+    latent: a buffer of 8192 x min(22, 8) rows."""
+    from mxnet_tpu.parallel import moe
+
+    def fwd(x, latent, router, correction, up, down):
+        return moe.token_choice_moe(x, router, correction, up, down,
+                                    held=tuple(range(8)), top_k=22,
+                                    scale=5.0, activation="relu2",
+                                    expert_input=latent)[0]
+
+    def bwd(*args):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2, 4, 5))(*args)
+
+    return (fwd if direction == "fwd" else bwd), [
+        ((8192, 4096), jnp.bfloat16), ((8192, 1024), jnp.bfloat16),
+        ((512, 4096), jnp.float32), ((512,), jnp.float32),
+        ((8, 1024, 2688), jnp.bfloat16), ((8, 2688, 1024), jnp.bfloat16)]
+
+
+def _scan(direction):
+    """Mamba-2's scan at the cell's sizes: 8192 positions, 16 heads of 64
+    in one group of 128 state lanes, chunks of 128 (ops/ssm.py: a
+    composition, no kernel of the program's own)."""
+    from mxnet_tpu.ops import ssm
+
+    def fwd(x, dt, a, b, c):
+        return ssm.ssd_scan(x, dt, a, b, c, 128)
+
+    def bwd(*args):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+
+    return (fwd if direction == "fwd" else bwd), [
+        ((1, 8192, 16, 64), jnp.bfloat16), ((1, 8192, 16), jnp.float32),
+        ((16,), jnp.float32), ((1, 8192, 1, 128), jnp.bfloat16),
+        ((1, 8192, 1, 128), jnp.bfloat16)]
 
 
 def _held_experts(direction):
@@ -226,6 +270,37 @@ CASES = [
     ("held-experts-8192x2048-top4-8of64-recomputed-sorts-once",
      lambda: _recomputed(_held_experts("fwd"), (0, 1, 3, 4)),
      (" sort(", 3), False),
+    # Nemotron-3-Super's attention: query heads of 128 lanes that share
+    # key/value heads, causal, 8192 positions - the cell's share (4 on 1)
+    # and the published layer (32 on 2); the kernels index the shared
+    # head's block, the backward is still one kernel (dk and dv come out
+    # a query head; XLA sums a group's), and a recomputed block still
+    # runs the forward kernel once
+    ("gqa-1x4on1x8192x128-causal-gets-flash",
+     lambda: _heads("fwd", (1, 8192, 512), 4, causal=True, kv_heads=1),
+     1, False),
+    ("gqa-1x4on1x8192x128-causal-gets-flash-bwd",
+     lambda: _heads("bwd", (1, 8192, 512), 4, causal=True, kv_heads=1),
+     2, False),
+    ("gqa-1x32on2x8192x128-causal-gets-flash-bwd",
+     lambda: _heads("bwd", (1, 8192, 4096), 32, causal=True, kv_heads=2),
+     2, False),
+    ("gqa-1x4on1x8192x128-causal-recomputed-runs-the-forward-kernel-once",
+     lambda: _recomputed(_heads("fwd", (1, 8192, 512), 4, causal=True,
+                                kv_heads=1)), 2, False),
+    # ... 64-lane heads that share key/value heads have no block of their
+    # own: the composition
+    ("gqa-1x4on2x512x64-gets-xla",
+     lambda: _heads("fwd", (1, 512, 256), 4, causal=True, kv_heads=2),
+     0, True),
+    # its expert layer (top-22 of 512, 8 held, a 65,536-row buffer) and
+    # its scan compile; neither holds a kernel of the program's own
+    ("latent-experts-8192x4096-top22-8of512",
+     lambda: _latent_experts("fwd"), 3, False),
+    ("latent-experts-8192x4096-top22-8of512-bwd",
+     lambda: _latent_experts("bwd"), 8, False),
+    ("ssd-scan-1x8192x16x64-chunk128", lambda: _scan("fwd"), 0, False),
+    ("ssd-scan-1x8192x16x64-chunk128-bwd", lambda: _scan("bwd"), 0, False),
     # a mask, or a T that is no block multiple, keeps the composition:
     # the same program text as with the kernels switched off
     ("bert-base-8x12x512x64-masked-gets-xla",
@@ -305,6 +380,47 @@ def test_flash_is_partitioned_by_batch_on_four_chips(topo, monkeypatch):
     assert not lines(text, "all-gather")
     with pytest.raises(NotImplementedError, match="shard_map"):
         compiled(None)
+
+
+def test_the_nemotron_step_fits_the_described_chip(topo, monkeypatch,
+                                                   capsys):
+    """The whole train step of the cell
+    `nemotron3super-train-s8192-ep64tp8share` - 607.0 M parameters built
+    on the host, the step's program lowered from shapes - compiles for one
+    chip of the described v5e:2x2 (benchmark/tools/aot_check.py, the
+    builder's own tool): its arguments (weights, masters, AdamW's moments)
+    and its temporaries together stay under the 15.0 GB the issue allows
+    of the chip's 16, and both attention layers run the grouped-KV flash
+    kernels (forward + one backward kernel each, kept across the
+    recomputation)."""
+    import importlib.util
+    from benchmark.run import Run
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    load = Run.model
+
+    def model(run):
+        """The cell's model file without its set-up passes over the net
+        (`balance_routers` moves parameter VALUES; here is no device to
+        run a forward on, and the program does not depend on them)."""
+        module = load(run)
+        module.balance_routers = lambda *args: None
+        return module
+
+    monkeypatch.setattr(Run, "model", model)
+    spec = importlib.util.spec_from_file_location(
+        "_aot_check", os.path.join(REPO, "benchmark", "tools",
+                                   "aot_check.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.main(
+        ["--workload", "nemotron3super-train-s8192-ep64tp8share"]) == 0
+    printed = capsys.readouterr().out
+    got = json.loads(printed[printed.index("{"):])
+    assert 8.4 < got["argument_gb"] < 8.6
+    assert got["arguments_plus_temp_gb"] < 15.0
+    assert got["tpu_custom_calls"] > 0
+    # twice as many rows do not fit: what the cell's batch of 1 is for
+    assert got["batch"] == 1
 
 
 # ---------------------------------------------------------------------------

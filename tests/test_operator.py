@@ -93,6 +93,33 @@ def S(inputs, params=None, ref=None, **kw):
     return Spec(inputs, params, ref, **kw)
 
 
+def _own_draw(*shapes):
+    """Inputs from a generator of their own: the battery's shared one
+    hands every later spec other numbers when a spec is added."""
+    rng = np.random.RandomState(33)
+    return [rng.uniform(-1, 1, shape).astype(np.float32) for shape in shapes]
+
+
+def _silu(x):
+    return x / (1.0 + np.exp(-x))
+
+
+def _ssm_scan_ref(x, b, c, dt, z, dt_bias, a_log, d):
+    """Mamba-2's scan position by position: 2 heads of 2 lanes, one
+    group of 3 state lanes."""
+    step = np.log1p(np.exp(dt + dt_bias))
+    xh = x.reshape(1, 8, 2, 2)
+    h = np.zeros((1, 2, 2, 3))
+    out = np.zeros_like(xh)
+    for t in range(8):
+        h = np.exp(-np.exp(a_log) * step[:, t])[..., None, None] * h \
+            + (step[:, t, :, None] * xh[:, t])[..., None] \
+            * b[:, t, None, None, :]
+        out[:, t] = (h * c[:, t, None, None, :]).sum(-1) \
+            + d[:, None] * xh[:, t]
+    return (out.reshape(1, 8, 4) * _silu(z)).astype(np.float32)
+
+
 def _masked_softmax_ref(x, m):
     b = m.astype(bool)
     xm = np.where(b, x, -1e30)
@@ -617,6 +644,14 @@ SPECS.update({
                  None, :, None, None]
              + x.reshape(2, 3, 2, 4)[..., 2:3] * np.sin(np.arange(3))[
                  None, :, None, None]], -1).reshape(2, 3, 8)),
+    "causal_conv1d": S(lambda: _own_draw((2, 6, 3), (3, 4), (3,)),
+                       ref=lambda x, w, b: _silu(sum(
+                           np.pad(x, ((0, 0), (3, 0), (0, 0)))[:, k:k + 6]
+                           * w[:, k] for k in range(4)) + b)),
+    "ssm_scan": S(lambda: _own_draw((1, 8, 4), (1, 8, 3), (1, 8, 3), (1, 8, 2),
+                                    (1, 8, 4), (2,), (2,), (2,)),
+                  params={"num_heads": 2, "num_groups": 1, "chunk": 4},
+                  ref=lambda *a: _ssm_scan_ref(*a)),
     "L2Normalization": S(lambda: [f(3, 4)],
                          ref=lambda x: x / np.sqrt(
                              (x * x).sum(1, keepdims=True) + 1e-10)),
