@@ -281,7 +281,9 @@ class Block:
         * a router's choices (``parallel.moe.topk_route``): a discrete
           decision a second run can make the other way (k int32 a token);
         * the expert layer's sort order, its inverse and the group sizes
-          (``held_expert_ffn``: 2k int32 a token), not sorted again;
+          (``held_expert_ffn``: 2k int32 a token), not sorted again -
+          and the sizes choose the buffer, so every run takes the one
+          the first took;
         * the flash kernels' output and logsumexp
           (``ops/attention.py``): as many bytes as the block's input and
           4 a head and row, so that the second run holds no forward

@@ -265,16 +265,21 @@ class TokenChoiceMoE(HybridBlock):
     of the experts' kind and of width `shared_hidden_size` (default
     ``num_shared * hidden_size``: `num_shared` experts fused).  The held
     experts' products are grouped (assignments sorted by expert, one
-    ragged product a projection) over a buffer of ``tokens * min(top_k,
-    held)`` rows - the most that can land here, so no token is dropped
-    whatever the imbalance; nothing stands in for the experts held
-    elsewhere or for the exchange a deployment over several chips has.
+    ragged product a projection) over a buffer sized to the load the
+    device counts: twice an even router's share where the held experts'
+    assignments fit that, else ``tokens * min(top_k, held)`` rows - the
+    most that can land here, so no token is dropped whatever the
+    imbalance (``parallel.moe.held_expert_ffn``; small layers have the
+    second size alone); nothing stands in for the experts held elsewhere
+    or for the exchange a deployment over several chips has.
 
     Counters: in training mode the float32 aux buffers `assignments`
-    (one a held expert) and `elsewhere` grow inside the step; the
-    telemetry registry reads them on demand as ``moe_assignments{layer,
-    expert}`` and ``moe_assignments_elsewhere{layer}`` (`layer` labels
-    them; without it the layer is not registered).
+    (one a held expert), `elsewhere` and `buffer_calls` grow inside the
+    step; the telemetry registry reads them on demand as
+    ``moe_assignments{layer, expert}``, ``moe_assignments_elsewhere{layer}``,
+    ``moe_exact_buffer_calls{layer}`` (calls whose load passed the short
+    buffer: 0 where the layer has one size) and ``moe_layer_calls{layer}``
+    (`layer` labels them; without it the layer is not registered).
     """
 
     def __init__(self, units, hidden_size, num_experts, top_k,
@@ -324,6 +329,9 @@ class TokenChoiceMoE(HybridBlock):
                                      grad_req="null", init=init.Zero())
         self.elsewhere = Parameter("elsewhere", shape=(1,), grad_req="null",
                                    init=init.Zero())
+        # (calls that took the exact no-drop buffer, calls)
+        self.buffer_calls = Parameter("buffer_calls", shape=(2,),
+                                      grad_req="null", init=init.Zero())
         shared_width = num_shared * hidden_size \
             if shared_hidden_size is None else shared_hidden_size
         self.shared = (SwiGLU if gated else SquaredReLUMLP)(
@@ -355,6 +363,13 @@ class TokenChoiceMoE(HybridBlock):
             "moe_assignments_elsewhere", read(self.elsewhere, 0),
             "assignments routed to experts held on other chips",
             {"layer": layer})
+        _telemetry.registry.read_counter(
+            "moe_exact_buffer_calls", read(self.buffer_calls, 0),
+            "calls whose load passed the short buffer: the exact no-drop "
+            "buffer ran", {"layer": layer})
+        _telemetry.registry.read_counter(
+            "moe_layer_calls", read(self.buffer_calls, 1),
+            "calls of the expert layer in training mode", {"layer": layer})
 
     def cast(self, dtype):
         """The router (weight, bias) and the counters stay float32."""
@@ -383,8 +398,9 @@ class TokenChoiceMoE(HybridBlock):
         y = invoke("moe_token_choice", x, weight, bias,
                    self._first_weight.data(ctx), self.down_weight.data(ctx),
                    self.assignments.data(ctx), self.elsewhere.data(ctx),
-                   *latent, held=self._held, top_k=self._top_k,
-                   scale=self._scale, norm_topk_prob=self._norm,
+                   self.buffer_calls.data(ctx), *latent, held=self._held,
+                   top_k=self._top_k, scale=self._scale,
+                   norm_topk_prob=self._norm,
                    count=autograd.is_training(),
                    activation=self._activation)
         if latent:

@@ -433,21 +433,24 @@ def _rotary_embedding(data, num_heads=1, rotary_dim=None, theta=10000.0):
 # ---------------------------------------------------------------------------
 
 
-@register("moe_token_choice", num_outputs=3, aux_writeback={1: 5, 2: 6})
+@register("moe_token_choice", num_outputs=4,
+          aux_writeback={1: 5, 2: 6, 3: 7})
 def _moe_token_choice(data, router_weight, router_correction,
                       gate_up_weight, down_weight, assignments, elsewhere,
-                      expert_input=None, held=(0,),
+                      buffer_calls, expert_input=None, held=(0,),
                       top_k=1, scale=1.0, norm_topk_prob=True, count=False,
                       activation="swiglu"):
     """The held experts' part of a token-choice layer for (..., d) tokens.
-    `assignments` (H,) and `elsewhere` (1,) are float32 counters written
-    in place (aux state, as BatchNorm's moving statistics): with `count`
-    they grow by this call's assignments a held expert and by those routed
-    to experts held elsewhere.  `gate_up_weight` is the experts' first
-    matrix (`activation` "relu2": no gate in it); `expert_input`, where
-    given, is what the experts read in place of `data` (a latent)."""
+    `assignments` (H,), `elsewhere` (1,) and `buffer_calls` (2,) are
+    float32 counters written in place (aux state, as BatchNorm's moving
+    statistics): with `count` they grow by this call's assignments a held
+    expert, by those routed to experts held elsewhere, and by (1 where
+    the load took the exact no-drop buffer and not the short one, 1 for
+    the call).  `gate_up_weight` is the experts' first matrix
+    (`activation` "relu2": no gate in it); `expert_input`, where given, is
+    what the experts read in place of `data` (a latent)."""
     from ..parallel.moe import token_choice_moe
-    y, here, away = token_choice_moe(
+    y, here, away, exact = token_choice_moe(
         data, router_weight, router_correction, gate_up_weight,
         down_weight, held=tuple(held), top_k=top_k, scale=scale,
         norm_topk_prob=norm_topk_prob, activation=activation,
@@ -455,7 +458,9 @@ def _moe_token_choice(data, router_weight, router_correction,
     if count:
         assignments = assignments + lax.stop_gradient(here)
         elsewhere = elsewhere + lax.stop_gradient(away)
-    return y, assignments, elsewhere
+        buffer_calls = buffer_calls + jnp.stack(
+            [lax.stop_gradient(exact), jnp.ones((), exact.dtype)])
+    return y, assignments, elsewhere, buffer_calls
 
 
 @register("moe_topk_choice", differentiable=False)

@@ -31,6 +31,7 @@ per-token dense references (tests/test_parallel.py, tests/test_glm_moe_lite.py).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Sequence
 
 import numpy as _np
@@ -202,6 +203,175 @@ _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 EXPERT_ACTIVATIONS = ("swiglu", "relu2")
 
+# The rows after the sort come in two sizes, chosen on the device.  The
+# short one is this many times an even router's share of the assignments
+# (rounded up to _SHORT_UNIT rows): balanced routers read 1.04-1.15 x the
+# even share in both benchmark cells (PERF.md section 5), so 2 leaves the
+# usual step well inside it, and `moe_exact_buffer_calls` counts the steps
+# that were not.
+_SHORT_OVER_EVEN = 2
+_SHORT_UNIT = 512
+
+def short_rows(n: int, k: int, h: int, n_experts: int):
+    """The short buffer's rows for `n` tokens choosing `k` of `n_experts`
+    with `h` held - twice an even router's share, a multiple of 512 - or
+    None where it would not halve the exact bound ``n * min(k, h)``: the
+    layer then has one path."""
+    units = -(-_SHORT_OVER_EVEN * n * k * h // (n_experts * _SHORT_UNIT))
+    short = units * _SHORT_UNIT
+    return short if 0 < 2 * short <= n * min(k, h) else None
+
+
+def _activate(up, activation):
+    if activation == "swiglu":
+        f = up.shape[-1] // 2
+        return (jax.nn.silu(up[:, :f].astype(jnp.float32))
+                * up[:, f:].astype(jnp.float32)).astype(up.dtype)
+    return jnp.square(jax.nn.relu(up))
+
+
+def _exact_path(k, activation, x, flat_w, w_in, w_down, order, position,
+                places, counts):
+    """The held experts' result over the exact no-drop buffer: the first
+    ``N * min(k, H)`` sorted rows."""
+    here = counts.sum()
+    with jax.named_scope("dispatch"):
+        taken = order[:places.size]
+        xs = _take_rows(x, taken // k, places, here)
+        ws = _take_rows(flat_w, taken, position[:, None], here)
+    with jax.named_scope("experts"):
+        out = lax.ragged_dot(
+            _activate(lax.ragged_dot(xs, w_in, counts), activation),
+            w_down, counts)
+    with jax.named_scope("combine"):
+        out = (out.astype(jnp.float32) * ws).astype(x.dtype)
+        return _sum_rows(out, places, taken // k, here)
+
+
+class _Short:
+    """The same result over the first `short` sorted rows, for a load
+    `here` <= `short`; forward and backward written apart, so that what
+    the backward reads of the forward is `short` rows long.  Tokens ->
+    rows is a gather of `short` rows; rows -> tokens stays the gather of
+    min(k, H) places a token, now out of a `short`-row buffer: on the v5e
+    that costs a sixth of the same gather out of the exact buffer, and a
+    one-hot product ``(N, short) @ (short, d)`` in its place was no
+    faster at either cell's shape (PERF.md section 6, PR 34)."""
+
+    def __init__(self, k, short, activation, order, position, places,
+                 counts):
+        self.activation, self.counts = activation, counts
+        self.position, self.places = position, places
+        self.here = counts.sum()
+        self.taken = order[:short]
+        self.token = self.taken // k
+        self.valid = (jnp.arange(short) < self.here)[:, None]
+
+    def rows_of(self, tokens):
+        """(short, d): the token's row for every sorted row in the load."""
+        return jnp.where(self.valid, tokens[self.token],
+                         jnp.zeros((), tokens.dtype))
+
+    def tokens_of(self, rows):
+        """(N, d): every token's sum of its rows in the load, in float32."""
+        ok = self.places < self.here
+        got = rows[jnp.where(ok, self.places, 0)]      # (N, min(k, H), d)
+        return jnp.where(ok[..., None], got, jnp.zeros((), rows.dtype)) \
+            .astype(jnp.float32).sum(1).astype(rows.dtype)
+
+    def product_of(self, rows, w):
+        return lax.ragged_dot(rows, w, self.counts)
+
+    def forward(self, x, flat_w, w_in, w_down):
+        """(y, what the backward reads again: `short` rows each)."""
+        with jax.named_scope("dispatch"):
+            xs = self.rows_of(x)
+            ws = jnp.where(self.valid, flat_w[self.taken], 0.0)
+        with jax.named_scope("experts"):
+            up = self.product_of(xs, w_in)
+            out = self.product_of(_activate(up, self.activation), w_down)
+        with jax.named_scope("combine"):
+            y = self.tokens_of((out.astype(jnp.float32) * ws)
+                               .astype(x.dtype))
+        return y, (xs, ws, up, out)
+
+    def backward(self, kept, w_in, w_down, g):
+        """The cotangents of x, flat_w, w_in, w_down."""
+        xs, ws, up, out = kept
+        with jax.named_scope("combine"):
+            g_rows = self.rows_of(g).astype(jnp.float32)
+            d_ws = (g_rows * out.astype(jnp.float32)).sum(-1, keepdims=True)
+            d_out = (g_rows * ws).astype(out.dtype)
+        with jax.named_scope("experts"):
+            act, d_activate = jax.vjp(
+                lambda u: _activate(u, self.activation), up)
+            d_act, d_w_down = jax.vjp(self.product_of, act, w_down)[1](d_out)
+            d_up, = d_activate(d_act)
+            d_xs, d_w_in = jax.vjp(self.product_of, xs, w_in)[1](d_up)
+        with jax.named_scope("dispatch"):
+            d_x = self.tokens_of(d_xs)
+            ok = (self.position < self.here)[:, None]
+            d_flat_w = jnp.where(ok, d_ws[jnp.where(ok[:, 0],
+                                                    self.position, 0)], 0.0)
+        return d_x, d_flat_w, d_w_in, d_w_down
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _sized_to_the_load(k, short, activation, x, flat_w, w_in, w_down, order,
+                       position, places, counts):
+    """The held experts' result by the path the load `counts.sum()` asks
+    for: `_Short` where it fits `short` rows, `_exact_path` otherwise.
+    The choice is NOT differentiated through.  jax's derivative of a
+    ``lax.cond`` hands the backward pass the union of both branches'
+    residuals and fills the branch not taken with zeros, so the short
+    branch would write, and hold, every worst-case-sized intermediate of
+    the exact one (PR 28's second branch: 2.2 GB of temporaries for 0.7
+    ms).  Here the backward pass is a ``cond`` of its own on the same
+    load; between the two cross the inputs and `short`-sized values (the
+    exact branch leaves them zero and runs its forward again in its
+    backward: it is the rare one)."""
+    return _sized_fwd(k, short, activation, x, flat_w, w_in, w_down, order,
+                      position, places, counts)[0]
+
+
+def _sized_fwd(k, short, activation, x, flat_w, w_in, w_down, *indices):
+    def short_path(x, flat_w, w_in, w_down):
+        return _Short(k, short, activation, *indices) \
+            .forward(x, flat_w, w_in, w_down)
+
+    def exact_path(x, flat_w, w_in, w_down):
+        y = _exact_path(k, activation, x, flat_w, w_in, w_down, *indices)
+        kept = jax.eval_shape(short_path, x, flat_w, w_in, w_down)[1]
+        return y, jax.tree_util.tree_map(
+            lambda v: jnp.zeros(v.shape, v.dtype), kept)
+
+    y, kept = lax.cond(indices[-1].sum() <= short, short_path, exact_path,
+                       x, flat_w, w_in, w_down)
+    return y, (x, flat_w, w_in, w_down, indices, kept)
+
+
+def _sized_bwd(k, short, activation, res, g):
+    x, flat_w, w_in, w_down, indices, kept = res
+
+    def short_path(g):
+        return _Short(k, short, activation, *indices) \
+            .backward(kept, w_in, w_down, g)
+
+    def exact_path(g):
+        return jax.vjp(
+            lambda *a: _exact_path(k, activation, *a, *indices),
+            x, flat_w, w_in, w_down)[1](g)
+
+    # the barrier keeps what follows a gradient (an optimizer's cast to
+    # float32) out of the branches: XLA would move it in and hand on
+    # every expert matrix's gradient twice as large
+    return lax.optimization_barrier(
+        lax.cond(indices[-1].sum() <= short, short_path, exact_path, g)) \
+        + (None,) * len(indices)
+
+
+_sized_to_the_load.defvjp(_sized_fwd, _sized_bwd)
+
 
 def held_expert_ffn(x, idx, weights, w_in, w_down, held: Sequence[int],
                     n_experts: int, activation: str = "swiglu"):
@@ -217,33 +387,39 @@ def held_expert_ffn(x, idx, weights, w_in, w_down, held: Sequence[int],
     Assignments (token, choice) are sorted by held expert - those routed
     to experts held elsewhere sort last - and go through ONE ragged
     product a projection (``lax.ragged_dot``, groups = held experts) over
-    a buffer of the first ``N * min(k, H)`` sorted rows.  That is the
+    a buffer of the first sorted rows.  ``N * min(k, H)`` rows are the
     exact no-drop bound: a token's k choices are k DIFFERENT experts, so
     at most min(k, H) of them are held here, whatever the router does -
     every assignment that lands here has its row and no token is ever
-    dropped.  (Top-4 with 8 held: min = k, all N*k rows, as before the
-    bound was stated; top-22 with 8 held of 512: 8 N rows where N*k would
-    be 22 N, of which 0.34 N are used at an even load.)  The rows past
-    the held experts' load belong to no group and cost the product next
-    to nothing (on the v5e, forward + backward at 8192 tokens, 8 held of
-    64, top-4: 4.6 ms with 32768 rows against 3.0 ms with 4096, and a
-    second branch with a shorter buffer for the usual load bought 0.7 ms
-    of a layer's 11.4: PERF.md section 6, PR 28).  Where k > H the sum
-    back to tokens reads, for each token, only the min(k, H) places its
-    held assignments can have (its places sorted, the held ones first).
-    Gather, products and the weighted sum back to tokens are scoped
-    `dispatch`, `experts`, `combine`.
+    dropped.  (Top-4 with 8 held: all N*k rows; top-22 with 8 held of
+    512: 8 N rows where N*k would be 22 N, of which 0.34 N are used at an
+    even load.)  Every gather, elementwise pass and product over that
+    buffer pays for the worst case, 8 to 23 times the load, so the rows
+    come in TWO SIZES and the device chooses by the load it counted
+    (`here`, the held experts' assignments): `short_rows` - twice an even
+    router's share, from the shapes alone - where the load fits it, the
+    exact bound otherwise; where the short size would not halve the
+    bound (small shapes, a layer that holds all its experts) there is one
+    path, the exact one.  The mathematics, the dtypes and the no-drop
+    guarantee are the same in both (`_sized_to_the_load` says why the
+    choice is not differentiated through, and what PR 28's second branch
+    - 0.7 ms of a layer's 11.4 for 2.1 GB - got wrong).  Where k > H the
+    exact path's sum back to tokens reads, for each token, only the
+    min(k, H) places its held assignments can have (its places sorted,
+    the held ones first).  Gather, products and the weighted sum back to
+    tokens are scoped `dispatch`, `experts`, `combine`, in either path,
+    forward and backward.
 
     Returns (y (N, d), counts (H,) assignments a held expert, elsewhere
-    () assignments routed away), the counts as float32."""
+    () assignments routed away, exact () 1 where the load passed the
+    short size and the exact buffer ran - 0 where the layer has one
+    path), the last three as float32."""
     if activation not in EXPERT_ACTIVATIONS:
         raise ValueError("held_expert_ffn: activation %r is none of %r"
                          % (activation, EXPERT_ACTIVATIONS))
     n, k = idx.shape
     h = len(held)
     m = n * k
-    here_most = min(k, h)           # of one token's assignments
-    rows = n * here_most
     local_of = _np.full((n_experts,), h, _np.int32)
     local_of[_np.asarray(held)] = _np.arange(h)
     with jax.named_scope("dispatch"):
@@ -253,33 +429,28 @@ def held_expert_ffn(x, idx, weights, w_in, w_down, held: Sequence[int],
         position = jnp.argsort(order).astype(jnp.int32)  # order's inverse
         sizes = (local[:, None] == jnp.arange(h + 1)).sum(0, dtype=jnp.int32)
         # a recomputed block sorts once: its second run and its backward
-        # pass read the forward's order (0.26 MB against two sorts)
+        # pass read the forward's order (0.26 MB against two sorts) - and
+        # so take the path the forward took
         order, position, sizes = map(recompute_keep,
                                      (order, position, sizes))
         counts, elsewhere = sizes[:h], sizes[h]
-        here = counts.sum()
         flat_w = weights.reshape(m, 1).astype(jnp.float32)
-        taken = order[:rows]
         places = position.reshape(n, k)
-        if here_most < k:
+        if h < k:
             # a token's places in the sorted order, the held ones (those
-            # before `here`) first: min(k, H) columns hold them all
-            places = jnp.sort(places, axis=-1)[:, :here_most]
-        xs = _take_rows(x, taken // k, places, here)
-        ws = _take_rows(flat_w, taken, position.reshape(m, 1), here)
-    with jax.named_scope("experts"):
-        up = lax.ragged_dot(xs, w_in, counts)
-        if activation == "swiglu":
-            f = up.shape[-1] // 2
-            act = (jax.nn.silu(up[:, :f].astype(jnp.float32))
-                   * up[:, f:].astype(jnp.float32)).astype(x.dtype)
-        else:
-            act = jnp.square(jax.nn.relu(up))
-        out = lax.ragged_dot(act, w_down, counts)
-    with jax.named_scope("combine"):
-        out = (out.astype(jnp.float32) * ws).astype(x.dtype)
-        y = _sum_rows(out, places, taken // k, here)
-    return y, counts.astype(jnp.float32), elsewhere.astype(jnp.float32)
+            # before the load) first: min(k, H) columns hold them all
+            places = jnp.sort(places, axis=-1)[:, :h]
+    short = short_rows(n, k, h, n_experts)
+    if short is None:
+        y = _exact_path(k, activation, x, flat_w, w_in, w_down, order,
+                        position, places, counts)
+        exact = jnp.zeros((), jnp.float32)
+    else:
+        y = _sized_to_the_load(k, short, activation, x, flat_w, w_in,
+                               w_down, order, position, places, counts)
+        exact = (counts.sum() > short).astype(jnp.float32)
+    return y, counts.astype(jnp.float32), elsewhere.astype(jnp.float32), \
+        exact
 
 
 def token_choice_moe(x, router_w, bias, w_in, w_down, *,
@@ -289,14 +460,14 @@ def token_choice_moe(x, router_w, bias, w_in, w_down, *,
     """`topk_route` (scope `route`) of the (..., d) tokens `x` +
     `held_expert_ffn` on `expert_input` (..., d'), default `x` itself: a
     latent expert layer routes on the model width and computes in its
-    latent.  Returns (y like `expert_input`, counts (H,), elsewhere
-    ())."""
+    latent.  Returns (y like `expert_input`, counts (H,), elsewhere (),
+    exact ())."""
     read = x if expert_input is None else expert_input
     lead = read.shape[:-1]
     with jax.named_scope("route"):
         idx, weights = topk_route(x.reshape(-1, x.shape[-1]), router_w,
                                   bias, top_k, scale, norm_topk_prob)
-    y, counts, elsewhere = held_expert_ffn(
+    y, counts, elsewhere, exact = held_expert_ffn(
         read.reshape(-1, read.shape[-1]), idx, weights, w_in, w_down, held,
         router_w.shape[0], activation)
-    return y.reshape(lead + (y.shape[-1],)), counts, elsewhere
+    return y.reshape(lead + (y.shape[-1],)), counts, elsewhere, exact

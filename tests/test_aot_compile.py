@@ -13,6 +13,7 @@ The file name sorts first on purpose: tier-1 is cut by the clock.
 """
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -126,7 +127,8 @@ def _latent_experts(direction):
     """Nemotron-3-Super's expert layer on one chip's share at the cell's
     sizes: 8192 tokens of 4096 lanes routed top-22 over 512 experts, 8
     held, ungated squared-ReLU experts of width 2688 in a 1024-lane
-    latent: a buffer of 8192 x min(22, 8) rows."""
+    latent: a buffer of 5632 rows (twice the even share) or of the exact
+    8192 x min(22, 8), chosen on the device - both are in the program."""
     from mxnet_tpu.parallel import moe
 
     def fwd(x, latent, router, correction, up, down):
@@ -169,8 +171,11 @@ def _held_experts(direction):
     sizes: 8192 tokens, top-4 of 64 experts, 8 held, width 1536
     (parallel/moe.py: sorts, gathers and two ragged products, which
     XLA:TPU expands into kernels of its own: `ragged-dot-metadata` and one
-    `ragged-dot-none` a product - forward 2, backward 2 again (recomputed
-    by no one here) + 2 by the rows + 2 by the weights)."""
+    `ragged-dot-none` a product - forward 2; backward 2 by the rows + 2
+    by the weights, and the forward's 2 again in the exact buffer's
+    branch, which keeps nothing of its forward).  The program holds both
+    buffer sizes, 8192 rows and the exact 32768, each in its branch of a
+    `conditional`."""
     from mxnet_tpu.parallel import moe
 
     def fwd(x, router, correction, gate_up, down):
@@ -258,10 +263,15 @@ CASES = [
      lambda: _flash("bwd", (1, 1, 21248, 256), True), 2, False),
     ("long-1x1x21504x256-causal-falls-back-to-two",
      lambda: _flash("bwd", (1, 1, 21504, 256), True), 3, False),
+    # each buffer size has its branch: 3 kernels a forward branch; in the
+    # backward pass 5 in the short buffer's branch and 8 in the exact
+    # one's (its forward again), and in the forward's short branch the 3
+    # that make what the backward reads - the exact branch's forward makes
+    # nothing the gradients read and is dropped
     ("held-experts-8192x2048-top4-8of64",
-     lambda: _held_experts("fwd"), 3, False),
+     lambda: _held_experts("fwd"), 6, False),
     ("held-experts-8192x2048-top4-8of64-bwd",
-     lambda: _held_experts("bwd"), 8, False),
+     lambda: _held_experts("bwd"), 17, False),
     # the router's top-k and the dispatch's order and its inverse are
     # sorts; a recomputed block keeps all three results and its second
     # run sorts nothing (5 sorts if order and inverse were not kept)
@@ -293,12 +303,13 @@ CASES = [
     ("gqa-1x4on2x512x64-gets-xla",
      lambda: _heads("fwd", (1, 512, 256), 4, causal=True, kv_heads=2),
      0, True),
-    # its expert layer (top-22 of 512, 8 held, a 65,536-row buffer) and
-    # its scan compile; neither holds a kernel of the program's own
+    # its expert layer (top-22 of 512, 8 held: 5,632 rows or the exact
+    # 65,536, a branch each as above) and its scan compile; neither holds
+    # a kernel of the program's own
     ("latent-experts-8192x4096-top22-8of512",
-     lambda: _latent_experts("fwd"), 3, False),
+     lambda: _latent_experts("fwd"), 6, False),
     ("latent-experts-8192x4096-top22-8of512-bwd",
-     lambda: _latent_experts("bwd"), 8, False),
+     lambda: _latent_experts("bwd"), 17, False),
     ("ssd-scan-1x8192x16x64-chunk128", lambda: _scan("fwd"), 0, False),
     ("ssd-scan-1x8192x16x64-chunk128-bwd", lambda: _scan("bwd"), 0, False),
     # a mask, or a T that is no block multiple, keeps the composition:
@@ -339,6 +350,52 @@ def test_compiles_for_v5e(topo, chip, monkeypatch, build, custom_calls,
         with att.attention_impl_scope("xla"):
             assert jax.jit(fn).lower(*abstract).as_text() \
                 == lowered.as_text()
+
+
+# cell: (the layer, what is differentiated, the exact buffer's rows, sorts,
+#        the compiler's temporary bytes at the parent (one path, PR 33) and
+#        the most they may be now).  The sorts are the parent's: the
+#        router's top-k, the dispatch's order and its inverse - and, where k
+#        > H, a token's places sorted, once before the choice of a buffer.
+#        The bytes are NOT the parent's: the two buffer sizes share their
+#        temporaries (a conditional's branches never run together), but
+#        what a conditional hands on is a buffer of its own - the gradients
+#        of both expert matrices (here the program's results, in a step
+#        temporaries either way), and the short-sized values the backward
+#        pass reads again; read 1,221,358,080 and 1,245,150,208.
+SIZED_EXPERTS = {
+    "glm": (lambda: _held_experts("fwd"), (0, 1, 3, 4), 32768, 3,
+            815_574_528, 1_250_000_000),
+    "nemotron": (lambda: _latent_experts("fwd"), (0, 1, 2, 4, 5), 65536, 6,
+                 1_163_004_928, 1_280_000_000),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SIZED_EXPERTS))
+def test_a_recomputed_expert_layer_holds_both_buffer_sizes(topo, chip, cell):
+    """The held-expert layer at a cell's shape, forward + backward under
+    `Block.recompute`'s policy, for a described v5e: three conditionals
+    (the forward, the recomputed forward, the backward pass - the choice
+    is not differentiated through), and none hands on a value as long as
+    the exact buffer: a derivative taken THROUGH the choice would fill the
+    exact branch's residuals with zeros in the short one (PR 28's second
+    branch: 2.2 GB of them at the GLM shape)."""
+    build, argnums, rows, sorts, parent, most = SIZED_EXPERTS[cell]
+    step, args = _recomputed(build(), argnums)
+    abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                for shape, dtype in args]
+    compiled = jax.jit(step).lower(*abstract).compile()
+    text = compiled.as_text()
+    assert text.count(" sort(") == sorts
+    conditionals = [line.split(" conditional(")[0]
+                    for line in text.splitlines() if " conditional(" in line]
+    assert len(conditionals) == 3
+    # (one column is the choices' weights' cotangent: N * k scalars)
+    assert not [c for c in conditionals
+                if any(int(width) > 1 for width in
+                       re.findall(r"\[%d,(\d+)\]" % rows, c))]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert parent < temp <= most
 
 
 def _rows_over_four_chips(topo):

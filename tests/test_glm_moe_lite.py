@@ -329,6 +329,131 @@ def test_the_grouped_products_differentiate_like_the_dense_loop():
                                    atol=1e-4 * float(jnp.abs(w).max()))
 
 
+# -- the buffer sized to the load ---------------------------------------------
+
+# 1024 tokens choose of 32 experts.  k < H: top-2, 4 held - twice an even
+# router's share is 512 rows of the exact bound's 2048; k > H: top-5, 2
+# held - 640 -> 1024 of 2048.
+SIZED = {"k<H": dict(top_k=2, held=(3, 4, 5, 6)),
+         "k>H": dict(top_k=5, held=(3, 4))}
+SIZED_TOKENS, SIZED_EXPERTS = 1024, 32
+
+
+def _value_and_grads(fn, *args):
+    # a new function a call: jit and grad remember a function's trace, and
+    # what the caller patches in `moe` is not among its arguments
+    return jax.jit(jax.value_and_grad(lambda *a: fn(*a), argnums=tuple(
+        range(len(args))), has_aux=True))(*args)
+
+
+def _close(got, want, dtype, what):
+    """float32: the same sums in another order; bfloat16: an ulp of the
+    largest sum."""
+    got, want = (np.asarray(v, np.float32) for v in (got, want))
+    scale = float(np.abs(want).max())
+    rel = 1e-6 if dtype == "float32" else 2.0 ** -8
+    np.testing.assert_allclose(got, want, rtol=10 * rel, atol=rel * scale,
+                               err_msg=what)
+
+
+@pytest.mark.parametrize("case", sorted(SIZED))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_short_buffer_gives_what_the_exact_one_gives(monkeypatch, dtype,
+                                                        case):
+    """A load inside twice the even share runs over the short buffer:
+    the result and the gradients by the tokens, the router and both
+    expert matrices are the exact buffer's (the same layer held to one
+    path), and both are the dense loop's."""
+    top_k, held = SIZED[case]["top_k"], SIZED[case]["held"]
+    n, most = SIZED_TOKENS, min(top_k, len(held))
+    short = moe.short_rows(n, top_k, len(held), SIZED_EXPERTS)
+    assert short is not None and 2 * short <= n * most
+    layer = _layer(held, experts=SIZED_EXPERTS, top_k=top_k)
+    params = _layer_params(layer)
+    names = ("router_weight", "gate_up_weight", "down_weight")
+    cast = {"router_weight": jnp.float32}
+    x = jnp.asarray(np.random.RandomState(6).randn(n, 32), dtype)
+    ws = [params[k].astype(cast.get(k, dtype)) for k in names]
+
+    def mine(x, *ws):
+        y, counts, _, exact = moe.token_choice_moe(
+            x, ws[0], params["router_correction"], ws[1], ws[2], held=held,
+            top_k=top_k, scale=1.8)
+        return (y.astype(jnp.float32) ** 2).sum(), (y, counts.sum(), exact)
+
+    def theirs(x, *ws):
+        y = MODEL.reference_expert_layer(
+            dict(params, **dict(zip(names, ws))), x,
+            dict(LAYER_CONFIG, experts_held=list(held),
+                 num_experts_per_tok=top_k), shared=False)
+        return (y ** 2).sum(), y
+
+    (_, (y, here, exact)), grads = _value_and_grads(mine, x, *ws)
+    assert 0 < float(here) <= short and float(exact) == 0.0
+    monkeypatch.setattr(moe, "_SHORT_OVER_EVEN", 0)     # one path
+    assert moe.short_rows(n, top_k, len(held), SIZED_EXPERTS) is None
+    (_, (y_exact, _, ran)), grads_exact = _value_and_grads(mine, x, *ws)
+    assert float(ran) == 0.0                # no second size: nothing to count
+    _close(y, y_exact, dtype, "y")
+    for name, g, w in zip(("x",) + names, grads, grads_exact):
+        _close(g, w, dtype, name)
+    (_, y_loop), grads_loop = _value_and_grads(
+        theirs, *(v.astype(jnp.float32) for v in (x, *ws)))
+    loose = {"float32": dict(rtol=2e-3, atol=1e-4),
+             "bfloat16": dict(rtol=6e-2, atol=3e-2)}[dtype]
+    for name, g, w in zip(("y", "x") + names, (y,) + grads,
+                          (y_loop,) + grads_loop):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w), rtol=loose["rtol"],
+            atol=loose["atol"] * float(jnp.abs(w).max()), err_msg=name)
+
+
+def test_the_short_size_follows_from_the_shapes():
+    """`short_rows` at the two cells' layers: GLM (8192 tokens top-4, 8
+    of 64) 8192 rows of 32768; Nemotron (top-22, 8 of 512) 5632 of 65536.
+    One size where the short one would not halve the bound."""
+    assert moe.short_rows(8192, 4, 8, 64) == 8192
+    assert moe.short_rows(8192, 22, 8, 512) == 5632
+    assert moe.short_rows(64, 2, 3, 8) is None          # 512 rows of 128
+    assert moe.short_rows(8192, 4, 64, 64) is None      # holds them all
+    assert moe.short_rows(1024, 2, 1, 8) == 512         # half: a branch
+
+
+@pytest.mark.parametrize("lifted", [0, 1, 4], ids=["even", "one", "all"])
+def test_a_load_past_the_short_buffer_runs_the_exact_one_and_is_counted(
+        lifted):
+    """A bias that lifts `lifted` of the 4 held experts over the rest
+    sends every token there: 1024 or 2048 assignments against a short
+    buffer of 512.  The exact buffer runs, nothing is dropped, the result
+    is the dense loop's, and `moe_exact_buffer_calls` grows by one a call
+    - and stays 0 while the load fits (`moe_layer_calls` counts both)."""
+    top_k, held = SIZED["k<H"]["top_k"], SIZED["k<H"]["held"]
+    n, label = SIZED_TOKENS, "sized-%d" % lifted
+    layer = _layer(held, experts=SIZED_EXPERTS, top_k=top_k, layer=label)
+    bias = layer.router_correction.data().asnumpy().copy()
+    bias[list(held[:lifted])] = 50.0
+    layer.router_correction.set_data(nd.array(bias, ctx=CTX))
+    x = np.random.RandomState(4).randn(n, 32).astype(np.float32)
+    calls = 3
+    for _ in range(calls):
+        with autograd.train_mode():
+            y = layer(nd.array(x, ctx=CTX))
+    layer(nd.array(x, ctx=CTX))             # not training: not counted
+    here = layer.assignments.data().asnumpy().sum() / calls
+    assert here + layer.elsewhere.data().asnumpy()[0] / calls == n * top_k
+    assert here >= n * min(lifted, top_k)
+    assert (here > 512) == bool(lifted)
+    snapshot = telemetry.registry.snapshot()
+    assert snapshot["moe_layer_calls{layer=%s}" % label]["value"] == calls
+    assert snapshot["moe_exact_buffer_calls{layer=%s}" % label]["value"] \
+        == (calls if lifted else 0)
+    want = MODEL.reference_expert_layer(
+        _layer_params(layer), jnp.asarray(x),
+        dict(LAYER_CONFIG, experts_held=list(held)))
+    np.testing.assert_allclose(np.asarray(y._jax), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+
+
 def test_the_layer_refuses_a_share_it_cannot_hold():
     with pytest.raises(ValueError):
         nn.TokenChoiceMoE(8, 8, 4, 2, held=(1, 1))
@@ -380,8 +505,10 @@ def test_rms_norm_and_swiglu_blocks():
 
 # -- recomputation ------------------------------------------------------------
 
-def _one_sgd_step(recompute, seq, **over):
+def _one_sgd_step(recompute, seq, prepare=None, **over):
     net, config = _net("float32", seed=21, **over)
+    if prepare is not None:
+        prepare(net)
     if not recompute:
         for block in list(net.blocks) + [net.mtp.block]:
             block.recompute(False)
@@ -449,6 +576,46 @@ def test_a_recomputed_block_keeps_what_its_kernels_made():
     assert unmarked["recompute_kept_bytes"] == 0
     assert "rematted_computation" in text_m
     assert "rematted_computation" not in text_p
+
+
+@pytest.mark.parametrize("buffer", ["short", "exact"])
+def test_a_recomputed_block_takes_the_buffer_its_forward_took(buffer):
+    """Expert layers with two sizes (1024 tokens, top-2 of 16, 2 held: 512
+    rows of 2048): the marked net's SGD step equals the unmarked net's bit
+    for bit in float32, whether the load fits the short buffer or - the
+    held experts lifted over the rest - takes the exact one; the recomputed
+    run and the backward pass read the forward's kept sizes, and the
+    counters say which buffer every call of the three layers took."""
+    def prepare(net):
+        # the drawn corrections decide a fresh router's choices: none,
+        # the scores alone choose; the held experts' lifted, every token
+        # chooses both
+        for name, p in net.collect_params().items():
+            if name.endswith("router_correction"):
+                bias = np.zeros(p.shape, np.float32)
+                if buffer == "exact":
+                    bias[CONFIG["experts_held"]] = 50.0
+                p.set_data(nd.array(bias, ctx=CTX))
+
+    over = {"n_routed_experts_published": 16}
+    assert moe.short_rows(1024, 2, 2, 16) == 512
+    marked, loss_m, _, text_m = _one_sgd_step(True, 512, prepare, **over)
+    plain, loss_p, _, text_p = _one_sgd_step(False, 512, prepare, **over)
+    loads = [v.sum() for name, v in marked.items()
+             if name.endswith("moe.assignments")]
+    assert len(loads) == 3 and all(
+        0 < v <= 512 if buffer == "short" else v == 2048 for v in loads)
+    np.testing.assert_array_equal(loss_m, loss_p)
+    for name in marked:
+        np.testing.assert_array_equal(marked[name], plain[name],
+                                      err_msg=name)
+    calls = [v for name, v in marked.items() if name.endswith("buffer_calls")]
+    assert len(calls) == 3
+    for exact, every in calls:
+        assert every == 1 and exact == (buffer == "exact")
+    assert "rematted_computation" in text_m
+    assert "rematted_computation" not in text_p
+    assert " conditional(" in text_m and " conditional(" in text_p
 
 
 def test_an_eager_call_ignores_the_recompute_mark():

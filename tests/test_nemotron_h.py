@@ -542,6 +542,120 @@ def test_the_buffer_is_the_no_drop_bound_under_the_worst_imbalance(top_k,
     assert rows and set(rows) == {n * most}
 
 
+# 1024 tokens choose of 32 experts.  k > H: top-5, 2 held - twice an even
+# router's share is 640 -> 1024 rows of the exact bound's 2048; k < H:
+# top-2, 4 held - 512 of 2048.
+SIZED = {"k>H": dict(top_k=5, held=(3, 4)),
+         "k<H": dict(top_k=2, held=(3, 4, 5, 6))}
+SIZED_TOKENS, SIZED_EXPERTS = 1024, 32
+
+
+def _value_and_grads(fn, *args):
+    # a new function a call: jit and grad remember a function's trace, and
+    # what the caller patches in `moe` is not among its arguments
+    return jax.jit(jax.value_and_grad(lambda *a: fn(*a), argnums=tuple(
+        range(len(args))), has_aux=True))(*args)
+
+
+@pytest.mark.parametrize("case", sorted(SIZED))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_short_buffer_gives_what_the_exact_one_gives(monkeypatch, dtype,
+                                                        case):
+    """Ungated experts in a latent, a load inside twice the even share:
+    over the short buffer the result and the gradients by the
+    tokens, the latent, the router and both expert matrices are the exact
+    buffer's (the same layer held to one path: float32 to 1e-6, bfloat16
+    to an ulp of the largest sum), and both are the dense loop's."""
+    top_k, held = SIZED[case]["top_k"], SIZED[case]["held"]
+    n = SIZED_TOKENS
+    short = moe.short_rows(n, top_k, len(held), SIZED_EXPERTS)
+    assert short is not None and 2 * short <= n * min(top_k, len(held))
+    layer = _layer(held, num_experts=SIZED_EXPERTS, top_k=top_k)
+    params = _params(layer)
+    names = ("router_weight", "up_weight", "down_weight")
+    x = jnp.asarray(np.random.RandomState(6).randn(n, 32), dtype)
+    latent = (x.astype(jnp.float32)
+              @ params["latent_down.weight"].T).astype(dtype)
+    ws = [params[names[0]]] + [params[k].astype(dtype) for k in names[1:]]
+
+    def mine(x, latent, *ws):
+        y, counts, _, exact = moe.token_choice_moe(
+            x, ws[0], params["router_correction"], ws[1], ws[2], held=held,
+            top_k=top_k, scale=5.0, activation="relu2", expert_input=latent)
+        return (y.astype(jnp.float32) ** 2).sum(), (y, counts.sum(), exact)
+
+    def theirs(x, latent, *ws):
+        eq = MODEL._Equations(
+            dict(params, **dict(zip(names, ws))),
+            dict(LAYER_CONFIG, experts_held=list(held),
+                 num_experts_per_tok=top_k))
+        idx, weight, _, _ = eq.route(x, "")
+        y = 0.0
+        for local, expert in enumerate(held):
+            w_e = (weight * (idx == expert)).sum(-1, keepdims=True)
+            y = y + w_e * eq.mlp(latent, ws[1][local], ws[2][local])
+        return (y ** 2).sum(), y
+
+    (_, (y, here, exact)), grads = _value_and_grads(mine, x, latent, *ws)
+    assert 0 < float(here) <= short and float(exact) == 0.0
+    monkeypatch.setattr(moe, "_SHORT_OVER_EVEN", 0)     # one path
+    assert moe.short_rows(n, top_k, len(held), SIZED_EXPERTS) is None
+    (_, (y_exact, _, _)), grads_exact = _value_and_grads(mine, x, latent,
+                                                         *ws)
+    rel = 1e-6 if dtype == "float32" else 2.0 ** -8
+    for name, g, w in zip(("y", "x", "latent") + names, (y,) + grads,
+                          (y_exact,) + grads_exact):
+        g, w = (np.asarray(v, np.float32) for v in (g, w))
+        np.testing.assert_allclose(g, w, rtol=10 * rel,
+                                   atol=rel * float(np.abs(w).max()),
+                                   err_msg=name)
+    with jax.default_matmul_precision("highest"):
+        (_, y_loop), grads_loop = _value_and_grads(
+            theirs, *(v.astype(jnp.float32) for v in (x, latent, *ws)))
+    loose = {"float32": dict(rtol=2e-3, atol=1e-4),
+             "bfloat16": dict(rtol=6e-2, atol=3e-2)}[dtype]
+    for name, g, w in zip(("y", "x", "latent") + names, (y,) + grads,
+                          (y_loop,) + grads_loop):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w), rtol=loose["rtol"],
+            atol=loose["atol"] * float(jnp.abs(w).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("lifted", [0, 2], ids=["even", "all"])
+def test_a_load_past_the_short_buffer_runs_the_exact_one_and_is_counted(
+        lifted):
+    """Top-5 with 2 held of 32 (k > H): both held experts lifted over the
+    rest, every token chooses both - 2048 assignments, the whole exact
+    bound, against a short buffer of 1024.  The exact buffer runs, nothing
+    is dropped, the result is the dense loop's, and
+    `moe_exact_buffer_calls` grows by one a call - and stays 0 while the
+    load fits."""
+    top_k, held = SIZED["k>H"]["top_k"], SIZED["k>H"]["held"]
+    n, label = SIZED_TOKENS, "sized-relu2-%d" % lifted
+    layer = _layer(held, num_experts=SIZED_EXPERTS, top_k=top_k, layer=label)
+    bias = layer.router_correction.data().asnumpy().copy()
+    bias[list(held[:lifted])] = 50.0
+    layer.router_correction.set_data(nd.array(bias, ctx=CTX))
+    x = np.random.RandomState(4).randn(n, 32).astype(np.float32)
+    calls = 2
+    for _ in range(calls):
+        with autograd.train_mode():
+            y = layer(nd.array(x, ctx=CTX))
+    here = layer.assignments.data().asnumpy().sum() / calls
+    assert here + layer.elsewhere.data().asnumpy()[0] / calls == n * top_k
+    assert here == n * 2 if lifted else 0 < here <= 1024
+    snapshot = telemetry.registry.snapshot()
+    assert snapshot["moe_layer_calls{layer=%s}" % label]["value"] == calls
+    assert snapshot["moe_exact_buffer_calls{layer=%s}" % label]["value"] \
+        == (calls if lifted else 0)
+    want = MODEL.reference_expert_layer(
+        _params(layer), jnp.asarray(x),
+        dict(LAYER_CONFIG, experts_held=list(held),
+             num_experts_per_tok=top_k))
+    np.testing.assert_allclose(np.asarray(y._jax), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+
+
 # -- the add-up tests: every share of a layer sums to the uncut layer ---------
 
 def _set(block, values):
