@@ -1,0 +1,9 @@
+"""Kernels: device time a step under the scope `attention_window` (every
+sliding-window attention layer: the four projections, rotary, the banded
+core, the head gate), forward, recomputed forward and backward
+(harness/scope_time_swa.py)."""
+from benchmark.harness import scope_time_swa
+
+
+def read(run):
+    return scope_time_swa.ms(run, "attention_window")

@@ -50,19 +50,36 @@ class RotaryEmbedding(HybridBlock):
     """Rotary positions for the packed ``(B, T, num_heads * D)`` tensor a
     projection produces (the ``rotary_embedding`` operator): the last
     `rotary_dim` lanes of every head turn by the position's angle
-    (``theta``, half-split pairs), the lanes before them pass through."""
+    (``theta``, half-split pairs), the lanes before them pass through -
+    or, with `first`, the first `rotary_dim` lanes turn and the rest
+    pass.  `yarn` = ``(factor, original positions, beta_fast,
+    beta_slow)`` blends the frequencies as YaRN does and
+    `attention_factor` scales cos and sin; the defaults are the plain
+    rotary call."""
 
-    def __init__(self, num_heads, rotary_dim=None, theta=10000.0, **kwargs):
+    def __init__(self, num_heads, rotary_dim=None, theta=10000.0,
+                 first=False, yarn=None, attention_factor=1.0, **kwargs):
         super().__init__(**kwargs)
         self._heads, self._dim, self._theta = num_heads, rotary_dim, theta
+        self._more = {}
+        if first or yarn is not None or attention_factor != 1.0:
+            self._more = dict(
+                first=bool(first),
+                yarn=None if yarn is None else tuple(float(y) for y in yarn),
+                attention_factor=float(attention_factor))
 
-    def forward(self, x):
-        return invoke("rotary_embedding", x, num_heads=self._heads,
-                      rotary_dim=self._dim, theta=float(self._theta))
+    def forward(self, x, num_heads=None):
+        """`num_heads`: of `x`, where it is not the block's own (keys on
+        fewer heads than the queries)."""
+        return invoke("rotary_embedding", x,
+                      num_heads=num_heads or self._heads,
+                      rotary_dim=self._dim, theta=float(self._theta),
+                      **self._more)
 
     def __repr__(self):
-        return "RotaryEmbedding(heads=%s, dim=%s, theta=%g)" % (
-            self._heads, self._dim, self._theta)
+        return "RotaryEmbedding(heads=%s, dim=%s, theta=%g%s)" % (
+            self._heads, self._dim, self._theta,
+            "".join(", %s=%s" % kv for kv in self._more.items()))
 
 
 class SwiGLU(HybridBlock):
@@ -100,20 +117,32 @@ class SquaredReLUMLP(HybridBlock):
 class GroupedQueryAttention(HybridBlock):
     """Causal self-attention of `num_heads` query heads on `num_kv_heads`
     key/value heads of `head_dim` lanes (``num_heads / num_kv_heads``
-    query heads read one key/value head), no biases, no position signal:
+    query heads read one key/value head), no biases:
     ``o(softmax(q k^T / sqrt(head_dim)) v)``.  The core is
     ``multi_head_attention`` on the packed ``(B, T, heads * head_dim)``
     tensors the projections produce, so ``ops/attention.py``'s rule
     decides between the flash kernels - which read the one key/value
-    head for each of its query heads - and the composition."""
+    head for each of its query heads - and the composition.
+
+    The defaults are the layer with no position signal, every earlier
+    key visible and no gate.  `window`: a query sees the `window` keys
+    that end with its own (``multi_head_attention``'s band).  `rotary`:
+    keywords of `RotaryEmbedding` (``rotary_dim``, ``theta``, ``first``,
+    ``yarn``, ``attention_factor``) for the positions q and k take
+    (scope ``rotary``).  `head_gate`: a sigmoid gate a query head on the
+    attention output, ``o_h <- sigmoid(x W_g)_h o_h`` before `o_proj`,
+    from the layer's own input (`g_proj`, ``units x num_heads``; scope
+    ``head_gate``)."""
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
-                 dtype="float32", **kwargs):
+                 dtype="float32", window=None, rotary=None, head_gate=False,
+                 **kwargs):
         super().__init__(**kwargs)
         if num_heads % num_kv_heads:
             raise ValueError("GroupedQueryAttention: %d query heads on %d "
                              "key/value heads" % (num_heads, num_kv_heads))
-        self._heads = num_heads
+        self._heads, self._kv_heads = num_heads, num_kv_heads
+        self._band = {} if window is None else {"window": int(window)}
 
         def dense(out, inp):
             return Dense(out, use_bias=False, flatten=False, in_units=inp,
@@ -123,11 +152,24 @@ class GroupedQueryAttention(HybridBlock):
         self.k_proj = dense(num_kv_heads * head_dim, units)
         self.v_proj = dense(num_kv_heads * head_dim, units)
         self.o_proj = dense(units, num_heads * head_dim)
+        self.rotary = None if rotary is None \
+            else RotaryEmbedding(num_heads, **rotary)
+        self.g_proj = dense(num_heads, units) if head_gate else None
 
     def forward(self, x):
-        out = invoke("multi_head_attention", self.q_proj(x), self.k_proj(x),
-                     self.v_proj(x), None, num_heads=self._heads,
-                     scaled=True, causal=True)
+        q, k = self.q_proj(x), self.k_proj(x)
+        if self.rotary is not None:
+            q, k = self.rotary(q), self.rotary(k, self._kv_heads)
+        out = invoke("multi_head_attention", q, k, self.v_proj(x), None,
+                     num_heads=self._heads, scaled=True, causal=True,
+                     **self._band)
+        if self.g_proj is not None:
+            with trace_scope("head_gate"):
+                b, t, _ = out.shape
+                gate = invoke("sigmoid", self.g_proj(x))
+                out = (out.reshape((b, t, self._heads, -1))
+                       * gate.reshape((b, t, self._heads, 1))) \
+                    .reshape((b, t, -1))
         return self.o_proj(out)
 
 

@@ -182,27 +182,30 @@ class attention_partition_scope(_Scope):
 
 
 def flash_rule(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16,
-               group=1):
+               group=1, window=None):
     """THE dispatch rule: may this call run the flash kernels?  A
     function of what the call shows and of nothing else.  `group`: query
     heads a key/value head (1: as many of each); a shared key/value head
-    fills its own 128-lane block."""
+    fills its own 128-lane block.  `window` (a causal call's, in keys a
+    query sees with its own): a multiple of the 256-row unit."""
     return (mask is None and Tq % _BLOCK_Q == 0 and Tk % _BLOCK_K == 0
             and (D % _LANES == 0 or (D == 64 and group == 1))
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32))
-            and (not causal or Tq == Tk))
+            and (not causal or Tq == Tk)
+            and (window is None
+                 or (causal and window > 0 and window % _BLOCK_K == 0)))
 
 
 def use_flash(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16,
-              group=1):
+              group=1, window=None):
     """`flash_rule` under the configured implementation: "xla" never,
     "pallas" wherever the rule holds (the CPU interprets), otherwise on a
     TPU only."""
     impl = current_attention_impl()
     if impl == "xla":
         return False
-    return flash_rule(Tq, Tk, D, causal, mask, dtype, group) and \
+    return flash_rule(Tq, Tk, D, causal, mask, dtype, group, window) and \
         (impl == "pallas" or _on_tpu())
 
 
@@ -211,9 +214,10 @@ def use_flash(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16,
 # ---------------------------------------------------------------------------
 
 
-def _attention_jnp(q, k, v, scale, causal, mask=None):
+def _attention_jnp(q, k, v, scale, causal, mask=None, window=None):
     """q: (B, H, T, D); k, v: (B, Hkv, T, D), H / Hkv query heads a
-    key/value head."""
+    key/value head.  `window`: a causal call's band - a query sees the
+    `window` keys that end with its own."""
     group = q.shape[1] // k.shape[1]
     if group > 1:
         k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
@@ -222,6 +226,8 @@ def _attention_jnp(q, k, v, scale, causal, mask=None):
     if causal:
         Tq, Tk = q.shape[2], k.shape[2]
         cm = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
+        if window is not None:
+            cm &= ~jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq - window)
         logits = jnp.where(cm, logits, -jnp.inf)
     if mask is not None:
         logits = jnp.where(mask.astype(bool), logits, -1e30)
@@ -322,10 +328,12 @@ class _Geometry:
             (lambda b, h, i: (b, h, 0, i)) if blocked
             else (lambda b, h, i: (b, h, 0, 0)))
 
-    def blocks(self, causal):
+    def blocks(self, causal, window=None):
         """(query rows, key rows of the forward and dq kernels, key rows
         of the dk/dv kernel) a block: twice the unit where it divides T
-        and a step's tiles at that size take no more than a quarter of
+        (and a window: a block is no longer than the band is wide, so a
+        block meets at most two of the other side's on each edge) and a
+        step's tiles at that size take no more than a quarter of
         fast memory (any width a head has had here).  A non-causal forward
         takes up to four units of keys as ONE block: no online rescaling
         (at T = 512 a head's whole K and V are 64 KB each).  A causal call
@@ -337,7 +345,8 @@ class _Geometry:
         def rows(seq, unit):
             fits = self._working(2 * unit, 2 * unit, 4) \
                 <= _FAST_MEMORY // 4
-            return 2 * unit if seq % (2 * unit) == 0 and fits else unit
+            return 2 * unit if math.gcd(seq, window or 0) % (2 * unit) == 0 \
+                and fits else unit
 
         bq, bk = rows(self.Tq, _BLOCK_Q), rows(self.Tk, _BLOCK_K)
         one_block = not causal and self.Tk <= 4 * _BLOCK_K
@@ -449,6 +458,22 @@ def _mask_causal(s, q0, k0, q_axis):
     return jnp.where(q_pos >= k_pos, s, -jnp.inf)
 
 
+def _mask_window(s, q0, k0, q_axis, window):
+    """Scores with key positions `window` or more before the query's set
+    to -inf (the band's lower edge); axes as `_mask_causal`."""
+    q_pos = q0 + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    return jnp.where(q_pos - k_pos < window, s, -jnp.inf)
+
+
+def _masked(s, q0, k0, q_axis, edge, window):
+    """`s` under the edge a masked segment of `_visits` names: the band's
+    lower one (``"window"``) or the diagonal."""
+    if edge == "window":
+        return _mask_window(s, q0, k0, q_axis, window)
+    return _mask_causal(s, q0, k0, q_axis)
+
+
 def _rows(start_block, block):
     import jax.experimental.pallas as pl
     return pl.ds(pl.multiple_of(start_block * block, block), block)
@@ -458,11 +483,11 @@ def _loop(lo, count, body, init):
     """`count` steps of `body` from `lo`: fori_loop, the body once where
     the count is one, nothing where it is zero."""
     if isinstance(count, int) and count <= 1:
-        return body(lo, init) if count else init
+        return body(lo, init) if count > 0 else init
     return lax.fori_loop(lo, lo + count, body, init)
 
 
-def _visits(causal, start, held, stream, seq, held_is_query):
+def _visits(causal, start, held, stream, seq, held_is_query, window=None):
     """What a block of `held` rows from `start` visits of the other,
     streamed operand of `seq` rows: segments (first block, blocks, rows a
     block, masked), in the order to run them.  Non-causal: every block of
@@ -473,12 +498,37 @@ def _visits(causal, start, held, stream, seq, held_is_query):
     the first step on and no row is ever fully masked in all it has seen.
     Causal, the held rows are keys (dk/dv): the diagonal's units, masked,
     then units up to a multiple of `stream`, then the queries after it.
-    Blocks above the diagonal are not visited."""
+    Blocks above the diagonal are not visited.
+    Causal with a `window` (a query sees the `window` keys that end with
+    its own; a multiple of both blocks, `_Geometry.blocks`): the band has
+    a LOWER edge beside the diagonal, and blocks wholly beyond it are not
+    visited either.  In units: the diagonal's, masked as above (first:
+    every query row holds its own key, so the running max is finite from
+    there on), the units between the edges, unmasked, then the `held`
+    rows' worth of units the lower edge crosses, masked ``"window"``;
+    what falls before row 0 or past `seq` is cut off (`start` may be
+    traced: the counts are then, and a count below 1 runs nothing)."""
     if not causal:
         return [(0, seq // stream, stream, False)]
     unit = math.gcd(held, stream)
     per = stream // unit
     diagonal = (start // unit, held // unit, unit, True)
+    if window is not None:
+        if window % unit or held > window:
+            raise ValueError("a window of %d keys under blocks of %d and %d"
+                             % (window, held, stream))
+        at, own, band = start // unit, held // unit, window // unit
+        least, most = (min, max) if isinstance(at, int) \
+            else (jnp.minimum, jnp.maximum)
+        if held_is_query:
+            first, edge = most(at + own - band, 0), most(at - band, 0)
+            return [diagonal, (first, at - first, unit, False),
+                    (edge, at + own - band - edge, unit, "window")]
+        last = seq // unit
+        return [diagonal,
+                (at + own, least(at + band, last) - at - own, unit, False),
+                (at + band, least(at + band + own, last) - at - band, unit,
+                 "window")]
     if held_is_query:
         whole = start // stream
         below = [(0, whole, stream, False)]
@@ -500,7 +550,7 @@ def _visits(causal, start, held, stream, seq, held_is_query):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                      block_k, d):
+                      block_k, d, window=None):
     # refs: q/o (block_q, width), k/v (seq_k, width),
     # lse (per_block, 1, block_q); grid = (B, H // per_block, Tq // block_q)
     import jax.experimental.pallas as pl
@@ -509,7 +559,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     q0 = pl.program_id(2) * block_q
     fold = _exact_scale(scale)
     q_all = q_ref[:] * scale if fold else q_ref[:]
-    visits = _visits(causal, q0, block_q, block_k, k_ref.shape[0], True)
+    visits = _visits(causal, q0, block_q, block_k, k_ref.shape[0], True,
+                     window)
     out = None
     for g, mask in enumerate(_head_masks(width // d, d)):
         q = _only(q_all, mask)
@@ -522,7 +573,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 if not fold:
                     s = s * scale
                 if masked:
-                    s = _mask_causal(s, q0, kb * block, 0)
+                    s = _masked(s, q0, kb * block, 0, masked, window)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                 p = jnp.exp(s - m_new)
                 alpha = jnp.exp(m - m_new)
@@ -544,15 +595,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     o_ref[:] = out.astype(o_ref.dtype)
 
 
-def _flash_fwd_res(q, k, v, scale, causal, heads=None):
+def _flash_fwd_res(q, k, v, scale, causal, heads=None, window=None):
     """(out, lse): out shaped like q, lse (B, H, 1, Tq) float32 — the
     residual the backward consumes."""
     import jax.experimental.pallas as pl
 
     geo = _Geometry(q, k, heads)
-    block_q, block_k, _ = geo.blocks(causal)
+    block_q, block_k, _ = geo.blocks(causal, window)
     kernel = functools.partial(_flash_fwd_kernel, scale=scale, causal=causal,
-                               block_k=block_k, d=geo.D)
+                               block_k=block_k, d=geo.D, window=window)
     return pl.pallas_call(
         kernel,
         interpret=_interpret(),
@@ -592,7 +643,7 @@ def _flash_fwd(q, k, v, scale, causal, heads=None):
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, scale, causal, block_k, d):
+                         dq_ref, *, scale, causal, block_k, d, window=None):
     import jax.experimental.pallas as pl
 
     block_q, width = q_ref.shape
@@ -600,7 +651,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     fold = _exact_scale(scale)
     q_all = q_ref[:] * scale if fold else q_ref[:]
     do_all = do_ref[:]
-    visits = _visits(causal, q0, block_q, block_k, k_ref.shape[0], True)
+    visits = _visits(causal, q0, block_q, block_k, k_ref.shape[0], True,
+                     window)
     out = None
     for g, mask in enumerate(_head_masks(width // d, d)):
         q, do = _only(q_all, mask), _only(do_all, mask)
@@ -614,7 +666,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 if not fold:
                     s = s * scale
                 if masked:
-                    s = _mask_causal(s, q0, kb * block, 0)
+                    s = _masked(s, q0, kb * block, 0, masked, window)
                 p = jnp.exp(s - lse)
                 dp = _dot_nt(do, v_ref[rows, :])
                 ds = (p * (dp - delta)).astype(k_ref.dtype)
@@ -630,7 +682,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dq_ref=None, dq_sum=None, *,
-                          scale, causal, block_q, d):
+                          scale, causal, block_q, d, window=None):
     # lse/delta: (per_block, 1, seq_q); the scores here are (block_k,
     # block_q).  With dq_ref, dq (seq_q, width) comes from the same
     # scores: written a query block at a time where the one key block
@@ -644,7 +696,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     fold = _exact_scale(scale)
     k_all = k_ref[:] * scale if fold else k_ref[:]
     v_all = v_ref[:]
-    visits = _visits(causal, k0, block_k, block_q, q_ref.shape[0], False)
+    visits = _visits(causal, k0, block_k, block_q, q_ref.shape[0], False,
+                     window)
     if dq_sum is not None:
         @pl.when(k_idx == 0)
         def _():
@@ -662,7 +715,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 if not fold:
                     s = s * scale
                 if masked:
-                    s = _mask_causal(s, qb * block, k0, 1)
+                    s = _masked(s, qb * block, k0, 1, masked, window)
                 p = jnp.exp(s - lse_ref[g, :, rows])
                 dv_new = dv + _dot(p.astype(do_blk.dtype), do_blk)
                 dp = _dot_nt(v, do_blk)
@@ -695,14 +748,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[:] = dq.astype(dq_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
+def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None, window=None):
     """(dq, dk, dv) from the forward's residuals and the cotangent `g`
-    of its output.  lse: (B, H, 1, Tq) float32."""
+    of its output.  lse: (B, H, 1, Tq) float32.  A `window` changes what
+    a block visits and nothing of what stays resident: q, dO and dq are
+    fetched and written once a head whatever the band (a band-long
+    residency would need tiles at row offsets no block grid has, to save
+    a transfer of 2 MB a head at T = 8192)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     geo = _Geometry(q, k, heads)
-    block_q, block_k, block_kv = geo.blocks(causal)
+    block_q, block_k, block_kv = geo.blocks(causal, window)
     # delta = rowsum(dO * O): (B, H, 1, Tq) float32, one fused pass
     prod = g.astype(jnp.float32) * o.astype(jnp.float32)
     if geo.packed:
@@ -725,7 +782,7 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
     grad_type = jnp.float32 if geo.group > 1 else None
     grads = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, d=geo.D),
+                          block_q=block_q, d=geo.D, window=window),
         interpret=_interpret(),
         grid=geo.grid(geo.Tk, block_kv),
         in_specs=[whole_q, key_tile, key_tile, whole_q,
@@ -746,7 +803,7 @@ def _flash_bwd(q, k, v, o, lse, g, scale, causal, heads=None):
     whole_k = geo.kv_tile(geo.Tk, False)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, d=geo.D),
+                          block_k=block_k, d=geo.D, window=window),
         interpret=_interpret(),
         grid=geo.grid(geo.Tq, block_q),
         in_specs=[q_tile, whole_k, whole_k, q_tile,
@@ -833,27 +890,65 @@ flash_attention_with_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd,
                                 symbolic_zeros=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, scale, causal, heads=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention(q, k, v, scale, causal, heads=None, window=None):
     """Blockwise flash attention: (B, H, T, D) operands, or with `heads`
-    the packed (B, T, heads*D) ones.  The output is laid out like q."""
-    return _kept(_flash_fwd_res(q, k, v, scale, causal, heads))[0]
+    the packed (B, T, heads*D) ones.  The output is laid out like q.
+    `window`: a causal call's band (`_visits`)."""
+    return _kept(_flash_fwd_res(q, k, v, scale, causal, heads, window))[0]
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, heads):
-    out, lse = _kept(_flash_fwd_res(q, k, v, scale, causal, heads),
+def _flash_vjp_fwd(q, k, v, scale, causal, heads, window):
+    out, lse = _kept(_flash_fwd_res(q, k, v, scale, causal, heads, window),
                      count=False)
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(scale, causal, heads, res, g):
+def _flash_vjp_bwd(scale, causal, heads, window, res, g):
     # the transposed call keeps the forward's name stack, so these
     # kernels too trace under attention_core
     q, k, v, o, lse = res
-    return _flash_bwd(q, k, v, o, lse, g, scale, causal, heads)
+    return _flash_bwd(q, k, v, o, lse, g, scale, causal, heads, window)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _band(causal, window, Tk):
+    """A call's `window` as the kernels and the composition take it: None
+    where the band holds every key a causal query sees anyway."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError("attention: window=%r is a causal call's band of "
+                         "at least one key" % (window,))
+    return None if window >= Tk else int(window)
+
+
+def _count_pairs(geo, window):
+    """The counters ``attention_pairs_needed{kind}`` and
+    ``attention_pairs_visited{kind}`` of one traced causal kernel call,
+    forward: the (query, key) pairs a query may see, and the pairs in the
+    blocks `_visits` runs for them (kind ``window`` with a band, else
+    ``full``).  Their ratio is what the block size costs: 1.0 is a
+    kernel that touches the band alone."""
+    from .. import telemetry
+    block_q, block_k, _ = geo.blocks(True, window)
+    T, W = geo.Tq, geo.Tq if window is None else window
+    visited = sum(block_q * rows * max(count, 0)
+                  for q0 in range(0, T, block_q)
+                  for _, count, rows, _ in _visits(
+                      True, q0, block_q, block_k, geo.Tk, True, window))
+    kind = {"kind": "full" if window is None else "window"}
+    for name, pairs, doc in (
+            ("attention_pairs_needed", T * W - W * (W - 1) // 2,
+             "(query, key) pairs inside the band of the traced causal "
+             "flash calls, forward"),
+            ("attention_pairs_visited", visited,
+             "(query, key) pairs in the blocks the traced causal flash "
+             "calls visit, forward")):
+        telemetry.registry.counter(name, doc, kind).inc(
+            geo.B * geo.H * pairs)
 
 
 def _partition_spec(layout, shape, heads):
@@ -868,12 +963,16 @@ def _partition_spec(layout, shape, heads):
     return P(*entries)
 
 
-def _flash(q, k, v, scale, causal, heads=None):
+def _flash(q, k, v, scale, causal, heads=None, window=None):
     """flash_attention — per shard under the layout CompiledStep noted:
     the kernels are opaque to GSPMD, which would gather their operands
     and run every row on every chip."""
     def call(q, k, v, heads=heads):
-        return flash_attention(q, k, v, float(scale), bool(causal), heads)
+        return flash_attention(q, k, v, float(scale), bool(causal), heads,
+                               window)
+
+    if causal:
+        _count_pairs(_Geometry(q, k, heads), window)
 
     layout = _LAYOUT_SCOPE.value
     spec = P() if layout is None else _partition_spec(layout, q.shape, heads)
@@ -894,23 +993,28 @@ def _flash(q, k, v, scale, causal, heads=None):
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def attention_core(q, k, v, scale=None, causal=False, mask=None):
+def attention_core(q, k, v, scale=None, causal=False, mask=None,
+                   window=None):
     """Dispatch: Pallas flash where `use_flash` says so, jnp composition
     otherwise.  q: (B, H, T, D); k, v: (B, Hkv, T, D) with H a multiple
     of Hkv (grouped key/value heads: query heads ``g * H/Hkv ..`` read
-    head g).  Both paths trace under the scope ``attention_core``, so a
-    device trace names the attention whatever implements it."""
+    head g).  `window` (causal calls): a query sees the `window` keys
+    that end with its own; one that holds every key is no window.  Both
+    paths trace under the scope ``attention_core``, so a device trace
+    names the attention whatever implements it."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
+    window = _band(causal, window, k.shape[2])
     flash = use_flash(q.shape[2], k.shape[2], q.shape[3], causal, mask,
-                      q.dtype, q.shape[1] // k.shape[1])
+                      q.dtype, q.shape[1] // k.shape[1], window)
     with jax.named_scope("attention_core"):
         if flash:
-            return _flash(q, k, v, scale, causal)
-        return _attention_jnp(q, k, v, scale, causal, mask)
+            return _flash(q, k, v, scale, causal, window=window)
+        return _attention_jnp(q, k, v, scale, causal, mask, window)
 
 
-def attention_heads(q, k, v, num_heads, scale=None, causal=False, mask=None):
+def attention_heads(q, k, v, num_heads, scale=None, causal=False, mask=None,
+                    window=None):
     """attention_core for the (B, T, H*D) tensors a projection produces.
     k and v may be (B, T, Hkv*D) with H a multiple of Hkv: grouped
     key/value heads, ``H/Hkv`` query heads on each.  Where the flash
@@ -926,15 +1030,18 @@ def attention_heads(q, k, v, num_heads, scale=None, causal=False, mask=None):
                          "k %s, v %s" % (num_heads, D, k.shape, v.shape))
     if scale is None:
         scale = 1.0 / (D ** 0.5)
+    window = _band(causal, window, k.shape[1])
     if use_flash(Tq, k.shape[1], D, causal, mask, q.dtype,
-                 num_heads // kv_heads) \
+                 num_heads // kv_heads, window) \
             and num_heads % (max(D, _LANES) // D) == 0:
         with jax.named_scope("attention_core"):
-            return _flash(q, k, v, scale, causal, heads=num_heads)
+            return _flash(q, k, v, scale, causal, heads=num_heads,
+                          window=window)
     qh = q.reshape(B, Tq, num_heads, D).transpose(0, 2, 1, 3)
     kh = k.reshape(B, -1, kv_heads, D).transpose(0, 2, 1, 3)
     vh = v.reshape(B, -1, kv_heads, D).transpose(0, 2, 1, 3)
-    out = attention_core(qh, kh, vh, scale=scale, causal=causal, mask=mask)
+    out = attention_core(qh, kh, vh, scale=scale, causal=causal, mask=mask,
+                         window=window)
     return out.transpose(0, 2, 1, 3).reshape(B, Tq, HD)
 
 

@@ -13,6 +13,8 @@ internally for the MXU so no NHWC surface change is needed.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -395,36 +397,71 @@ def _dropout(key, data, p=0.5, mode="training", axes=(), cudnn_off=False,
 
 @register("multi_head_attention")
 def _mha(q, k, v, mask=None, num_heads=1, scaled=True, causal=False,
-         units=None):  # units: carried for ONNX export (scale = sqrt(units/heads))
-    # q,k,v: (B, T, H*D), mask broadcastable to (B, H, Tq, Tk)
+         units=None,  # units: carried for ONNX export (scale = sqrt(units/heads))
+         window=None):
+    # q,k,v: (B, T, H*D), mask broadcastable to (B, H, Tq, Tk); window: a
+    # causal call's band, the `window` keys that end with the query's own
     from .attention import attention_heads
     return attention_heads(q, k, v, num_heads,
                            scale=None if scaled else 1.0,  # None: 1/sqrt(D)
-                           causal=causal, mask=mask)
+                           causal=causal, mask=mask, window=window)
+
+
+def yarn_inv_freq(rotary_dim, theta, factor, original, beta_fast=32.0,
+                  beta_slow=1.0):
+    """YaRN's blended inverse frequencies (Peng et al. 2023, the
+    "NTK-by-parts" rule) of a `rotary_dim`-lane rotary part, float64
+    numpy: ``f_i = theta^(-2i/d)`` kept where a lane turns more than
+    `beta_fast` times over the `original` context, divided by `factor`
+    where it turns less than `beta_slow` times, blended linearly in
+    between."""
+    import numpy as np
+    d = rotary_dim
+    f = float(theta) ** (-np.arange(d // 2, dtype=np.float64) * 2.0 / d)
+
+    def lane(turns):
+        return d * math.log(original / (2.0 * math.pi * turns)) \
+            / (2.0 * math.log(theta))
+
+    low = max(math.floor(lane(beta_fast)), 0)
+    high = min(math.ceil(lane(beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (1.0 - ramp) * f + ramp * f / factor
 
 
 @register("rotary_embedding")
-def _rotary_embedding(data, num_heads=1, rotary_dim=None, theta=10000.0):
+def _rotary_embedding(data, num_heads=1, rotary_dim=None, theta=10000.0,
+                      first=False, yarn=None, attention_factor=1.0):
     """Rotary positions on the packed (B, T, H*D) tensor a projection
     produces: the LAST `rotary_dim` lanes of every head are rotated by
     the position's angle, the first D - rotary_dim pass through (a head
-    laid out [nope | rope], as latent attention has it).  Half-split
-    pairs (lane i with lane i + rotary_dim/2, the NeoX layout), angles
-    pos * theta^(-2i/rotary_dim) in float32, positions 0..T-1."""
+    laid out [nope | rope], as latent attention has it); with `first` the
+    FIRST `rotary_dim` lanes are and the rest pass through (a partial
+    rotary factor).  Half-split pairs (lane i with lane i + rotary_dim/2,
+    the NeoX layout), angles pos * theta^(-2i/rotary_dim) in float32,
+    positions 0..T-1.  `yarn` = (factor, original positions, beta_fast,
+    beta_slow) blends the frequencies (`yarn_inv_freq`);
+    `attention_factor` multiplies cos and sin (YaRN's temperature, on q
+    and k alike)."""
     b, t, hd = data.shape
     d = hd // num_heads
     r = d if rotary_dim is None else rotary_dim
     half = r // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / r))
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / r))
+    else:
+        inv = jnp.asarray(yarn_inv_freq(r, theta, *yarn), jnp.float32)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
     x = data.reshape(b, t, num_heads, d)
-    rope = x[..., d - r:].astype(jnp.float32)
+    rope = (x[..., :r] if first else x[..., d - r:]).astype(jnp.float32)
     x1, x2 = rope[..., :half], rope[..., half:]
     turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                              axis=-1).astype(data.dtype)
-    return jnp.concatenate([x[..., :d - r], turned], axis=-1) \
-        .reshape(b, t, hd)
+    parts = [turned, x[..., r:]] if first else [x[..., :d - r], turned]
+    return jnp.concatenate(parts, axis=-1).reshape(b, t, hd)
 
 
 # ---------------------------------------------------------------------------

@@ -105,15 +105,18 @@ def _user_kernel():
         [((256, 256), jnp.float32)]
 
 
-def _heads(direction, shape, heads, mask=False, causal=False, kv_heads=None):
+def _heads(direction, shape, heads, mask=False, causal=False, kv_heads=None,
+           window=None):
     """multi_head_attention's entry: the packed (B, T, H*D) operands; k
-    and v of `kv_heads` heads where query heads share them."""
+    and v of `kv_heads` heads where query heads share them; `window`, a
+    causal call's band."""
     B, T, HD = shape
     kv_shape = (B, T, HD // heads * (kv_heads or heads))
 
     def fwd(q, k, v):
         m = jnp.ones((B, 1, T, T), bool) if mask else None
-        return att.attention_heads(q, k, v, heads, mask=m, causal=causal)
+        return att.attention_heads(q, k, v, heads, mask=m, causal=causal,
+                                   window=window)
 
     def bwd(q, k, v):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
@@ -298,6 +301,28 @@ CASES = [
     ("gqa-1x4on1x8192x128-causal-recomputed-runs-the-forward-kernel-once",
      lambda: _recomputed(_heads("fwd", (1, 8192, 512), 4, causal=True,
                                 kv_heads=1)), 2, False),
+    # Laguna-XS.2's two kinds of attention at the cell's shapes: 64 query
+    # heads on 8 key/value heads under a band of 512 keys (the windowed
+    # kernels: the same three, `_visits` names the band's blocks) and 48
+    # on 8 with every earlier key; one backward kernel each, and a
+    # recomputed block runs the windowed forward kernel once
+    ("swa-1x64on8x8192x128-window512-gets-flash",
+     lambda: _heads("fwd", (1, 8192, 8192), 64, causal=True, kv_heads=8,
+                    window=512), 1, False),
+    ("swa-1x64on8x8192x128-window512-gets-flash-bwd",
+     lambda: _heads("bwd", (1, 8192, 8192), 64, causal=True, kv_heads=8,
+                    window=512), 2, False),
+    ("swa-1x64on8x8192x128-window512-recomputed-runs-the-forward-kernel-once",
+     lambda: _recomputed(_heads("fwd", (1, 8192, 8192), 64, causal=True,
+                                kv_heads=8, window=512)), 2, False),
+    ("gqa-1x48on8x8192x128-causal-gets-flash-bwd",
+     lambda: _heads("bwd", (1, 8192, 6144), 48, causal=True, kv_heads=8),
+     2, False),
+    # ... a band that is no multiple of the 256-row unit keeps the
+    # composition
+    ("swa-1x8on1x512x128-window100-gets-xla",
+     lambda: _heads("fwd", (1, 512, 1024), 8, causal=True, kv_heads=1,
+                    window=100), 0, False),
     # ... 64-lane heads that share key/value heads have no block of their
     # own: the composition
     ("gqa-1x4on2x512x64-gets-xla",
@@ -477,6 +502,40 @@ def test_the_nemotron_step_fits_the_described_chip(topo, monkeypatch,
     assert got["arguments_plus_temp_gb"] < 15.0
     assert got["tpu_custom_calls"] > 0
     # twice as many rows do not fit: what the cell's batch of 1 is for
+    assert got["batch"] == 1
+
+
+def test_the_laguna_step_fits_the_described_chip(topo, monkeypatch, capsys):
+    """The whole train step of the cell `lagunaxs2-train-s8192-ep8share` -
+    691.6 M parameters built on the host, the step's program lowered from
+    shapes - compiles for one chip of the described v5e:2x2: arguments
+    (weights, masters, AdamW's moments) 9.68 GB and temporaries together
+    under the 15.0 GB the issue allows of the chip's 16 (read: 12.77), all
+    five attention layers in the flash kernels - three of them windowed -
+    kept across the recomputation."""
+    import importlib.util
+    from benchmark.run import Run
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    load = Run.model
+
+    def model(run):
+        module = load(run)
+        module.balance_routers = lambda *args: None     # as above
+        return module
+
+    monkeypatch.setattr(Run, "model", model)
+    spec = importlib.util.spec_from_file_location(
+        "_aot_check", os.path.join(REPO, "benchmark", "tools",
+                                   "aot_check.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    assert tool.main(["--workload", "lagunaxs2-train-s8192-ep8share"]) == 0
+    printed = capsys.readouterr().out
+    got = json.loads(printed[printed.index("{"):])
+    assert 9.6 < got["argument_gb"] < 9.8
+    assert got["arguments_plus_temp_gb"] < 15.0
+    # 5 attention layers x (forward + one backward kernel) among them
+    assert got["tpu_custom_calls"] >= 10
     assert got["batch"] == 1
 
 

@@ -2206,6 +2206,81 @@ def test_grad(opname):
                            atol=spec.grad_atol)
 
 
+# Keywords an operator gained after its spec was written: (operator, spec)
+# under a name of their own, so the battery's one spec an operator stays
+# what it was.  `rotary_embedding`: the FIRST lanes of a head turn (a
+# partial rotary factor), YaRN's blended frequencies (2 pairs, the ramp
+# between them) and its attention factor.  `multi_head_attention`: a
+# causal call's band of `window` keys, on 3 query heads a key/value head.
+def _rotary_first_ref(x, inv, factor=1.0):
+    """4 heads' first 4 lanes turned by `inv` (2 pairs), the rest kept."""
+    h = x.reshape(2, 5, 2, 6)
+    angle = np.arange(5)[:, None] * np.asarray(inv)
+    cos, sin = (fn(angle)[None, :, None, :] * factor
+                for fn in (np.cos, np.sin))
+    a, b = h[..., :2], h[..., 2:4]
+    return np.concatenate([a * cos - b * sin, b * cos + a * sin, h[..., 4:]],
+                          -1).reshape(2, 5, 12).astype(np.float32)
+
+
+def _yarn_ref(x):
+    # d 4, theta 100, factor 8, original 8: dim(n) = 4 ln(8 / (2 pi n)) /
+    # (2 ln 100); beta_fast 2 -> low 0 (dim < 0), beta_slow 0.1 -> high 1
+    f = np.array([1.0, 100.0 ** -0.5])
+    low = max(np.floor(4 * np.log(8 / (2 * np.pi * 2)) / (2 * np.log(100))),
+              0)
+    high = min(np.ceil(4 * np.log(8 / (2 * np.pi * 0.1))
+                       / (2 * np.log(100))), 3)
+    ramp = np.clip((np.arange(2) - low) / (high - low), 0, 1)
+    return _rotary_first_ref(x, (1 - ramp) * f + ramp * f / 8, 1.2)
+
+
+def _band_ref(q, k, v):
+    qh = q.reshape(2, 6, 3, 4).transpose(0, 2, 1, 3)
+    s = np.einsum("bhqd,bkd->bhqk", qh, k) / 2.0
+    i, j = np.arange(6)[:, None], np.arange(6)[None, :]
+    s = np.where((j <= i) & (j > i - 2), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    out = np.einsum("bhqk,bkd->bhqd", p / p.sum(-1, keepdims=True), v)
+    return out.transpose(0, 2, 1, 3).reshape(2, 6, 12).astype(np.float32)
+
+
+KEYWORD_SPECS = {
+    "rotary_embedding[first]": ("rotary_embedding", S(
+        lambda: _own_draw((2, 5, 12)),
+        params={"num_heads": 2, "rotary_dim": 4, "theta": 100.0,
+                "first": True},
+        ref=lambda x: _rotary_first_ref(x, [1.0, 0.1]))),
+    "rotary_embedding[yarn]": ("rotary_embedding", S(
+        lambda: _own_draw((2, 5, 12)),
+        params={"num_heads": 2, "rotary_dim": 4, "theta": 100.0,
+                "first": True, "yarn": (8.0, 8.0, 2.0, 0.1),
+                "attention_factor": 1.2}, ref=_yarn_ref)),
+    "multi_head_attention[window]": ("multi_head_attention", S(
+        lambda: _own_draw((2, 6, 12), (2, 6, 4), (2, 6, 4)),
+        params={"num_heads": 3, "causal": True, "window": 2},
+        ref=_band_ref)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KEYWORD_SPECS))
+def test_forward_keywords(name):
+    opname, spec = KEYWORD_SPECS[name]
+    np_inputs = spec.inputs()
+    out = invoke(opname, *[nd.array(x) for x in np_inputs], **spec.params)
+    assert_almost_equal(out.asnumpy(), spec.ref(*np_inputs), rtol=spec.rtol,
+                        atol=spec.atol, names=(name, name + "_ref"))
+
+
+@pytest.mark.parametrize("name", sorted(KEYWORD_SPECS))
+def test_grad_keywords(name):
+    opname, spec = KEYWORD_SPECS[name]
+    check_numeric_gradient(
+        lambda *args: invoke(opname, *args, **spec.params),
+        [nd.array(x) for x in spec.inputs()], rtol=spec.grad_rtol,
+        atol=spec.grad_atol)
+
+
 def test_ste_identity_gradient():
     """round_ste/sign_ste must pass the incoming gradient straight through
     (reference: stes_op.cc)."""
