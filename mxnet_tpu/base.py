@@ -161,7 +161,8 @@ ENV_CATALOG: Dict[str, Any] = {
 # would then differentiate another function than the forward pass ran
 # (PERF.md section 6, PR 28) - and a kernel's result that costs far more
 # time to make again than bytes to hold (the flash kernels' output and
-# logsumexp, an expert layer's sort order: PERF.md section 6, PR 31).
+# logsumexp, an expert layer's sort order and chosen scores: PERF.md
+# section 6, PR 31 and PR 36).
 RECOMPUTE_KEEP = "mx_recompute_keep"
 
 # trace-time state of the thread: how many recomputed blocks enclose the

@@ -278,8 +278,11 @@ class Block:
         What the block keeps from its first run: its inputs, and what an
         operator tags ``base.recompute_keep`` -
 
-        * a router's choices (``parallel.moe.topk_route``): a discrete
-          decision a second run can make the other way (k int32 a token);
+        * a router's choices (``parallel.moe.topk_choice``): a discrete
+          decision a second run can make the other way (k int32 a token)
+          - and their scores beside them (``topk_route``: k float32), so
+          that the second run holds neither the top-k nor the selection
+          nor the router's product;
         * the expert layer's sort order, its inverse and the group sizes
           (``held_expert_ffn``: 2k int32 a token), not sorted again -
           and the sizes choose the buffer, so every run takes the one

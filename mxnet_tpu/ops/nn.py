@@ -503,9 +503,9 @@ def _moe_token_choice(data, router_weight, router_correction,
 @register("moe_topk_choice", differentiable=False)
 def _moe_topk_choice(data, router_weight, router_correction, top_k=1):
     """The experts (global ids, (..., k) int32) `moe_token_choice` picks."""
-    from ..parallel.moe import topk_route
-    idx, _ = topk_route(data.reshape(-1, data.shape[-1]), router_weight,
-                        router_correction, top_k)
+    from ..parallel.moe import topk_choice
+    idx, _ = topk_choice(data.reshape(-1, data.shape[-1]), router_weight,
+                         router_correction, top_k)
     return idx.reshape(data.shape[:-1] + (top_k,))
 
 

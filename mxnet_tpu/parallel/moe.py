@@ -1,6 +1,6 @@
 """Mixture-of-experts layers: two routers, one file.
 
-**Token-choice top-k on one chip's share** (``topk_route``,
+**Token-choice top-k on one chip's share** (``topk_choice``, ``topk_route``,
 ``held_expert_ffn``, ``token_choice_moe``) is the layer a Gluon model
 reaches: ``gluon.nn.TokenChoiceMoE`` calls it through the
 ``moe_token_choice`` op, and ``model_zoo.glm_moe_lite`` builds its expert
@@ -34,7 +34,6 @@ from __future__ import annotations
 import functools
 from typing import Callable, Sequence
 
-import numpy as _np
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
@@ -42,8 +41,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..base import recompute_keep
 
-__all__ = ["moe_apply", "moe_parallel", "top1_dispatch", "topk_route",
-           "held_expert_ffn", "token_choice_moe"]
+__all__ = ["moe_apply", "moe_parallel", "top1_dispatch", "topk_choice",
+           "topk_route", "held_expert_ffn", "token_choice_moe"]
 
 
 def top1_dispatch(gate_logits, n_experts: int, capacity: int):
@@ -133,29 +132,84 @@ def moe_parallel(expert_fn: Callable, mesh: Mesh, *, ep_axis: str = "ep",
 # ---------------------------------------------------------------------------
 
 
-def topk_route(x, router_w, bias, top_k: int, scale: float = 1.0,
-               norm_topk_prob: bool = True):
-    """Sigmoid scores of every token over ALL experts and the chosen k.
+def _chose(idx, n_experts):
+    """(N, k, E) bool: choice j of token n is expert e.  Never held in
+    memory: what reads it reduces it inside its own fusion."""
+    return idx[:, :, None] == lax.broadcasted_iota(
+        idx.dtype, (1, 1, n_experts), 2)
+
+
+def _pick(table, idx):
+    """``table[n, idx[n, j]]`` (N, k) out of (N, E) as a compare of `idx`
+    against the expert axis and a sum over it: one term of each sum is not
+    zero, so the result is a gather's bit for bit."""
+    return jnp.where(_chose(idx, table.shape[-1]), table[:, None, :],
+                     jnp.zeros((), table.dtype)).sum(-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _chosen_scores(logits, idx, n_experts):
+    """``sigmoid(logits)[n, idx[n, j]]`` (N, k), without a gather: a TPU
+    walks a gather of N * k scalars one index at a time (7-10 ns each;
+    PERF.md section 6, PR 36), where `_pick`'s compare and sum run at the
+    vector units' width.  The cotangent of `logits` is built from the
+    chosen scores alone - ``g s (1 - s)`` spread by the same compare,
+    summed over the choices: a token's k experts are distinct, so each (n,
+    e) receives at most one term, which is what the gather's transpose, a
+    serialised scatter-add into the table, would have put there.  A
+    recomputed block keeps the chosen scores beside the choice (4 N k
+    bytes): its second run then holds no top-k, no selection and - the
+    backward pass reads nothing of the (N, E) table - no router product."""
+    return recompute_keep(_pick(jax.nn.sigmoid(logits), idx))
+
+
+def _chosen_scores_fwd(logits, idx, n_experts):
+    # tagged here too, and handed on as output AND residual (as the flash
+    # kernels' results are: ops/attention.py:_kept)
+    chosen = recompute_keep(_pick(jax.nn.sigmoid(logits), idx), count=False)
+    return chosen, (idx, chosen)
+
+
+def _chosen_scores_bwd(n_experts, res, g):
+    idx, chosen = res
+    return jnp.where(_chose(idx, n_experts),
+                     (g * chosen * (1 - chosen))[:, :, None],
+                     jnp.zeros((), g.dtype)).sum(1), None
+
+
+_chosen_scores.defvjp(_chosen_scores_fwd, _chosen_scores_bwd)
+
+
+def topk_choice(x, router_w, bias, top_k: int):
+    """Every token's logits over ALL experts and the k it chooses by their
+    sigmoid.
 
     x: (N, d) tokens; router_w: (E, d); bias: (E,) selection-only
     correction (``noaux_tc``: it picks, it does not weigh, and it gets no
     gradient).  Float32 at the highest matmul precision whatever the
     inputs' dtype: a near-tie between the k-th and the next score must not
-    be decided by a rounded product.  Returns (idx (N, k) int32, weights
-    (N, k) float32 = scale * s / sum of the chosen s)."""
+    be decided by a rounded product.  Returns (idx (N, k) int32, logits
+    (N, E) float32)."""
     with jax.default_matmul_precision("highest"):
         logits = jnp.dot(x.astype(jnp.float32),
                          router_w.astype(jnp.float32).T)
-    scores = jax.nn.sigmoid(logits)
-    _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)),
-                       top_k)
+    _, idx = lax.top_k(
+        jax.nn.sigmoid(logits)
+        + lax.stop_gradient(bias.astype(jnp.float32)), top_k)
     # a recomputed block keeps the choice of its forward pass: run again,
     # a near-tie can fall the other way (base.RECOMPUTE_KEEP)
-    idx = recompute_keep(idx)
-    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    return recompute_keep(idx.astype(jnp.int32)), logits
+
+
+def topk_route(x, router_w, bias, top_k: int, scale: float = 1.0,
+               norm_topk_prob: bool = True):
+    """`topk_choice` and the weights of the chosen: (idx (N, k) int32,
+    weights (N, k) float32 = scale * s / sum of the chosen s)."""
+    idx, logits = topk_choice(x, router_w, bias, top_k)
+    chosen = _chosen_scores(logits, idx, logits.shape[-1])
     if norm_topk_prob:
         chosen = chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), chosen * scale
+    return idx, chosen * scale
 
 
 @jax.custom_vjp
@@ -222,6 +276,14 @@ def short_rows(n: int, k: int, h: int, n_experts: int):
     return short if 0 < 2 * short <= n * min(k, h) else None
 
 
+def _slot_of(experts, held):
+    """(M,) int32: where each of `experts` (M,) stands in `held` (static
+    global ids), ``len(held)`` where it is not among them."""
+    h = len(held)
+    hit = experts[None, :] == jnp.asarray(held, experts.dtype)[:, None]
+    return jnp.where(hit, jnp.arange(h, dtype=jnp.int32)[:, None], h).min(0)
+
+
 def _activate(up, activation):
     if activation == "swiglu":
         f = up.shape[-1] // 2
@@ -279,6 +341,15 @@ class _Short:
         return jnp.where(ok[..., None], got, jnp.zeros((), rows.dtype)) \
             .astype(jnp.float32).sum(1).astype(rows.dtype)
 
+    def choices_of(self, rows):
+        """(N k, 1): every assignment's row (short, 1) in the load, 0 for
+        one outside it.  `taken` names each assignment once, so `short`
+        values are put in place, where a gather by `position` would walk
+        all N k to find them."""
+        return jnp.zeros((self.position.size, 1), rows.dtype) \
+            .at[self.taken].set(jnp.where(self.valid, rows, 0.0),
+                                unique_indices=True)
+
     def product_of(self, rows, w):
         return lax.ragged_dot(rows, w, self.counts)
 
@@ -310,9 +381,7 @@ class _Short:
             d_xs, d_w_in = jax.vjp(self.product_of, xs, w_in)[1](d_up)
         with jax.named_scope("dispatch"):
             d_x = self.tokens_of(d_xs)
-            ok = (self.position < self.here)[:, None]
-            d_flat_w = jnp.where(ok, d_ws[jnp.where(ok[:, 0],
-                                                    self.position, 0)], 0.0)
+            d_flat_w = self.choices_of(d_ws)
         return d_x, d_flat_w, d_w_in, d_w_down
 
 
@@ -420,11 +489,14 @@ def held_expert_ffn(x, idx, weights, w_in, w_down, held: Sequence[int],
     n, k = idx.shape
     h = len(held)
     m = n * k
-    local_of = _np.full((n_experts,), h, _np.int32)
-    local_of[_np.asarray(held)] = _np.arange(h)
+    if not all(0 <= e < n_experts for e in held):
+        raise ValueError("held_expert_ffn: held experts %r of %d"
+                         % (tuple(held), n_experts))
     with jax.named_scope("dispatch"):
-        # sorts and compares only: a TPU serialises a scatter
-        local = jnp.asarray(local_of)[idx.reshape(m)]    # h = held elsewhere
+        # sorts and compares only: a TPU serialises a scatter, and a
+        # gather of N * k scalars as well.  An assignment's place among
+        # the held experts, h = held elsewhere: H compares
+        local = _slot_of(idx.reshape(m), held)
         order = jnp.argsort(local, stable=True).astype(jnp.int32)
         position = jnp.argsort(order).astype(jnp.int32)  # order's inverse
         sizes = (local[:, None] == jnp.arange(h + 1)).sum(0, dtype=jnp.int32)

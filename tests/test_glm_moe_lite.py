@@ -564,12 +564,12 @@ def test_a_recomputed_block_keeps_what_its_kernels_made():
                                       err_msg=name)
     # every MLA block (1 dense + 2 expert + the MTP module's) keeps the
     # kernel's out and logsumexp; every expert layer the router's idx
-    # (twice: the benchmark's net also returns its routing) and the
-    # dispatch's order, position and sizes
+    # (twice: the benchmark's net also returns its routing), the chosen
+    # scores and the dispatch's order, position and sizes
     n, k, held = rows * seq, CONFIG["num_experts_per_tok"], 2
     attention = 4 * (rows * seq * h * d + rows * h * seq) * 4
-    experts = 3 * (2 * n * k + 2 * n * k + held + 1) * 4
-    assert kept["recompute_kept_values"] == 4 * 2 + 3 * 5
+    experts = 3 * (2 * n * k + n * k + 2 * n * k + held + 1) * 4
+    assert kept["recompute_kept_values"] == 4 * 2 + 3 * 6
     assert kept["recompute_kept_bytes"] == attention + experts
     assert summary["recompute_kept_bytes"] >= attention + experts
     assert unmarked["recompute_kept_values"] == 0

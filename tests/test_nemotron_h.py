@@ -849,10 +849,10 @@ def test_a_recomputed_layer_keeps_what_the_scan_made():
     chunks = seq // c["chunk_size"]
     scan = (rows * seq * heads * hd + rows * chunks * heads * hd * n) * 4
     tokens, k, held = rows * seq, c["num_experts_per_tok"], 3
-    # idx (twice: the benchmark's net also returns its routing), order,
-    # position, sizes
-    experts = (2 * tokens * k + 2 * tokens * k + held + 1) * 4
-    assert kept["recompute_kept_values"] == 2 * 2 + 3 * 5
+    # idx (twice: the benchmark's net also returns its routing), the chosen
+    # scores, order, position, sizes
+    experts = (2 * tokens * k + tokens * k + 2 * tokens * k + held + 1) * 4
+    assert kept["recompute_kept_values"] == 2 * 2 + 3 * 6
     assert kept["recompute_kept_bytes"] == 2 * scan + 3 * experts
     assert unmarked["recompute_kept_values"] == 0
     assert "rematted_computation" in text_m
