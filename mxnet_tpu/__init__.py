@@ -12,6 +12,11 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
+from . import telemetry as _telemetry
+# the start-up timeline's first span: this file's first line to its last
+_import_span = _telemetry.phase("import")
+_import_span.__enter__()
+
 from .base import MXNetError, get_env, set_env, environment, force_cpu
 
 # Honor the MX_FORCE_CPU=1 pin at import, before anything initializes a
@@ -85,3 +90,6 @@ from . import tpu_kernel
 # Subsystems land milestone-by-milestone (SURVEY.md §7.1); this list grows
 # until it covers the reference's full `python/mxnet/__init__.py` surface.
 from . import test_utils
+
+_import_span.__exit__(None, None, None)
+del _import_span, _telemetry

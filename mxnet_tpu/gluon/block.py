@@ -36,12 +36,22 @@ from ..ndarray import ndarray as _nd_mod
 from ..ndarray.ndarray import NDArray
 from .. import autograd
 from .. import initializer as init_mod
+from .. import telemetry as _telemetry
 from ..ops import random as _ops_random
 from .parameter import (Parameter, Constant, ParameterDict,
                         DeferredInitializationError, _ParamOverrideScope,
                         _overrides)
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "trace_scope"]
+
+
+class _EagerCall(threading.local):
+    """Whether this thread is inside a top-level eager ``Block.__call__``:
+    that call alone is a ``forward`` phase, its children are not."""
+    active = False
+
+
+_eager_call = _EagerCall()
 
 
 def trace_scope(name: str):
@@ -148,7 +158,9 @@ class Block:
 
     def initialize(self, init=None, ctx=None, verbose: bool = False,
                    force_reinit: bool = False) -> None:
-        self.collect_params().initialize(init, ctx, verbose, force_reinit)
+        with _telemetry.phase("initialize"):
+            self.collect_params().initialize(init, ctx, verbose,
+                                             force_reinit)
 
     def zero_grad(self):
         self.collect_params().zero_grad()
@@ -157,10 +169,12 @@ class Block:
         self.collect_params().reset_ctx(ctx)
 
     def cast(self, dtype) -> None:
-        for child in self._children.values():
-            child.cast(dtype)
-        for param in self._reg_params.values():
-            param.cast(dtype)
+        # one phase for the tree: a nested same-name phase counts once
+        with _telemetry.phase("initialize"):
+            for child in self._children.values():
+                child.cast(dtype)
+            for param in self._reg_params.values():
+                param.cast(dtype)
 
     def apply(self, fn: Callable) -> "Block":
         for child in self._children.values():
@@ -249,7 +263,17 @@ class Block:
             object.__setattr__(self, "_last_input_avals",
                                [(a.shape, str(a.dtype)) for a in args])
         if _overrides() is None:
-            out = self._call_impl(*args, **kwargs)
+            if _eager_call.active:
+                out = self._call_impl(*args, **kwargs)
+            else:
+                # the top-level eager call (a hybridized block's cached
+                # program too): one more thread-local read a child
+                _eager_call.active = True
+                try:
+                    with _telemetry.phase("forward"):
+                        out = self._call_impl(*args, **kwargs)
+                finally:
+                    _eager_call.active = False
         else:
             # inside a whole-program trace (compiled step, hybridize
             # cache) the block's structural name - the attribute its
@@ -357,10 +381,11 @@ class Block:
     def _deferred_init_from(self, args) -> None:
         """Finish deferred param init using input shapes (reference:
         HybridBlock._deferred_infer_shape → Parameter._finish_deferred_init)."""
-        self.infer_shape(*args)
-        for param in self._reg_params.values():
-            if param._deferred_init is not None:
-                param._finish_deferred_init()
+        with _telemetry.phase("initialize"):
+            self.infer_shape(*args)
+            for param in self._reg_params.values():
+                if param._deferred_init is not None:
+                    param._finish_deferred_init()
 
     def infer_shape(self, *args) -> None:
         """Leaf layers with deferred-shape params override this."""

@@ -24,6 +24,7 @@ import numpy as _np
 from ..base import MXNetError
 from ..ndarray.ndarray import NDArray, invoke
 from .. import ndarray as nd
+from .. import telemetry as _telemetry
 from ..lr_scheduler import LRScheduler
 
 __all__ = ["Optimizer", "Updater", "get_updater", "register", "create"]
@@ -925,8 +926,9 @@ class Updater:
                 (ctx.canonical_type, ctx.device_id))
         for i, w in zip(index, weight):
             if i not in self.states:
-                self.states[i] = \
-                    self.optimizer.create_state_multi_precision(i, w)
+                with _telemetry.phase("initialize"):
+                    self.states[i] = \
+                        self.optimizer.create_state_multi_precision(i, w)
                 self.states_synced[i] = True
         todo = list(zip(index, grad, weight))
         if self.aggregate_updates and len(todo) > 1:

@@ -69,6 +69,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 
 from .base import get_env, recompute_counting, recompute_tally
+from . import compile_cache as _cc
 from . import telemetry as _telemetry
 
 __all__ = [
@@ -738,9 +739,22 @@ class Program:
             return len(self._cache)
 
     def _compile(self, sig, args, kwargs):
+        """Lower, then compile or load, each under the census name: the
+        spans ``compile.lower`` (jax's own trace and lowering spans lie
+        inside it) and ``compile.cache_load`` / ``compile.backend``
+        around what ``.compile()`` does besides jax's backend span."""
+        tags = {"fun_name": module_name(self._name), "program": self._name}
         t0 = time.perf_counter()
         try:
-            compiled = self._jit.lower(*args, **kwargs).compile()
+            lowered = self._jit.lower(*args, **kwargs)
+            t_lowered, wall = time.perf_counter(), time.time()
+            _telemetry.record_span("compile.lower", t0, t_lowered,
+                                   cat="compile", **tags)
+            compiled = lowered.compile()
+            _telemetry.record_span(
+                "compile.cache_load" if _cc.hit_between(wall, time.time())
+                else "compile.backend", t_lowered, time.perf_counter(),
+                cat="compile", **tags)
         except Exception as e:
             # this site cannot AOT-lower (e.g. layout/sharding the
             # lowering path rejects): census degrades to light mode.
@@ -831,7 +845,6 @@ def register_program(name: str, fn: Callable, mode: str = "aot",
     rebuilds of an already-seen signature.  With
     ``MX_PROGRAM_CENSUS=0`` this is exactly ``jax.jit``.
     """
-    from . import compile_cache as _cc
     _cc.activate()              # idempotent; arms jax's persistent cache
     if not census_enabled():
         return jax.jit(fn, **jit_kw)

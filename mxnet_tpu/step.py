@@ -39,6 +39,7 @@ trajectory.
 """
 from __future__ import annotations
 
+import logging
 import warnings
 from typing import Dict, List, Optional
 
@@ -62,6 +63,8 @@ from .gluon.parameter import (DeferredInitializationError,
 
 __all__ = ["CompiledStep", "scan_window", "step_compile_enabled",
            "metric_trace_kernel"]
+
+logger = logging.getLogger("mxnet_tpu.step")
 
 
 def step_compile_enabled() -> bool:
@@ -168,6 +171,7 @@ class CompiledStep:
         # Python per dispatch on the host hot path
         self._plan_cached = None
         self._plan_sig = None
+        self._announced = False     # the first dispatch's INFO line
 
     # -- cache control (hybridize semantics) -------------------------------
     @property
@@ -331,15 +335,17 @@ class CompiledStep:
         # optimizer slot state, created through the SAME updater store the
         # eager path uses (and every save_states/checkpoint reads)
         mp_flags = []
-        for d, upd in enumerate(tr._updaters):
-            for pos, i in enumerate(trainable_idx):
-                w = trainable[pos].data(ctxs[d])
-                if i not in upd.states:
-                    upd.states[i] = \
-                        upd.optimizer.create_state_multi_precision(i, w)
-                    upd.states_synced[i] = True
-                if d == 0:
-                    mp_flags.append(bool(opt._is_mp_state(w, upd.states[i])))
+        with _telemetry.phase("initialize"):
+            for d, upd in enumerate(tr._updaters):
+                for pos, i in enumerate(trainable_idx):
+                    w = trainable[pos].data(ctxs[d])
+                    if i not in upd.states:
+                        upd.states[i] = \
+                            upd.optimizer.create_state_multi_precision(i, w)
+                        upd.states_synced[i] = True
+                    if d == 0:
+                        mp_flags.append(
+                            bool(opt._is_mp_state(w, upd.states[i])))
         groups: Dict[bool, List[int]] = {}
         for pos, mp in enumerate(mp_flags):
             groups.setdefault(mp, []).append(pos)
@@ -703,14 +709,18 @@ class CompiledStep:
             holders.extend(inner)
             if w32 is not None:
                 holders.append(w32)
-        for nd_ in holders:
-            if nd_._index is not None or nd_._vshape is not None \
-                    or id(nd_._jax) in self._owned:
-                continue            # a view: `donatable` copies its value
-            mine = jnp.array(nd_._jax, copy=True)
-            nd_._set_jax(mine)
-            self._owned_refs.append(mine)
-            self._owned.add(id(mine))
+        # a view is left out: `donatable` copies its value
+        foreign = [nd_ for nd_ in holders
+                   if nd_._index is None and nd_._vshape is None
+                   and id(nd_._jax) not in self._owned]
+        if not foreign:
+            return
+        with _telemetry.phase("initialize"):
+            for nd_ in foreign:
+                mine = jnp.array(nd_._jax, copy=True)
+                nd_._set_jax(mine)
+                self._owned_refs.append(mine)
+                self._owned.add(id(mine))
 
     def _gather_state(self, plan):
         tr = self._trainer
@@ -881,6 +891,14 @@ class CompiledStep:
             else "compiled_window"
         with _telemetry.phase(span_name, annotation="step.dispatch"):
             out = fn(*state, lr_rows, decay_rows, rng, xs, ys)
+        if not self._announced:
+            # why the job took this long to its first step, and whether
+            # it compiled or loaded (docs/ARCHITECTURE.md, start-up
+            # timeline)
+            self._announced = True
+            if logger.isEnabledFor(logging.INFO):
+                logger.info("first compiled step dispatched %s",
+                            _telemetry.startup_line())
         with _telemetry.phase("step.write_back"):
             (new_t, new_f, new_states, new_w32, new_res, new_mstate,
              losses, outs) = out

@@ -25,7 +25,8 @@ scale — arxiv 1605.08695, PAPERS.md):
   ``exchange`` / ``optimizer_apply`` / ``metric_update`` /
   ``metric_drain`` / ``retrace`` / ``compiled_step`` /
   ``compiled_window``, the compiled step's host parts
-  ``step.prepare`` / ``step.write_back``, plus the serving engine's
+  ``step.prepare`` / ``step.write_back``, the start-up phases
+  ``import`` / ``initialize``, plus the serving engine's
   request phases
   ``queue_wait`` / ``pad`` / ``serve_dispatch`` / ``scatter`` —
   ISSUE 9 — and the decode engine's ``prefill`` / ``decode_step`` /
@@ -51,6 +52,18 @@ scale — arxiv 1605.08695, PAPERS.md):
   (``MX_TELEMETRY_TRACE`` directory); ``tools/telemetry_dump.py``
   merges the per-worker files into a single timeline.
 
+* **Start-up timeline** — the first ``_STARTUP_SPANS`` spans of a
+  process are held in that buffer whether or not tracing is on (category
+  ``startup``; ``MX_TELEMETRY=0`` holds none), together with what every
+  jit of the process paid by name: ``compile.trace`` / ``compile.lower`` /
+  ``compile.cache_load`` / ``compile.backend`` spans that
+  ``compile_cache`` records from jax's monitoring events through
+  :func:`record_span` (they arrive at their end, so they are no ``mx.*``
+  annotations).  :func:`startup_breakdown` reduces it to seconds by kind
+  (the innermost span owns each moment; the kinds sum to the window),
+  by program and by unspanned gap; :func:`startup_line` is the one line a
+  ``CompiledStep`` logs when its first dispatch returns.
+
 * **Flight recorder** — a ring of the last ``MX_TELEMETRY_RING``
   structured step records (phase durations, dispatch/wire deltas,
   retry and NaN-guard hits, throughput), appended by
@@ -71,6 +84,7 @@ afford it on every request.
 from __future__ import annotations
 
 import bisect
+import heapq
 import json
 import os
 import threading
@@ -90,6 +104,8 @@ __all__ = [
     "heartbeat_payload", "HEARTBEAT_SCHEMA", "parse_heartbeat",
     "phase_snapshot",
     "dump_trace", "trace_events", "clear_trace", "dump_crash",
+    "record_span", "holding_spans", "wall_to_perf", "process_start",
+    "startup_breakdown", "startup_line", "STARTUP_KINDS",
     "register_step_observer", "register_crash_section",
 ]
 
@@ -393,6 +409,52 @@ _trace_forced = [0]          # start_tracing() holds (tests; under _trace_lock)
 _TRACE_CAP = 200_000         # drop-newest bound; a leaked trace must not OOM
 _atexit_armed = [False]
 
+# The start-up timeline: the first spans of a process are held in the
+# buffer whether or not tracing asks for more, so that the process can say
+# where the time before its first step went (startup_breakdown).  The
+# largest set-up measured (a decoder cell: an eager pass, a reference, a
+# compiled step) makes ~3,000 spans; 8,192 events are ~3 MB.  Held while
+# tracing is off they carry the category below, and trace_events() -
+# "what tracing buffered" - leaves them out; dump_trace() writes both.
+_STARTUP_SPANS = 8192
+_STARTUP_CAT = "startup"
+_startup_left = [_STARTUP_SPANS]    # under _trace_lock; read without it
+_startup_dropped = [0]              # spans that came after the cap
+registry.read_counter(
+    "telemetry.startup_spans_dropped", lambda: _startup_dropped[0],
+    doc="spans that ended after the start-up timeline's first %d and "
+        "were not held (tracing off)" % _STARTUP_SPANS)
+
+# jax stamps its compile events with time.time(); spans run on
+# perf_counter: one offset, taken once
+_WALL_TO_PERF = time.perf_counter() - time.time()
+
+
+def wall_to_perf(wall: float) -> float:
+    """A ``time.time()`` stamp on this process's ``perf_counter`` axis."""
+    return wall + _WALL_TO_PERF
+
+
+_t_process: List[float] = []       # process_start()'s answer, once asked
+
+
+def process_start() -> float:
+    """``perf_counter`` seconds at which this process started: from /proc
+    (the kernel's start time against its uptime, 10 ms steps), else the
+    first call of this function.  Read when first asked, not at import."""
+    if not _t_process:
+        now = time.perf_counter()
+        try:
+            with open("/proc/self/stat") as f:
+                ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+            with open("/proc/uptime") as f:
+                age = float(f.read().split()[0]) \
+                    - ticks / os.sysconf("SC_CLK_TCK")
+        except (OSError, ValueError, IndexError):
+            age = 0.0
+        _t_process.append(now - age if 0.0 <= age < 86400.0 else now)
+    return _t_process[0]
+
 
 def tracing_enabled() -> bool:
     """Span buffering is on: ``start_tracing()`` held, or
@@ -400,6 +462,19 @@ def tracing_enabled() -> bool:
     # one list read: no lock needed to see start_tracing()'s hold
     return bool(_trace_forced[0]) or \
         _env_flag("MX_TELEMETRY_TRACE", _trace_dir_cache, _read_trace_dir)
+
+
+def holding_spans() -> bool:
+    """A span that ends now is kept: tracing is on, or telemetry is and
+    the process is within its first ``_STARTUP_SPANS`` spans."""
+    if tracing_enabled():
+        return True
+    if not enabled():
+        return False
+    if _startup_left[0] > 0:
+        return True
+    _startup_dropped[0] += 1    # a tally, not a ledger: no lock
+    return False
 
 
 def start_tracing() -> None:
@@ -415,9 +490,10 @@ def stop_tracing() -> None:
 
 
 def trace_events() -> List[dict]:
-    """Snapshot of the buffered chrome-trace events."""
+    """Snapshot of the chrome-trace events tracing buffered (the
+    start-up timeline's own are :func:`startup_breakdown`'s)."""
     with _trace_lock:
-        return list(_trace_events)
+        return [e for e in _trace_events if e["cat"] != _STARTUP_CAT]
 
 
 def clear_trace() -> None:
@@ -426,8 +502,18 @@ def clear_trace() -> None:
 
 
 def _buffer_event(ev: dict) -> None:
+    """Keep one event a caller found :func:`holding_spans` for: as it is
+    under tracing, as a start-up span otherwise."""
     arm = False
+    traced = tracing_enabled()
     with _trace_lock:
+        startup = _startup_left[0] > 0
+        if startup:
+            _startup_left[0] -= 1
+        if not traced:
+            if not startup:
+                return          # lost a race for the last place
+            ev["cat"] = _STARTUP_CAT
         if len(_trace_events) < _TRACE_CAP:
             _trace_events.append(ev)
         if not _atexit_armed[0]:
@@ -435,6 +521,19 @@ def _buffer_event(ev: dict) -> None:
     if arm:
         import atexit
         atexit.register(_flush_trace_atexit)
+
+
+def record_span(name: str, t0: float, t1: float, cat: str = "span",
+                **args) -> None:
+    """Keep a span that was measured elsewhere and has already ended
+    (``perf_counter`` seconds): jax's compile events arrive so.  No
+    histogram, no ``mx.*`` annotation - those need the span open."""
+    if not holding_spans():
+        return
+    _buffer_event({"name": name, "cat": cat, "ph": "X",
+                   "ts": (t0 - _WALL_TO_PERF) * 1e6,
+                   "dur": (t1 - t0) * 1e6, "pid": os.getpid(),
+                   "tid": threading.get_ident(), "args": args})
 
 
 def _flush_trace_atexit() -> None:
@@ -534,7 +633,7 @@ class Span:
                          span_id=self.span_id)})
 
     def _close(self, dur: float) -> None:
-        if tracing_enabled():
+        if holding_spans():
             _buffer_event({
                 "name": self.name, "cat": self.cat, "ph": "X",
                 "ts": self._wall0 * 1e6, "dur": dur * 1e6,
@@ -696,6 +795,128 @@ def phase_snapshot() -> Dict[str, Dict[str, float]]:
             "max_ms": round(snap["max"] * 1e3, 4),
         }
     return out
+
+
+# ---------------------------------------------------------------------------
+# The start-up timeline
+# ---------------------------------------------------------------------------
+
+#: what :func:`startup_breakdown` splits a stretch of the process's life
+#: into; every second lands in exactly one
+STARTUP_KINDS = ("import", "initialize", "eager_forward", "trace", "lower",
+                 "cache_load", "cold_compile", "step_host", "unspanned")
+
+_COMPILE_KINDS = {"compile.trace": "trace", "compile.lower": "lower",
+                  "compile.cache_load": "cache_load",
+                  "compile.backend": "cold_compile"}
+_PHASE_KINDS = {"phase.import": "import", "phase.initialize": "initialize",
+                "phase.forward": "eager_forward"}
+_GAP_SECONDS = 0.5          # an unspanned stretch worth a line of its own
+
+
+def _kind_of(name: str) -> Optional[str]:
+    """The kind a span's own time counts as; None for what is no part of
+    the timeline (RPC spans).  Every phase without a kind of its own is a
+    step's host part."""
+    kind = _COMPILE_KINDS.get(name) or _PHASE_KINDS.get(name)
+    if kind is None and name.startswith("phase."):
+        kind = "step_host"
+    return kind
+
+
+def startup_breakdown(t0: Optional[float] = None,
+                      t1: Optional[float] = None) -> Dict[str, Any]:
+    """Where the seconds of ``[t0, t1]`` went (``perf_counter`` seconds;
+    default: process start to now), from the spans the buffer holds.
+
+    Spans are clipped to the window and the INNERMOST span at each moment
+    (the one that started last) owns it, so each second is counted once:
+    a compile inside an eager ``forward`` is ``trace`` / ``lower`` /
+    ``cache_load`` / ``cold_compile``, not ``eager_forward``.  Returns
+    seconds by kind (:data:`STARTUP_KINDS`; ``unspanned`` is the
+    remainder, so the kinds sum to ``t1 - t0``), ``by_program`` (the four
+    compile kinds and their ``total`` by jax's ``fun_name``, largest
+    first), ``gaps`` (every unspanned stretch over 0.5 s: ``start`` and
+    ``end`` as offsets from ``t0``, the spans ``before`` and ``after``
+    it) and ``dropped`` (spans that came after the buffer's first
+    ``_STARTUP_SPANS``: time after them reads ``unspanned``)."""
+    t0 = process_start() if t0 is None else float(t0)
+    t1 = time.perf_counter() if t1 is None else float(t1)
+    with _trace_lock:
+        events = list(_trace_events)
+    spans = []
+    for ev in events:
+        kind = _kind_of(ev["name"]) if ev["ph"] == "X" else None
+        if kind is None:
+            continue
+        start = ev["ts"] / 1e6 + _WALL_TO_PERF
+        end = min(start + ev["dur"] / 1e6, t1)
+        start = max(start, t0)
+        if end > start:
+            name = ev["name"]
+            fun = ev["args"].get("fun_name")
+            label = name[len("phase."):] if name.startswith("phase.") \
+                else name
+            spans.append((start, end, kind, fun,
+                          "%s %s" % (label, fun) if fun else label))
+    spans.sort(key=lambda sp: sp[0])
+    bounds = sorted({t0, t1}.union(*((sp[0], sp[1]) for sp in spans)))
+    seconds = dict.fromkeys(STARTUP_KINDS, 0.0)
+    programs: Dict[str, Dict[str, float]] = {}
+    gaps: List[Dict[str, Any]] = []
+    live: List[Tuple[float, float, int]] = []   # (-start, end, index)
+    nxt, gap_at, last = 0, None, None
+    for a, b in zip(bounds, bounds[1:]):
+        while nxt < len(spans) and spans[nxt][0] <= a:
+            heapq.heappush(live, (-spans[nxt][0], spans[nxt][1], nxt))
+            nxt += 1
+        while live and live[0][1] <= a:
+            heapq.heappop(live)
+        if not live:
+            if gap_at is None:
+                gap_at = a
+            continue
+        _, _, kind, fun, label = spans[live[0][2]]
+        if gap_at is not None:
+            if a - gap_at > _GAP_SECONDS:
+                gaps.append({"start": gap_at - t0, "end": a - t0,
+                             "before": last, "after": label})
+            gap_at = None
+        last = label
+        seconds[kind] += b - a
+        if fun:                 # a compile span: they alone carry one
+            row = programs.setdefault(fun, {})
+            row[kind] = row.get(kind, 0.0) + (b - a)
+    if gap_at is not None and t1 - gap_at > _GAP_SECONDS:
+        gaps.append({"start": gap_at - t0, "end": t1 - t0,
+                     "before": last, "after": None})
+    seconds["unspanned"] = max(0.0, (t1 - t0) - sum(seconds.values()))
+    rows = {}
+    for fun, row in programs.items():
+        rows[fun] = {k: row[k] for k in STARTUP_KINDS if k in row}
+        rows[fun]["total"] = sum(row.values())
+    out: Dict[str, Any] = dict(seconds)
+    out["by_program"] = dict(sorted(rows.items(),
+                                    key=lambda kv: -kv[1]["total"]))
+    out["gaps"] = gaps
+    out["dropped"] = _startup_dropped[0]
+    return out
+
+
+def startup_line(top: int = 3) -> str:
+    """One line for an operator: seconds since the process started, by
+    kind, and the `top` costliest programs with what each paid for
+    (``cache_load``: found in jax's persistent cache; ``cold_compile``:
+    XLA compiled it)."""
+    b = startup_breakdown()
+    total = sum(b[k] for k in STARTUP_KINDS)
+    kinds = ", ".join("%s %.1f" % (k, b[k]) for k in STARTUP_KINDS)
+    programs = "; ".join(
+        "%s %.1f s (%s)" % (name, row["total"], ", ".join(
+            "%s %.1f" % (k, v) for k, v in row.items() if k != "total"))
+        for name, row in list(b["by_program"].items())[:top])
+    return "%.1f s since process start: %s; costliest programs: %s" \
+        % (total, kinds, programs or "none")
 
 
 # ---------------------------------------------------------------------------
