@@ -30,7 +30,11 @@ __all__ = ["RMSNorm", "RotaryEmbedding", "SwiGLU", "SquaredReLUMLP",
 
 class RMSNorm(HybridBlock):
     """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, the mean
-    in float32 (the ``RMSNorm`` operator)."""
+    in float32 (the ``RMSNorm`` operator).  The result has `x`'s dtype
+    whatever dtype the gain is kept in: a float32 `gamma` beside bfloat16
+    data is applied in float32 and the product rounded once, so a
+    parameter left float32 under `cast` does not promote the net's
+    activations."""
 
     def __init__(self, in_channels, epsilon=1e-6, gamma_initializer="ones",
                  **kwargs):
@@ -211,8 +215,10 @@ class Mamba2Mixer(HybridBlock):
     ``log U(1, 16)``, dt_bias the inverse softplus of a log-uniform step
     in ``[dt_min, dt_max]`` (floored), D ones, the convolution ``U(+-
     K^-1/2)``: the published initialisation, whatever initializer the net
-    is given; the first three and the norm's gain stay float32 under
-    `cast`.
+    is given.  Under `cast` the first three and the norm's gain stay
+    float32 (the scan reads them in float32; the gain is applied in
+    float32); the mixer returns its input's dtype all the same - the scan
+    and the norm each return their data's.
     Scopes: the convolution ``conv``, the scan with its skip and gate
     ``scan``."""
 
@@ -248,7 +254,8 @@ class Mamba2Mixer(HybridBlock):
                               in_units=self._inner, dtype=dtype)
 
     def cast(self, dtype):
-        """dt_bias, A_log, D and the norm's gain stay float32."""
+        """dt_bias, A_log, D and the norm's gain stay float32; the mixer
+        returns its input's dtype."""
         self.in_proj.cast(dtype)
         self.out_proj.cast(dtype)
         self.conv_weight.cast(dtype)

@@ -364,9 +364,17 @@ def _instance_norm(data, gamma, beta, eps=1e-3):
 
 @register("RMSNorm")
 def _rms_norm(data, gamma, axis=-1, eps=1e-6):
+    """``data / sqrt(mean(data^2) + eps) * gamma``, the mean in float32.
+    The result has `data`'s dtype whatever dtype `gamma` is kept in."""
     x32 = data.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x32), axis=axis, keepdims=True)
-    return (x32 * lax.rsqrt(ms + eps)).astype(data.dtype) * gamma
+    norm = x32 * lax.rsqrt(ms + eps)
+    if gamma.dtype == data.dtype:
+        return norm.astype(data.dtype) * gamma
+    # a gain kept in another dtype (Mamba2Mixer's stays float32 in a cast
+    # net) is applied in float32, before the one rounding, and promotes
+    # nothing downstream
+    return (norm * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------

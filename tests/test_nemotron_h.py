@@ -83,10 +83,16 @@ def _kind(name):
 # operands carry 8 bits, through 7 layers; the reference follows the net's
 # router choices and holds them to a gap.  float8_e4m3 operands, a scan
 # state kept in bfloat16 and a skipped term must each fail at least one of
-# the bfloat16 limits (asserted below).  Read on seeds 11, 14, 17, 21:
-# bf16 logits 0.8-1.1e-2, gradients 1.4-2.1e-2 by kind - but for A_log,
-# four numbers a layer here, each a sum of differences that nearly cancel
-# (rows of the decay matrix against its columns): 7.5e-2 on seed 14.
+# the bfloat16 limits (asserted below).  Read on seeds 11, 14, 17, 21
+# since PR 38, when the cast net's residual stream became bfloat16 (until
+# then the mixer's float32 gain had made every layer after the first
+# float32, on the host the scans' operands too): bf16 logits 0.9-1.2e-2
+# (were 0.7-1.0e-2), loss under 1.5e-4, gap 0.3-1.6e-3 (0.2-0.8e-3),
+# gradients 1.8-3.9e-2 by kind (1.1-1.7e-2), worst D and dt_bias - but
+# for A_log, four numbers a layer here, each a sum of differences that
+# nearly cancel (rows of the decay matrix against its columns): 0.6-2.6e-2
+# on seeds 11, 14, 21 and 2.1e-1 on seed 17 (5.5e-2 before), a seed the
+# cases below do not run.
 TOLERANCE = {"float32": {"logits": 2e-5, "loss": 1e-5, "grads": 2e-4,
                          "gap": 1e-5},
              "bfloat16": {"logits": 3e-2, "loss": 3e-3, "grads": 1e-1,

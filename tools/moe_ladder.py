@@ -128,7 +128,9 @@ def main(argv=None):
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--tokens-dtype", default=None,
                     help="of what the experts read, where it is not the "
-                         "weights' (the Nemotron cell's latent is float32)")
+                         "weights' (default: the weights' - bfloat16 in "
+                         "all three cells; the Nemotron cell's latent was "
+                         "float32 until PR 38)")
     ap.add_argument("--route", type=int, default=0,
                     help="1: the layer with its router, rungs `gathered` "
                          "and `as it is`")
