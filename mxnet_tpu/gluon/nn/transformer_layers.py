@@ -314,7 +314,7 @@ class TokenChoiceMoE(HybridBlock):
     of the experts' kind and of width `shared_hidden_size` (default
     ``num_shared * hidden_size``: `num_shared` experts fused).  The held
     experts' products are grouped (assignments sorted by expert, one
-    ragged product a projection) over a buffer sized to the load the
+    grouped product a projection) over a buffer sized to the load the
     device counts: twice an even router's share where the held experts'
     assignments fit that, else ``tokens * min(top_k, held)`` rows - the
     most that can land here, so no token is dropped whatever the

@@ -8,7 +8,9 @@ DCN across slices.
 
 Two mixture-of-experts routers live in ``moe.py``: ``token_choice_moe``
 (with ``topk_choice``, ``topk_route`` and ``held_expert_ffn``: top-k over all
-experts, no dropped token, computed for the experts one chip HOLDS) is the
+experts, no dropped token, computed for the experts one chip HOLDS - one
+grouped product a projection, ``ops/grouped.py``'s Pallas kernels on a TPU
+and ``lax.ragged_dot`` elsewhere) is the
 one a Gluon model reaches, through ``gluon.nn.TokenChoiceMoE``;
 ``moe_parallel`` / ``moe_apply`` / ``top1_dispatch`` (top-1 with capacity
 and two ``all_to_all``s over an ``ep`` axis) is called directly and by no

@@ -8,8 +8,10 @@ blocks from that, ``model_zoo.nemotron_h`` its latent expert layers.  The
 layer is told which experts it HOLDS, scores every token over ALL experts
 (sigmoid scores, selection by score + bias, weights renormalised over the
 chosen k and scaled), and computes the part of the result its own experts
-give: assignments sorted by expert, one grouped (ragged) product a
-projection, no capacity and no dropped token whatever the imbalance.  An
+give: assignments sorted by expert, one grouped product a projection
+(``ops/grouped.py``: Pallas kernels that stop at the load on a TPU,
+``lax.ragged_dot`` elsewhere), no capacity and no dropped token whatever
+the imbalance.  An
 expert is what its weights' shapes say - a gated SwiGLU or an ungated
 squared-ReLU MLP - of whatever width its input has (the model width, or
 a latent the layer projects to), which need not be the width the router
@@ -40,6 +42,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..base import recompute_keep
+from ..ops import grouped
 
 __all__ = ["moe_apply", "moe_parallel", "top1_dispatch", "topk_choice",
            "topk_route", "held_expert_ffn", "token_choice_moe"]
@@ -292,22 +295,60 @@ def _activate(up, activation):
     return jnp.square(jax.nn.relu(up))
 
 
-def _exact_path(k, activation, x, flat_w, w_in, w_down, order, position,
-                places, counts):
-    """The held experts' result over the exact no-drop buffer: the first
-    ``N * min(k, H)`` sorted rows."""
+def _exact_stages(k, activation, order, position, places, counts):
+    """The exact buffer's path - the first ``N * min(k, H)`` sorted rows -
+    as its three stages, each a function of what it differentiates:
+    dispatch (x, flat_w) -> (xs, ws), experts (xs, w_in, w_down) -> out,
+    combine (out, ws) -> y."""
     here = counts.sum()
-    with jax.named_scope("dispatch"):
-        taken = order[:places.size]
-        xs = _take_rows(x, taken // k, places, here)
-        ws = _take_rows(flat_w, taken, position[:, None], here)
-    with jax.named_scope("experts"):
-        out = lax.ragged_dot(
-            _activate(lax.ragged_dot(xs, w_in, counts), activation),
+    taken = order[:places.size]
+
+    def dispatch(x, flat_w):
+        return (_take_rows(x, taken // k, places, here),
+                _take_rows(flat_w, taken, position[:, None], here))
+
+    def experts(xs, w_in, w_down):
+        return grouped.grouped_product(
+            _activate(grouped.grouped_product(xs, w_in, counts), activation),
             w_down, counts)
+
+    def combine(out, ws):
+        return _sum_rows((out.astype(jnp.float32) * ws).astype(out.dtype),
+                         places, taken // k, here)
+
+    return dispatch, experts, combine
+
+
+def _exact_path(k, activation, x, flat_w, w_in, w_down, *indices):
+    """The held experts' result over the exact no-drop buffer."""
+    dispatch, experts, combine = _exact_stages(k, activation, *indices)
+    with jax.named_scope("dispatch"):
+        xs, ws = dispatch(x, flat_w)
+    with jax.named_scope("experts"):
+        out = experts(xs, w_in, w_down)
     with jax.named_scope("combine"):
-        out = (out.astype(jnp.float32) * ws).astype(x.dtype)
-        return _sum_rows(out, places, taken // k, here)
+        return combine(out, ws)
+
+
+def _exact_backward(k, activation, x, flat_w, w_in, w_down, indices, g):
+    """The cotangents of x, flat_w, w_in, w_down over the exact buffer,
+    its forward run again.  Stage by stage, each stage's derivative taken
+    INSIDE its scope: a ``jax.vjp`` of the whole path would name what it
+    runs ``transpose(jvp(experts))``, which is no scope a reader of the
+    `op_name` paths finds (`moe/experts` would lose the branch's
+    kernels)."""
+    dispatch, experts, combine = _exact_stages(k, activation, *indices)
+    with jax.named_scope("dispatch"):
+        (xs, ws), d_dispatch = jax.vjp(dispatch, x, flat_w)
+    with jax.named_scope("experts"):
+        out, d_experts = jax.vjp(experts, xs, w_in, w_down)
+    with jax.named_scope("combine"):
+        d_out, d_ws = jax.vjp(combine, out, ws)[1](g)
+    with jax.named_scope("experts"):
+        d_xs, d_w_in, d_w_down = d_experts(d_out)
+    with jax.named_scope("dispatch"):
+        d_x, d_flat_w = d_dispatch((d_xs, d_ws))
+    return d_x, d_flat_w, d_w_in, d_w_down
 
 
 class _Short:
@@ -318,7 +359,14 @@ class _Short:
     min(k, H) places a token, now out of a `short`-row buffer: on the v5e
     that costs a sixth of the same gather out of the exact buffer, and a
     one-hot product ``(N, short) @ (short, d)`` in its place was no
-    faster at either cell's shape (PERF.md section 6, PR 34)."""
+    faster at either cell's shape (PERF.md section 6, PR 34).  The
+    products are one grouped product a projection
+    (`grouped.grouped_product`), forward and in both cotangents: its
+    kernels visit the row tiles the load reaches and write zeros past it,
+    whatever the rows there hold - `rows_of` makes them zeros anyway, as
+    ``lax.ragged_dot``, which multiplies the whole buffer where the
+    kernels' rule does not hold (off a TPU; a width that is no multiple
+    of 128 lanes), needs them."""
 
     def __init__(self, k, short, activation, order, position, places,
                  counts):
@@ -351,7 +399,7 @@ class _Short:
                                 unique_indices=True)
 
     def product_of(self, rows, w):
-        return lax.ragged_dot(rows, w, self.counts)
+        return grouped.grouped_product(rows, w, self.counts)
 
     def forward(self, x, flat_w, w_in, w_down):
         """(y, what the backward reads again: `short` rows each)."""
@@ -427,9 +475,8 @@ def _sized_bwd(k, short, activation, res, g):
             .backward(kept, w_in, w_down, g)
 
     def exact_path(g):
-        return jax.vjp(
-            lambda *a: _exact_path(k, activation, *a, *indices),
-            x, flat_w, w_in, w_down)[1](g)
+        return _exact_backward(k, activation, x, flat_w, w_in, w_down,
+                               indices, g)
 
     # the barrier keeps what follows a gradient (an optimizer's cast to
     # float32) out of the branches: XLA would move it in and hand on
@@ -454,9 +501,15 @@ def held_expert_ffn(x, idx, weights, w_in, w_down, held: Sequence[int],
     ``"relu2"``, w_in (H, d, f), no gate, ``relu(x W1)^2 Wd``.
 
     Assignments (token, choice) are sorted by held expert - those routed
-    to experts held elsewhere sort last - and go through ONE ragged
-    product a projection (``lax.ragged_dot``, groups = held experts) over
-    a buffer of the first sorted rows.  ``N * min(k, H)`` rows are the
+    to experts held elsewhere sort last - and go through ONE grouped
+    product a projection (`ops.grouped.grouped_product`, groups = held
+    experts) over a buffer of the first sorted rows: on a TPU, for widths
+    that are multiples of 128 lanes, two Pallas kernels
+    (`grouped_product_rows`, forward and by the rows; `grouped_product_weights`,
+    by the weights) that visit only the row tiles the load reaches and
+    write zeros past it; anywhere else ``lax.ragged_dot``, which XLA:TPU
+    would expand into `ragged-dot-*` kernels of its own over the whole
+    buffer.  ``N * min(k, H)`` rows are the
     exact no-drop bound: a token's k choices are k DIFFERENT experts, so
     at most min(k, H) of them are held here, whatever the router does -
     every assignment that lands here has its row and no token is ever

@@ -172,13 +172,14 @@ def _scan(direction):
 def _held_experts(direction):
     """GLM-4.7-Flash's expert layer on one chip's share at the cell's
     sizes: 8192 tokens, top-4 of 64 experts, 8 held, width 1536
-    (parallel/moe.py: sorts, gathers and two ragged products, which
-    XLA:TPU expands into kernels of its own: `ragged-dot-metadata` and one
-    `ragged-dot-none` a product - forward 2; backward 2 by the rows + 2
-    by the weights, and the forward's 2 again in the exact buffer's
-    branch, which keeps nothing of its forward).  The program holds both
-    buffer sizes, 8192 rows and the exact 32768, each in its branch of a
-    `conditional`."""
+    (parallel/moe.py: sorts, gathers and two grouped products, which on
+    the chip are ops/grouped.py's Pallas kernels, `grouped_product_rows`
+    forward and by the rows, `grouped_product_weights` by the weights -
+    forward 2; backward 2 by the rows + 2 by the weights, and the
+    forward's 2 again in the exact buffer's branch, which keeps nothing of
+    its forward; no `ragged-dot-*` kernel of XLA's is left).  The program
+    holds both buffer sizes, 8192 rows and the exact 32768, each in its
+    branch of a `conditional`."""
     from mxnet_tpu.parallel import moe
 
     def fwd(x, router, correction, gate_up, down):
@@ -266,15 +267,18 @@ CASES = [
      lambda: _flash("bwd", (1, 1, 21248, 256), True), 2, False),
     ("long-1x1x21504x256-causal-falls-back-to-two",
      lambda: _flash("bwd", (1, 1, 21504, 256), True), 3, False),
-    # each buffer size has its branch: 3 kernels a forward branch; in the
-    # backward pass 5 in the short buffer's branch and 8 in the exact
-    # one's (its forward again), and in the forward's short branch the 3
-    # that make what the backward reads - the exact branch's forward makes
-    # nothing the gradients read and is dropped
+    # each buffer size has its branch: 2 grouped kernels a forward branch;
+    # in the backward pass 4 in the short buffer's branch and 6 in the
+    # exact one's (its forward again), and in the forward's short branch
+    # the 2 that make what the backward reads - the exact branch's forward
+    # makes nothing the gradients read and is dropped.  None of XLA's own
+    # `ragged-dot-*` kernels is left, forward or backward
     ("held-experts-8192x2048-top4-8of64",
-     lambda: _held_experts("fwd"), 6, False),
+     lambda: _held_experts("fwd"), 4, False),
     ("held-experts-8192x2048-top4-8of64-bwd",
-     lambda: _held_experts("bwd"), 17, False),
+     lambda: _held_experts("bwd"), 12, False),
+    ("held-experts-8192x2048-top4-8of64-bwd-holds-no-ragged-dot",
+     lambda: _held_experts("bwd"), ("ragged-dot", 0), False),
     # the router's top-k and the dispatch's order and its inverse are
     # sorts; a recomputed block keeps all three results and its second
     # run sorts nothing (5 sorts if order and inverse were not kept)
@@ -329,12 +333,15 @@ CASES = [
      lambda: _heads("fwd", (1, 512, 256), 4, causal=True, kv_heads=2),
      0, True),
     # its expert layer (top-22 of 512, 8 held: 5,632 rows or the exact
-    # 65,536, a branch each as above) and its scan compile; neither holds
-    # a kernel of the program's own
+    # 65,536, a branch each as above: the grouped kernels at 1024 -> 2688
+    # -> 1024 lanes) and its scan compile; the scan holds no kernel of the
+    # program's own
     ("latent-experts-8192x4096-top22-8of512",
-     lambda: _latent_experts("fwd"), 6, False),
+     lambda: _latent_experts("fwd"), 4, False),
     ("latent-experts-8192x4096-top22-8of512-bwd",
-     lambda: _latent_experts("bwd"), 17, False),
+     lambda: _latent_experts("bwd"), 12, False),
+    ("latent-experts-8192x4096-top22-8of512-bwd-holds-no-ragged-dot",
+     lambda: _latent_experts("bwd"), ("ragged-dot", 0), False),
     ("ssd-scan-1x8192x16x64-chunk128", lambda: _scan("fwd"), 0, False),
     ("ssd-scan-1x8192x16x64-chunk128-bwd", lambda: _scan("bwd"), 0, False),
     # a mask, or a T that is no block multiple, keeps the composition:
@@ -401,20 +408,26 @@ SIZED_EXPERTS = {
 
 
 @pytest.mark.parametrize("cell", sorted(SIZED_EXPERTS))
-def test_a_recomputed_expert_layer_holds_both_buffer_sizes(topo, chip, cell):
+def test_a_recomputed_expert_layer_holds_both_buffer_sizes(topo, chip,
+                                                           monkeypatch, cell):
     """The held-expert layer at a cell's shape, forward + backward under
-    `Block.recompute`'s policy, for a described v5e: three conditionals
+    `Block.recompute`'s policy, for a described v5e, with the grouped
+    kernels as on the chip (24 calls: 2 + 2 in the forward's branches, the
+    same in the second run's, 4 + 6 + ... in the backward's; no
+    `ragged-dot`): three conditionals
     (the forward, the recomputed forward, the backward pass - the choice
     is not differentiated through), and none hands on a value as long as
     the exact buffer: a derivative taken THROUGH the choice would fill the
     exact branch's residuals with zeros in the short one (PR 28's second
     branch: 2.2 GB of them at the GLM shape)."""
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
     build, argnums, rows, sorts, parent, most = SIZED_EXPERTS[cell]
     step, args = _recomputed(build(), argnums)
     abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
                 for shape, dtype in args]
     compiled = jax.jit(step).lower(*abstract).compile()
     text = compiled.as_text()
+    assert "ragged-dot" not in text and "grouped_product" in text
     assert text.count(" sort(") == sorts
     conditionals = [line.split(" conditional(")[0]
                     for line in text.splitlines() if " conditional(" in line]
@@ -423,8 +436,31 @@ def test_a_recomputed_expert_layer_holds_both_buffer_sizes(topo, chip, cell):
     assert not [c for c in conditionals
                 if any(int(width) > 1 for width in
                        re.findall(r"\[%d,(\d+)\]" % rows, c))]
+    # with the grouped kernels the Nemotron layer reads 1,162,763,264:
+    # XLA's ragged-dot expansion had temporaries of its own (PR 39)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert parent < temp <= most
+    assert parent * 0.99 < temp <= most
+
+
+def test_every_grouped_kernel_carries_the_experts_scope(topo, chip,
+                                                        monkeypatch):
+    """Forward and backward of the GLM cell's expert layer: the `op_name`
+    path of every grouped kernel - in the short buffer's branch and in
+    the exact one's, whose backward runs its forward again - holds
+    `experts` as a whole component, which is how
+    benchmark/harness/scope_time.py finds what `moe/experts` counts (a
+    ``jax.vjp`` of the whole exact path named them
+    ``transpose(jvp(experts))``)."""
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    fn, args = _held_experts("bwd")
+    abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                for shape, dtype in args]
+    text = jax.jit(fn).lower(*abstract).compile().as_text()
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(names) == 12
+    assert all("grouped_product" in name and "experts" in name.split("/")
+               for name in names), names
 
 
 def _rows_over_four_chips(topo):

@@ -496,20 +496,62 @@ def test_latent_squared_relu_experts_are_the_dense_loop():
 
 
 def _ragged_rows(fn, *args):
-    """Rows of every ragged product's left operand in `fn`'s program."""
+    """Rows of every grouped product's left operand in `fn`'s program,
+    whatever implements it: a ragged product, or a call of the grouped
+    kernels (ops/grouped.py: the operands after the scalar-prefetched
+    maps; rows x lanes is the first of them)."""
     rows = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name.startswith("ragged_dot"):
                 rows.append(eqn.invars[0].aval.shape[0])
+            elif eqn.primitive.name == "pallas_call" \
+                    and eqn.params["name"].startswith("grouped_product"):
+                rows.append(next(v.aval.shape[0] for v in eqn.invars
+                                 if v.aval.ndim == 2))
             for value in eqn.params.values():
-                inner = getattr(value, "jaxpr", None)
-                if inner is not None:
-                    walk(getattr(inner, "jaxpr", inner))
+                # a cond's branches come as a tuple
+                for sub in (value if isinstance(value, (tuple, list))
+                            else (value,)):
+                    inner = getattr(sub, "jaxpr", None)
+                    if inner is not None:
+                        walk(getattr(inner, "jaxpr", inner))
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
     return rows
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["ragged-dot", "grouped-kernels"])
+@pytest.mark.parametrize("top_k,held,buffers", [
+    (5, (3, 4), {1024, 2048}), (2, (3, 4, 5, 6), {512, 2048}),
+    (2, tuple(range(32)), {2048})])
+def test_every_product_runs_over_the_sized_buffers(monkeypatch, kernels,
+                                                   top_k, held, buffers):
+    """1024 tokens of 128 lanes choose of 32 experts: forward and
+    backward, every grouped product's rows are the short buffer's or the
+    exact bound's (a layer that holds all its experts has one size) -
+    with ``lax.ragged_dot`` as on the CPU, and with the grouped kernels
+    in its place as on the chip (their rule holds at this width)."""
+    from mxnet_tpu.ops import grouped
+    if kernels:
+        monkeypatch.setattr(grouped, "grouped_product", grouped._product)
+    n, d, f, h = SIZED_TOKENS, 128, 256, len(held)
+    assert grouped.product_rule(min(buffers), d, f, jnp.float32,
+                                jnp.float32)
+    rng = np.random.RandomState(5)
+    args = [jnp.asarray(rng.randn(*shape), jnp.float32) * 0.1 for shape in
+            ((n, d), (SIZED_EXPERTS, d), (h, d, f), (h, f, d))]
+
+    def loss(x, router, up, down):
+        return moe.token_choice_moe(
+            x, router, jnp.zeros((SIZED_EXPERTS,)), up, down, held=held,
+            top_k=top_k, activation="relu2")[0].sum()
+
+    rows = _ragged_rows(jax.grad(loss, argnums=(0, 2, 3)), *args)
+    # 2 products forward, 2 + 2 backward, in each size's branch
+    assert set(rows) == buffers and len(rows) >= 6 * len(buffers)
 
 
 @pytest.mark.parametrize("top_k,held", [(5, (1, 2, 3)), (2, (0, 1, 2, 3))])

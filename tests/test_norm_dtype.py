@@ -233,25 +233,74 @@ def _equations(jaxpr):
                     yield from _equations(inner)
 
 
+GROUPED_KERNELS = "grouped_product"      # ops/grouped.py's pallas_calls
+
+
+def _product_operands(eqn):
+    """(what the product is, its two operands' avals), or None for an
+    equation that is no matrix product.  A grouped product - a ragged
+    dot, or a call of the grouped kernels that take its place on a chip
+    (their operands follow the scalar-prefetched integer maps) - is
+    `grouped`: its rows are gathered tokens whatever their number."""
+    name = eqn.primitive.name
+    if name == "dot_general":
+        return "dense", [v.aval for v in eqn.invars[:2]]
+    if name in ("ragged_dot", "ragged_dot_general"):
+        return "grouped", [v.aval for v in eqn.invars[:2]]
+    if name == "pallas_call" \
+            and eqn.params["name"].startswith(GROUPED_KERNELS):
+        return "grouped", [v.aval for v in eqn.invars
+                           if jnp.issubdtype(v.aval.dtype, jnp.floating)][:2]
+    return None
+
+
 def _float32_activation_products(jaxpr):
     """(primitive, scope, operand shapes) of every matrix product under
     `jaxpr` that takes a float32 operand of the activations' shape - its
-    leading axes the rows and positions, or their product - outside the
-    router's scope (``moe/route`` scores in float32, as published)."""
+    leading axes the rows and positions, or their product; the rows of a
+    grouped product, however many the buffer has - outside the router's
+    scope (``moe/route`` scores in float32, as published)."""
     found = []
     for eqn in _equations(jaxpr):
         scope = str(eqn.source_info.name_stack)
-        if eqn.primitive.name not in ("dot_general", "ragged_dot",
-                                      "ragged_dot_general") \
-                or "route" in scope.split("/"):
+        product = _product_operands(eqn)
+        if product is None or "route" in scope.split("/"):
             continue
-        operands = [v.aval for v in eqn.invars[:2]]
+        kind, operands = product
         if any(a.dtype == jnp.float32 and (a.shape[:2] == (ROWS, SEQ)
                                            or a.shape[:1] == (ROWS * SEQ,))
-               for a in operands):
+               for a in operands) \
+                or (kind == "grouped" and any(
+                    a.dtype == jnp.float32 and a.ndim == 2
+                    for a in operands)):
             found.append((eqn.primitive.name, scope,
                           [tuple(a.shape) for a in operands]))
     return found
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["ragged-dot", "grouped-kernels"])
+def test_the_walk_sees_a_float32_row_of_a_grouped_product(monkeypatch,
+                                                          kernels):
+    """The expert layer's products, forward and backward, by
+    ``lax.ragged_dot`` and by the grouped kernels that stand in its place
+    on a chip: float32 rows are found in every one of them - the rows, the
+    cotangent the row kernel reads, both operands of the weight kernel -
+    and bf16 rows in none."""
+    from mxnet_tpu.ops import grouped
+    if kernels:
+        monkeypatch.setattr(grouped, "grouped_product", grouped._product)
+    counts = jnp.asarray([100, 0, 156], jnp.int32)
+
+    def products(dtype):
+        rows = jnp.zeros((grouped._ROW_TILE, 128), dtype)
+        w = jnp.zeros((3, 128, 256), dtype)
+        return _float32_activation_products(jax.make_jaxpr(jax.grad(
+            lambda r, w: grouped.grouped_product(r, w, counts)
+            .astype(jnp.float32).sum(), (0, 1)))(rows, w).jaxpr)
+
+    assert len(products(jnp.float32)) == 3
+    assert not products(jnp.bfloat16)
 
 
 def _program(net):

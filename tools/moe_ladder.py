@@ -17,7 +17,11 @@ the load is met.  Rungs, each at every load:
 
 - ``exact``: one path over the exact no-drop buffer, what the layer was
   before it had two sizes;
-- ``sized``: the layer as it is, the short buffer where the load fits it.
+- ``sized``: the layer as it is, the short buffer where the load fits it;
+- ``ragged`` (`--products 1`): the layer with ``lax.ragged_dot`` in the
+  place of the grouped kernels (ops/grouped.py), what it was before PR
+  39; and ``sized@<rows>,<MiB>`` for every `--tiles` entry: the kernels at
+  another row tile and another bound on their blocks' fast memory.
 
 `--route 1` times the layer WITH its router (`token_choice_moe`: the
 router's product over `ROUTER_WIDTH` lanes, the top-k, the chosen scores;
@@ -134,6 +138,12 @@ def main(argv=None):
     ap.add_argument("--route", type=int, default=0,
                     help="1: the layer with its router, rungs `gathered` "
                          "and `as it is`")
+    ap.add_argument("--products", type=int, default=0,
+                    help="1: rungs `ragged`, `sized` and one a `--tiles` "
+                         "entry in the place of `exact` and `sized`")
+    ap.add_argument("--tiles", nargs="*", default=[],
+                    help="rows,MiB: the grouped kernels' row tile and the "
+                         "fast memory their blocks may take")
     ap.add_argument("--recompute", type=int, default=1)
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--inner", type=int, default=10)
@@ -151,6 +161,7 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax import lax
     from mxnet_tpu.base import RECOMPUTE_KEEP
     from mxnet_tpu.parallel import moe
 
@@ -216,6 +227,14 @@ def main(argv=None):
         else:
             # a load is the choices themselves, drawn on the host
             rungs = {"exact": {"_SHORT_OVER_EVEN": 0}, "sized": {}}
+            if opts.products:
+                rungs = {"ragged": {"grouped.grouped_product":
+                                    lax.ragged_dot}, "sized": {}}
+                for tiles in opts.tiles:
+                    rows, mib = map(int, tiles.split(","))
+                    rungs["sized@" + tiles] = {
+                        "grouped._ROW_TILE": rows,
+                        "grouped._BLOCK_BYTES": mib << 20}
             fixed = (x, jnp.asarray(rng.rand(n, k) / k, jnp.float32), w_in,
                      w_down)
             drawn = {load: jnp.asarray(choices(
