@@ -59,7 +59,11 @@ class RotaryEmbedding(HybridBlock):
     pass.  `yarn` = ``(factor, original positions, beta_fast,
     beta_slow)`` blends the frequencies as YaRN does and
     `attention_factor` scales cos and sin; the defaults are the plain
-    rotary call."""
+    rotary call.  What runs follows from the shapes
+    (``ops/rotary.py:rotary_rule``): heads of whole 128-lane blocks on a
+    TPU turn where they lie, in one Pallas kernel forward and backward
+    that keeps nothing of its input; any other call is the operator's
+    composition."""
 
     def __init__(self, num_heads, rotary_dim=None, theta=10000.0,
                  first=False, yarn=None, attention_factor=1.0, **kwargs):

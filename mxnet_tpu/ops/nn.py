@@ -450,10 +450,20 @@ def _rotary_embedding(data, num_heads=1, rotary_dim=None, theta=10000.0,
     positions 0..T-1.  `yarn` = (factor, original positions, beta_fast,
     beta_slow) blends the frequencies (`yarn_inv_freq`);
     `attention_factor` multiplies cos and sin (YaRN's temperature, on q
-    and k alike)."""
+    and k alike).  The turn is float32 and rounded once to the data's
+    dtype.  Where `ops/rotary.py:rotary_rule` holds (heads of whole
+    128-lane blocks, `T` in its row blocks, bf16 or float32) a TPU runs
+    the same mathematics as one Pallas kernel on the packed layout,
+    forward and backward; anything else the composition below."""
+    from . import attention, rotary
     b, t, hd = data.shape
     d = hd // num_heads
     r = d if rotary_dim is None else rotary_dim
+    if rotary.rotary_rule(t, d, r, data.dtype) and attention._on_tpu():
+        rotary.count("kernel")
+        return rotary.turn(data, num_heads, r, theta, first, yarn,
+                           attention_factor)
+    rotary.count("composition")
     half = r // 2
     if yarn is None:
         inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / r))

@@ -126,6 +126,44 @@ def _heads(direction, shape, heads, mask=False, causal=False, kv_heads=None,
         [(shape, jnp.bfloat16)] + [(kv_shape, jnp.bfloat16)] * 2
 
 
+def _turned(direction, shape, heads, keywords, dtype=jnp.bfloat16):
+    """The ``rotary_embedding`` operator on a packed (B, T, H*D) tensor
+    (ops/rotary.py's kernel where its rule holds: one call forward, the
+    same kernel on the negative angle backward)."""
+    from mxnet_tpu.ops import nn as ops_nn
+
+    def fwd(x):
+        return ops_nn._rotary_embedding(x, num_heads=heads, **keywords)
+
+    def bwd(x):
+        # a loss whose cotangent reads the result: a constant one would
+        # leave a program that reads no argument
+        return jax.grad(lambda x: (fwd(x).astype(jnp.float32) ** 2).sum())(x)
+
+    return (fwd if direction == "fwd" else bwd), [(shape, dtype)]
+
+
+def _turned_heads(kind):
+    """One of Laguna-XS.2's attention layers past its projections at the
+    cell's shapes: q and k through the rotary operator, then the causal
+    core on 8 key/value heads (`kind` "sliding_attention": 64 query
+    heads, every lane turns, a band of 512 keys; "full_attention": 48,
+    the first 64 lanes with YaRN)."""
+    from mxnet_tpu.gluon.model_zoo import laguna
+    from mxnet_tpu.ops import nn as ops_nn
+    heads, window = (64, 512) if kind == "sliding_attention" else (48, None)
+    keywords = laguna.rotary_keywords(laguna.ROPE_XS_2[kind], 128)
+
+    def fwd(q, k, v):
+        q = ops_nn._rotary_embedding(q, num_heads=heads, **keywords)
+        k = ops_nn._rotary_embedding(k, num_heads=8, **keywords)
+        return att.attention_heads(q, k, v, heads, causal=True,
+                                   window=window)
+
+    return fwd, [((1, 8192, heads * 128), jnp.bfloat16)] \
+        + [((1, 8192, 1024), jnp.bfloat16)] * 2
+
+
 def _latent_experts(direction):
     """Nemotron-3-Super's expert layer on one chip's share at the cell's
     sizes: 8192 tokens of 4096 lanes routed top-22 over 512 experts, 8
@@ -344,6 +382,63 @@ CASES = [
      lambda: _latent_experts("bwd"), ("ragged-dot", 0), False),
     ("ssd-scan-1x8192x16x64-chunk128", lambda: _scan("fwd"), 0, False),
     ("ssd-scan-1x8192x16x64-chunk128-bwd", lambda: _scan("bwd"), 0, False),
+    # rotary positions on the packed layout at the Laguna cell's shapes
+    # (ops/rotary.py: lane rotates inside each head's 128 lanes, lane
+    # slices of a block of 8 heads): sliding layers' q, every lane; full
+    # layers' q and the 8 key heads, the first 64 lanes with YaRN; a
+    # 256-lane head whose last 64 turn (GLM's query) and float32 data.
+    # The backward is the same kernel; nothing of four axes is left
+    ("rotary-1x8192x64x128-every-lane",
+     lambda: _turned("fwd", (1, 8192, 8192), 64, dict(rotary_dim=128)),
+     1, False),
+    ("rotary-1x8192x64x128-every-lane-bwd",
+     lambda: _turned("bwd", (1, 8192, 8192), 64, dict(rotary_dim=128)),
+     2, False),
+    ("rotary-1x8192x64x128-every-lane-bwd-holds-no-four-axes",
+     lambda: _turned("bwd", (1, 8192, 8192), 64, dict(rotary_dim=128)),
+     ("[1,8192,64,128]", 0), False),
+    ("rotary-1x8192x48x128-first-64-yarn-bwd",
+     lambda: _turned("bwd", (1, 8192, 6144), 48, dict(
+         rotary_dim=64, theta=5e5, first=True, yarn=(64, 4096, 64, 1),
+         attention_factor=1.4158883083359672)), 2, False),
+    ("rotary-1x8192x8x128-first-64-yarn-float32-bwd",
+     lambda: _turned("bwd", (1, 8192, 1024), 8, dict(
+         rotary_dim=64, theta=5e5, first=True, yarn=(64, 4096, 64, 1),
+         attention_factor=1.4158883083359672), jnp.float32), 2, False),
+    ("rotary-2x4096x20x256-last-64-bwd",
+     lambda: _turned("bwd", (2, 4096, 5120), 20, dict(rotary_dim=64,
+                                                      theta=1e6)), 2, False),
+    # ... the rule's widest block (float32 heads of 1,024 lanes) fits the
+    # fast memory Mosaic grants a kernel that asks for none
+    ("rotary-1x2048x2x1024-float32-bwd",
+     lambda: _turned("bwd", (1, 2048, 2048), 2, dict(rotary_dim=1024),
+                     jnp.float32), 2, False),
+    # ... heads of 192 lanes, GLM's one rotary key of 64 and a length that
+    # is no multiple of the row block keep the composition
+    ("rotary-2x4096x20x192-gets-xla",
+     lambda: _turned("bwd", (2, 4096, 3840), 20, dict(rotary_dim=64)),
+     0, False),
+    ("rotary-2x4096x1x64-gets-xla",
+     lambda: _turned("bwd", (2, 4096, 64), 1, dict(rotary_dim=64)),
+     0, False),
+    ("rotary-1x1000x8x128-gets-xla",
+     lambda: _turned("bwd", (1, 1000, 1024), 8, dict(rotary_dim=128)),
+     0, False),
+    # ... a recomputed attention layer runs it again - nothing of q and k
+    # is kept across the recomputation - beside the flash kernels, whose
+    # forward is kept: q and k in the forward, in the second run and in
+    # the backward (6) + the core's forward and backward (2)
+    ("rotary-swa-1x64on8x8192x128-recomputed-turns-q-and-k-three-times",
+     lambda: _recomputed(_turned_heads("sliding_attention")), 8, False),
+    ("rotary-gqa-1x48on8x8192x128-recomputed-turns-q-and-k-three-times",
+     lambda: _recomputed(_turned_heads("full_attention")), 8, False),
+    # ... and under the four chips' layout each chip turns its own rows
+    # (forward and backward: the second run of a layer nobody reads is
+    # dropped)
+    ("rotary-8x4096x8x128-on-four-chips",
+     lambda: _recomputed(_turned("fwd", (8, 4096, 1024), 8,
+                                 dict(rotary_dim=128)), (0,)) + (True,),
+     2, False),
     # a mask, or a T that is no block multiple, keeps the composition:
     # the same program text as with the kernels switched off
     ("bert-base-8x12x512x64-masked-gets-xla",
@@ -577,6 +672,76 @@ def test_the_laguna_step_fits_the_described_chip(topo, monkeypatch, capsys):
     # 5 attention layers x (forward + one backward kernel) among them
     assert got["tpu_custom_calls"] >= 10
     assert got["batch"] == 1
+
+
+def test_the_laguna_step_turns_q_and_k_on_the_packed_layout(topo,
+                                                            monkeypatch,
+                                                            capsys):
+    """The same step's program text: q and k of the five attention layers
+    go through ops/rotary.py's kernel in the forward, in the recomputed
+    forward and in the backward (30 custom calls), each under its layer's
+    `rotary` scope - which is how benchmark/harness/scope_time_swa.py's
+    note finds them - and none under `attention_core`, whose time
+    `attention_kernel_share.swa` and `window_attention_roofline` read; no
+    instruction under `rotary` is a float32 tensor of four axes, and no
+    float32 tensor of a token's heads is copied into another layout (the
+    composition's `f32[1,8192,64,128]` result and its relayout copy:
+    PERF.md section 6, PR 40.  The head gate's backward still multiplies
+    at that shape inside its fusion: ROADMAP S17)."""
+    import importlib.util
+    from benchmark.run import Run
+    from mxnet_tpu import telemetry
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+    load = Run.model
+
+    def model(run):
+        module = load(run)
+        module.balance_routers = lambda *args: None     # as above
+        return module
+
+    monkeypatch.setattr(Run, "model", model)
+    texts = []
+    as_text = jax.stages.Compiled.as_text
+
+    def noted(self, *args, **kwargs):
+        texts.append(as_text(self, *args, **kwargs))
+        return texts[-1]
+
+    monkeypatch.setattr(jax.stages.Compiled, "as_text", noted)
+    spec = importlib.util.spec_from_file_location(
+        "_aot_check", os.path.join(REPO, "benchmark", "tools",
+                                   "aot_check.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    def calls():
+        return {path: telemetry.registry.value("rotary_calls",
+                                               {"path": path})
+                for path in ("kernel", "composition")}
+
+    before = calls()
+    assert tool.main(["--workload", "lagunaxs2-train-s8192-ep8share"]) == 0
+    capsys.readouterr()
+    # q and k of each kind of layer, traced once a shape in a process
+    # (the test above may have traced them): none by the composition
+    assert calls()["kernel"] >= 4
+    assert calls()["composition"] == before["composition"]
+    lines = [line for line in texts[-1].splitlines() if " = " in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in lines if "tpu_custom_call" in line]
+    turns = [name for name in names if "rotary_turn" in name]
+    assert len(turns) == 5 * 2 * 3
+    assert all("rotary" in name.split("/")
+               and "attention_core" not in name.split("/")
+               and ("attention_window" in name.split("/")
+                    or "attention_full" in name.split("/"))
+               for name in turns), turns
+    assert sum("rematted_computation" in name for name in turns) == 10
+    wide = re.compile(r"= f32\[1,8192,\d+,128\]")
+    assert not [line for line in lines if wide.search(line)
+                and "/rotary/" in line]
+    assert not [line for line in lines if wide.search(line)
+                and " copy(" in line]
 
 
 # ---------------------------------------------------------------------------
