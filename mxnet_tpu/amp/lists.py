@@ -29,7 +29,8 @@ TARGET_DTYPE_OPS = [
 # (softmax/log/exp accumulate in ways that overflow/cancel in 8-bit-mantissa
 # bf16; norms divide by small variances).
 FP32_OPS = [
-    "softmax", "log_softmax", "softmin", "masked_softmax",
+    "softmax", "log_softmax", "sparse_softmax_cross_entropy", "softmin",
+    "masked_softmax",
     "masked_log_softmax", "softmax_cross_entropy", "SoftmaxOutput",
     "CTCLoss",
     "BatchNorm", "LayerNorm", "GroupNorm", "InstanceNorm", "RMSNorm",

@@ -14,6 +14,7 @@ import numpy as _np
 
 from ..ndarray.ndarray import NDArray, invoke
 from .. import ndarray as nd
+from .. import telemetry
 from .block import HybridBlock
 
 __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
@@ -21,6 +22,19 @@ __all__ = ["Loss", "L2Loss", "L1Loss", "SigmoidBinaryCrossEntropyLoss",
            "KLDivLoss", "CTCLoss", "HuberLoss", "HingeLoss",
            "SquaredHingeLoss", "LogisticLoss", "TripletLoss",
            "PoissonNLLLoss", "CosineEmbeddingLoss", "SDMLLoss"]
+
+
+def count_cross_entropy(path):
+    """The counter ``cross_entropy_calls{path}``: a softmax cross-entropy
+    loss's calls by what computes them, ``fused`` (the
+    ``sparse_softmax_cross_entropy`` operator) or ``composition``
+    (`log_softmax` and a pick or a product).  Counted where the loss is
+    called, so a compiled step counts each of its losses once, when it is
+    traced, however often it runs."""
+    telemetry.registry.counter(
+        "cross_entropy_calls", "calls of the softmax cross-entropy loss by "
+        "what computes them: the sparse_softmax_cross_entropy operator, or "
+        "log_softmax and a pick or a product", {"path": path}).inc()
 
 
 def _apply_weighting(loss, weight=None, sample_weight=None):
@@ -117,8 +131,11 @@ SigmoidBCELoss = SigmoidBinaryCrossEntropyLoss
 
 
 class SoftmaxCrossEntropyLoss(Loss):
-    """Reference: SoftmaxCELoss — sparse_label picks via one-hot/log_softmax;
-    fused into the matmul's epilogue by XLA on TPU."""
+    """Reference: SoftmaxCELoss.  Sparse labels on logits take the
+    ``sparse_softmax_cross_entropy`` operator (logsumexp less the picked
+    logit, in float32: no float32 ``[..., classes]`` tensor is written);
+    `from_logits` and dense labels keep `log_softmax` and a pick or a
+    product."""
 
     def __init__(self, axis=-1, sparse_label=True, from_logits=False,
                  weight=None, batch_axis=0, **kwargs):
@@ -128,14 +145,20 @@ class SoftmaxCrossEntropyLoss(Loss):
         self._from_logits = from_logits
 
     def forward(self, pred, label, sample_weight=None):
-        if not self._from_logits:
-            pred = pred.log_softmax(axis=self._axis)
-        if self._sparse_label:
-            loss = -invoke("pick", pred, label, axis=self._axis,
-                           keepdims=False)
+        if self._sparse_label and not self._from_logits:
+            count_cross_entropy("fused")
+            loss = invoke("sparse_softmax_cross_entropy", pred, label,
+                          axis=self._axis)
         else:
-            label = label.reshape(pred.shape)
-            loss = -(pred * label).sum(axis=self._axis, keepdims=False)
+            count_cross_entropy("composition")
+            if not self._from_logits:
+                pred = pred.log_softmax(axis=self._axis)
+            if self._sparse_label:
+                loss = -invoke("pick", pred, label, axis=self._axis,
+                               keepdims=False)
+            else:
+                label = label.reshape(pred.shape)
+                loss = -(pred * label).sum(axis=self._axis, keepdims=False)
         loss = _apply_weighting(loss, self._weight, sample_weight)
         return _batch_mean(loss, self._batch_axis)
 

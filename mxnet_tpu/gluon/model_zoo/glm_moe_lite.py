@@ -39,7 +39,7 @@ from __future__ import annotations
 from ...ndarray.ndarray import invoke
 from .. import nn
 from ..block import HybridBlock, trace_scope
-from ..loss import Loss
+from ..loss import Loss, count_cross_entropy
 
 __all__ = ["GLMMoeLite", "DecoderBlock", "MLAttention", "MTPModule",
            "NextTokenLoss", "glm_4_7_flash"]
@@ -247,8 +247,9 @@ class NextTokenLoss(Loss):
     @staticmethod
     def _term(logits, ids, ahead):
         """Mean over positions 0 .. T - ahead - 1 of -log p(t_{i+ahead})."""
-        nll = -invoke("pick", logits.astype("float32").log_softmax(axis=-1),
-                      invoke("roll", ids, shift=-ahead, axis=1), axis=-1)
+        count_cross_entropy("fused")
+        nll = invoke("sparse_softmax_cross_entropy", logits,
+                     invoke("roll", ids, shift=-ahead, axis=1), axis=-1)
         return invoke("slice_axis", nll, axis=1, begin=0,
                       end=ids.shape[1] - ahead).mean(axis=1)
 

@@ -269,6 +269,27 @@ def _log_softmax(data, axis=-1, temperature=None, dtype=None):
     return jax.nn.log_softmax(x, axis=axis).astype(dtype or data.dtype)
 
 
+@register("sparse_softmax_cross_entropy")
+def _sparse_softmax_cross_entropy(data, label, axis=-1):
+    """``-log softmax(data)[label]`` along `axis`, in float32: the
+    logsumexp less the picked logit, `label` clipped into the classes as
+    `pick` clips it.  The pick is a compare, a select and a sum, no
+    gather: XLA fuses the float32 copy of the logits into the two
+    reductions and never writes it (a gather's operand is written whole
+    first, a float32 ``[..., classes]`` tensor read for one value a
+    row), and the select's transpose is a select, so the backward,
+    ``softmax - onehot``, holds no scatter either."""
+    x = data.astype(jnp.float32)
+    axis = axis % x.ndim
+    idx = jnp.clip(label.astype(jnp.int32), 0, x.shape[axis] - 1)
+    shape = [1] * x.ndim
+    shape[axis] = x.shape[axis]
+    hit = lax.broadcasted_iota(jnp.int32, shape, axis) \
+        == jnp.expand_dims(idx, axis)
+    picked = jnp.sum(jnp.where(hit, x, 0.0), axis=axis)
+    return jax.nn.logsumexp(x, axis=axis) - picked
+
+
 @register("softmin")
 def _softmin(data, axis=-1, temperature=None, dtype=None):
     return _softmax(-data, axis=axis, temperature=temperature, dtype=dtype)

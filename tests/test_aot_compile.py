@@ -599,6 +599,75 @@ def test_flash_is_partitioned_by_batch_on_four_chips(topo, monkeypatch):
         compiled(None)
 
 
+def _entry_types(text):
+    """The result type of each instruction of the entry computation that
+    writes one (a fusion's insides are not written; a parameter, a
+    bitcast or a tuple's element is no write)."""
+    body = text.split("\nENTRY", 1)[1].split("\n}", 1)[0]
+    out = []
+    for line in body.splitlines()[1:]:
+        if " = " in line:
+            rhs = line.split(" = ", 1)[1]
+            op = re.search(r" ([a-z][\w\-.]*)\(", rhs)
+            if op.group(1) not in ("parameter", "bitcast",
+                                   "get-tuple-element"):
+                out.append(rhs[:op.start()])
+    return out
+
+
+def _composition_loss(logits, label):
+    """The sparse-label loss as SoftmaxCrossEntropyLoss and NextTokenLoss
+    had it: `log_softmax`, then `pick` (a gather)."""
+    from mxnet_tpu.ops import nn as ops_nn, matrix
+    return -matrix._pick(ops_nn._log_softmax(logits), label)
+
+
+# head: (x, the head's weight, the labels' dtype, a bias), and the float32
+# vocabulary-wide results and scatters of the entry computation that the
+# composition leaves: BERT's log_softmax (the chip trace's `fusion.22`, 2 GB,
+# PR 25); at Nemotron's head that and the gather's transpose, a scatter-add
+# into float32 zeros of the logits' size
+LOSS_HEADS = {
+    "bert-32x512x30522": ((32, 512, 768), (30522, 768), jnp.float32, True,
+                          1, 0),
+    "nemotron-1x8192x16384": ((1, 8192, 4096), (16384, 4096), jnp.int32,
+                              False, 2, 1),
+}
+
+
+@pytest.mark.parametrize("form", ["operator", "composition"])
+@pytest.mark.parametrize("head", sorted(LOSS_HEADS))
+def test_the_sparse_label_loss_writes_no_float32_vocabulary(topo, chip, head,
+                                                            form):
+    """``value_and_grad`` of a head's product and the mean sparse-label
+    loss over its logits cast to float32, compiled for a described v5e:
+    with the ``sparse_softmax_cross_entropy`` operator the entry
+    computation holds no float32 instruction as wide as the vocabulary
+    and no scatter (the float32 copy is fused into the reductions and
+    the backward's products); the composition it replaced holds them."""
+    from mxnet_tpu.ops import nn as ops_nn
+    x, w, label_dtype, bias, wide, scatters = LOSS_HEADS[head]
+    loss = ops_nn._sparse_softmax_cross_entropy if form == "operator" \
+        else _composition_loss
+
+    def mean_loss(x, w, b, label):
+        logits = jnp.einsum("bth,vh->btv", x, w)
+        if bias:
+            logits = logits + b
+        return loss(logits.astype(jnp.float32), label).mean()
+
+    args = [(x, jnp.bfloat16), (w, jnp.bfloat16), (w[:1], jnp.bfloat16),
+            (x[:2], label_dtype)]
+    abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                for shape, dtype in args]
+    text = jax.jit(jax.value_and_grad(mean_loss, argnums=(0, 1, 2))).lower(
+        *abstract).compile().as_text()
+    vocab = re.compile(r"f32\[[\d,]*\b%d\b[\d,]*\]" % w[0])
+    written = sum(len(vocab.findall(kind)) for kind in _entry_types(text))
+    assert (written, text.count(" scatter(")) == \
+        ((0, 0) if form == "operator" else (wide, scatters))
+
+
 def test_the_nemotron_step_fits_the_described_chip(topo, monkeypatch,
                                                    capsys):
     """The whole train step of the cell

@@ -372,6 +372,11 @@ SPECS.update({
              - np.log(np.exp(x - x.max(-1, keepdims=True)).sum(
                  -1, keepdims=True)))[np.arange(3), y]).sum(),
             np.float32)),
+    # labels past both ends are clipped into the classes, as `pick` does
+    "sparse_softmax_cross_entropy": S(
+        lambda: _own_draw((3, 4)) + [np.array([-2, 1, 9], np.int32)],
+        ref=lambda x, y: np.log(np.exp(x).sum(-1))
+        - x[np.arange(3), np.clip(y, 0, 3)]),
     "smooth_l1": S(lambda: [f(3, 4)], {"scalar": 1.0},
                    ref=lambda x: np.where(np.abs(x) < 1, 0.5 * x * x,
                                           np.abs(x) - 0.5)),
