@@ -16,6 +16,7 @@ import numpy as _np
 
 from ...base import MXNetError
 from ... import ndarray as nd
+from ... import telemetry
 from ...ndarray.ndarray import NDArray, invoke
 from ... import initializer as init
 from ..block import Block, HybridBlock
@@ -158,6 +159,20 @@ class Dropout(HybridBlock):
         return "Dropout(p = %s, axes=%s)" % (self._rate, self._axes)
 
 
+def count_batch_norm(stats):
+    """The counter ``batch_norm_calls{stats}``: a BatchNorm layer's calls
+    by the statistics they normalise with, ``batch`` (training: the
+    batch's, the moving ones updated) or ``moving`` (``use_global_stats``
+    and inference).  Counted where the layer is called, as
+    ``cross_entropy_calls``: a compiled step counts each of its layers
+    once, when it is traced, however often it runs (the operator itself
+    is traced once a shape)."""
+    telemetry.registry.counter(
+        "batch_norm_calls", "calls of a BatchNorm layer by the statistics "
+        "they normalise with: the batch's, or the moving ones",
+        {"stats": stats}).inc()
+
+
 class BatchNorm(HybridBlock):
     """Reference: nn.BatchNorm over axis=1 (channels) with moving stats as
     aux states (running_mean/running_var mutated in train mode — the rebuild's
@@ -200,6 +215,7 @@ class BatchNorm(HybridBlock):
     def forward(self, x):
         from ... import autograd
         use_global = self._use_global_stats or not autograd.is_training()
+        count_batch_norm("moving" if use_global else "batch")
         ctx = x.context
         return invoke("BatchNorm", x, self.gamma.data(ctx),
                       self.beta.data(ctx), self.running_mean.data(ctx),

@@ -331,9 +331,18 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
     bshape[ax] = data.shape[ax]
     g = jnp.ones_like(gamma) if fix_gamma else gamma
     if training and not use_global_stats:
-        x32 = data.astype(jnp.float32)
-        mean = jnp.mean(x32, axis=red)
-        var = jnp.var(x32, axis=red)
+        # both statistics from one read of the data: float32 means of d
+        # and d² for d = x - K, K the moving mean (a constant here), so
+        # the two reductions share their operand and XLA folds both into
+        # the producer's output fusion (jnp.var needs the mean first: a
+        # second full pass).  K bounds the cancellation of E[d²] - E[d]²
+        # once the moving mean tracks the batch's; at K = 0 it is the
+        # plain one-pass form of _contrib_SyncBatchNorm.
+        k = lax.stop_gradient(moving_mean).astype(jnp.float32)
+        d = data.astype(jnp.float32) - k.reshape(bshape)
+        m1 = jnp.mean(d, axis=red)
+        mean = k + m1
+        var = jnp.maximum(jnp.mean(jnp.square(d), axis=red) - m1 * m1, 0.0)
         new_mean = momentum * moving_mean + (1.0 - momentum) * mean
         new_var = momentum * moving_var + (1.0 - momentum) * var
     else:

@@ -599,20 +599,27 @@ def test_flash_is_partitioned_by_batch_on_four_chips(topo, monkeypatch):
         compiled(None)
 
 
+def _entry_instructions(text):
+    """The entry computation's instructions by name: (result type with
+    no layout, opcode, operand names, the line)."""
+    body = text.split("\nENTRY", 1)[1].split("\n}", 1)[0]
+    out = {}
+    for line in body.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (.*?) ([a-z][\w\-.]*)\(([^)]*)\)",
+                     line)
+        if m:
+            out[m.group(1)] = (re.sub(r"\{[^}]*\}", "", m.group(2)),
+                               m.group(3), re.findall(r"%[\w.\-]+",
+                                                      m.group(4)), line)
+    return out
+
+
 def _entry_types(text):
     """The result type of each instruction of the entry computation that
     writes one (a fusion's insides are not written; a parameter, a
     bitcast or a tuple's element is no write)."""
-    body = text.split("\nENTRY", 1)[1].split("\n}", 1)[0]
-    out = []
-    for line in body.splitlines()[1:]:
-        if " = " in line:
-            rhs = line.split(" = ", 1)[1]
-            op = re.search(r" ([a-z][\w\-.]*)\(", rhs)
-            if op.group(1) not in ("parameter", "bitcast",
-                                   "get-tuple-element"):
-                out.append(rhs[:op.start()])
-    return out
+    return [kind for kind, op, _, _ in _entry_instructions(text).values()
+            if op not in ("parameter", "bitcast", "get-tuple-element")]
 
 
 def _composition_loss(logits, label):
@@ -666,6 +673,65 @@ def test_the_sparse_label_loss_writes_no_float32_vocabulary(topo, chip, head,
     written = sum(len(vocab.findall(kind)) for kind in _entry_types(text))
     assert (written, text.count(" scatter(")) == \
         ((0, 0) if form == "operator" else (wide, scatters))
+
+
+def _two_pass_batch_norm(x, gamma, beta, moving_mean, moving_var):
+    """BatchNorm's training branch as it was: `jnp.mean`, then `jnp.var`."""
+    x32 = x.astype(jnp.float32)
+    mean, var = jnp.mean(x32, axis=(0, 2, 3)), jnp.var(x32, axis=(0, 2, 3))
+    inv = jax.lax.rsqrt(var + 1e-5).astype(x.dtype)
+    out = (x - mean.astype(x.dtype)[:, None, None]) * \
+        (inv * gamma.astype(x.dtype))[:, None, None] + \
+        beta.astype(x.dtype)[:, None, None]
+    return out, 0.9 * moving_mean + 0.1 * mean, 0.9 * moving_var + 0.1 * var
+
+
+@pytest.mark.parametrize("form", ["operator", "two-pass"])
+def test_batch_norm_statistics_come_out_of_the_convolution(topo, chip, form):
+    """A convolution -> BatchNorm -> ReLU -> convolution block at stage
+    1's width of ResNet-50 (64 -> 256 channels at 56 x 56, batch 8),
+    training, forward and backward, for a described v5e: with the
+    operator's one pass the entry computation holds no stand-alone
+    `kLoop` fusion that reduces the block's activation to a float32
+    vector of its channels, and the convolution's output fusion writes
+    both statistics beside the activation; the two-pass form leaves two
+    such reductions (the variance's, forward and backward) and one
+    statistic in the convolution's fusion."""
+    from mxnet_tpu.ops import nn as ops_nn
+    batch_norm = _two_pass_batch_norm if form == "two-pass" else \
+        (lambda *args: ops_nn._batch_norm(*args, fix_gamma=False))
+    n, c_in, c, hw = 8, 64, 256, 56
+
+    def loss(x, w1, w2, gamma, beta, moving_mean, moving_var):
+        y = ops_nn._convolution(x, w1, None, kernel=(1, 1), num_filter=c,
+                                no_bias=True)
+        y, new_mean, new_var = batch_norm(y, gamma, beta, moving_mean,
+                                          moving_var)
+        z = ops_nn._convolution(jnp.maximum(y, 0), w2, None, kernel=(1, 1),
+                                num_filter=c_in, no_bias=True)
+        return z.astype(jnp.float32).sum(), (new_mean, new_var)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = [((n, c_in, hw, hw), bf16), ((c, c_in, 1, 1), bf16),
+            ((c_in, c, 1, 1), bf16)] + [((c,), f32)] * 4
+    abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                for shape, dtype in args]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)) \
+        .lower(*abstract).compile().as_text()
+    entry = _entry_instructions(text)
+    activation, stat = "bf16[%d,%d,%d,%d]" % (n, c, hw, hw), "f32[%d]" % c
+    alone = [name for name, (kind, op, operands, line) in entry.items()
+             if op == "fusion" and "kind=kLoop" in line
+             and stat in kind and activation not in kind
+             and any(entry[o][0] == activation for o in operands
+                     if o in entry)]
+    convolution = [kind for kind, op, _, line in entry.values()
+                   if op == "fusion" and "kind=kOutput" in line
+                   and activation in kind and 'jvp()/conv_general_dilated"'
+                   in line]
+    assert len(convolution) == 1
+    assert (len(alone), convolution[0].count(stat)) == \
+        ((0, 2) if form == "operator" else (2, 1))
 
 
 def test_the_nemotron_step_fits_the_described_chip(topo, monkeypatch,
