@@ -75,17 +75,17 @@ __all__ = ["attention_core", "attention_heads", "flash_attention",
            "cached_attention_multi", "paged_attention",
            "paged_attention_multi"]
 
-# kernel block sizes.  256 rows is the unit: the rule asks for multiples of
-# it, the blocks a call takes are whole units (`_Geometry.blocks` chooses
-# them from the shapes), the diagonal of a causal call is cut to it, and
-# the env can still sweep it on a chip.  On the v5e 512-row blocks beat 256
-# (one BERT-base layer, forward + backward: 1.77 against 2.53 ms, PERF.md
-# section 6, PR 27) and one key block of up to 1024 rows beats a loop with
-# online rescaling (2.53 against 3.46 ms).
-from ..base import get_env, recompute_keep
+from ..base import recompute_keep
 
-_BLOCK_Q = get_env("MX_FLASH_BLOCK_Q", 256, int)
-_BLOCK_K = get_env("MX_FLASH_BLOCK_K", 256, int)
+# the kernels' block unit, in rows of queries and of keys alike: the rule
+# asks for multiples of it, the blocks a call takes are whole units
+# (`_Geometry.blocks` chooses them from the shapes), and the diagonal of a
+# causal call is cut to it.  A constant: every run on record took 256.  On
+# the v5e 512-row blocks beat 256 (one BERT-base layer, forward +
+# backward: 1.77 against 2.53 ms, PERF.md section 6) and one key block
+# of up to 1024 rows beats a loop with online rescaling (2.53 against
+# 3.46 ms).
+_UNIT = 256
 
 _LANES = 128            # a vector register's lanes: the packed block width
 
@@ -188,13 +188,13 @@ def flash_rule(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16,
     heads a key/value head (1: as many of each); a shared key/value head
     fills its own 128-lane block.  `window` (a causal call's, in keys a
     query sees with its own): a multiple of the 256-row unit."""
-    return (mask is None and Tq % _BLOCK_Q == 0 and Tk % _BLOCK_K == 0
+    return (mask is None and Tq % _UNIT == 0 and Tk % _UNIT == 0
             and (D % _LANES == 0 or (D == 64 and group == 1))
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32))
             and (not causal or Tq == Tk)
             and (window is None
-                 or (causal and window > 0 and window % _BLOCK_K == 0)))
+                 or (causal and window > 0 and window % _UNIT == 0)))
 
 
 def use_flash(Tq, Tk, D, causal=False, mask=None, dtype=jnp.bfloat16,
@@ -342,14 +342,14 @@ class _Geometry:
         backward, 512-row blocks took 10.0 ms where 256-row blocks took
         12.1 and 1024 rows on either side 10.8-10.9 (larger blocks waste
         more of the diagonal; PERF.md section 6, PR 29)."""
-        def rows(seq, unit):
-            fits = self._working(2 * unit, 2 * unit, 4) \
+        def rows(seq):
+            fits = self._working(2 * _UNIT, 2 * _UNIT, 4) \
                 <= _FAST_MEMORY // 4
-            return 2 * unit if math.gcd(seq, window or 0) % (2 * unit) == 0 \
-                and fits else unit
+            return 2 * _UNIT if math.gcd(seq, window or 0) % (2 * _UNIT) \
+                == 0 and fits else _UNIT
 
-        bq, bk = rows(self.Tq, _BLOCK_Q), rows(self.Tk, _BLOCK_K)
-        one_block = not causal and self.Tk <= 4 * _BLOCK_K
+        bq, bk = rows(self.Tq), rows(self.Tk)
+        one_block = not causal and self.Tk <= 4 * _UNIT
         return bq, (self.Tk if one_block else bk), bk
 
     def _bytes(self, rows, itemsize=None):

@@ -69,7 +69,7 @@ _LANES = attention._LANES
 # ms against the composition's 3.70 and a plain copy's 0.439) and 128 rows
 # 9-13 % slower: 256 rows, the flash kernels' unit, keeps the rule widest
 # (tools/rotary_ladder.py; PERF.md section 6, PR 40).
-_ROWS = 256
+_ROWS = attention._UNIT
 _BLOCK_LANES = 1024
 
 

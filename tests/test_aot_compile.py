@@ -4,12 +4,11 @@ The TPU's compiler is installed with jax and compiles for a device that is
 described (`v5e:2x2`) and not attached, so the kernels of the main path
 are held to what Mosaic and XLA:TPU accept at real widths in tier-1:
 what interpret mode on the CPU cannot show (tiling, fast-memory limits).
-Nothing runs — results and times come only from a chip run.  Whole-model
-compiles (the ResNet-50 and BERT-base steps) take a quarter of a minute
-and more and stay out of tier-1.  Also here: chip_smoke.py's phase
-functions at tiny sizes on the CPU, and its refusal to pass without a TPU.
-
-The file name sorts first on purpose: tier-1 is cut by the clock.
+Nothing runs — results and times come only from a chip run.  The cases
+are kernels and layers; whole train steps are test_aot_steps.py's.  A
+program that several cases read is built and compiled once a module
+(`_program`).  Also here: chip_smoke.py's phase functions at tiny sizes
+on the CPU, and its refusal to pass without a TPU.
 """
 import json
 import os
@@ -19,43 +18,23 @@ import sys
 
 import pytest
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or libtpu logs to /tmp
+from described_v5e import topo        # noqa: F401 (fixture; sets TPU_LOG_DIR)
 
-import jax                                        # noqa: E402
-import jax.numpy as jnp                           # noqa: E402
-from jax.sharding import SingleDeviceSharding     # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
-from mxnet_tpu import tpu_kernel                  # noqa: E402
-from mxnet_tpu.base import RECOMPUTE_KEEP         # noqa: E402
-from mxnet_tpu.ops import attention as att        # noqa: E402
-from mxnet_tpu.serve.decode import DecodeConfig   # noqa: E402
+from mxnet_tpu import tpu_kernel
+from mxnet_tpu.base import RECOMPUTE_KEEP
+from mxnet_tpu.ops import attention as att
+from mxnet_tpu.serve.decode import DecodeConfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 
 @pytest.fixture(scope="module")
-def topo():
-    """A described v5e:2x2; the persistent compile cache is off around
-    these compiles (an entry written for a described device cannot be
-    read back without one, and the retry warns)."""
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    try:
-        described = topologies.get_topology_desc(platform="tpu",
-                                                 topology_name="v5e:2x2")
-    except Exception as e:      # no libtpu here: nothing to compile with
-        pytest.skip("cannot describe a v5e topology: %s" % e)
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield described
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def chip(topo):
+def chip(topo):                                   # noqa: F811
     """Sharding onto one chip of the described v5e:2x2."""
     return SingleDeviceSharding(topo.devices[0])
 
@@ -252,6 +231,40 @@ def _recomputed(forward, argnums=(0, 1, 2)):
     return step, args
 
 
+# the build functions whose program more than one case reads: each is one
+# key of `_program`'s cache, so the program is built and compiled once
+SHARED = {
+    "held-experts-fwd": lambda: _held_experts("fwd"),
+    "held-experts-bwd": lambda: _held_experts("bwd"),
+    "held-experts-recomputed": lambda: _recomputed(_held_experts("fwd"),
+                                                   (0, 1, 3, 4)),
+    "latent-experts-bwd": lambda: _latent_experts("bwd"),
+    "rotary-every-lane-bwd": lambda: _turned(
+        "bwd", (1, 8192, 8192), 64, dict(rotary_dim=128)),
+}
+_PROGRAMS = {}
+
+
+def _program(build, topo, chip):
+    """(lowered text, compiled text, the compiler's temporary bytes) of
+    `build`'s program for the described chip, compiled the first time a
+    case asks for it (the caller steers `att._on_tpu`)."""
+    if build not in _PROGRAMS:
+        fn, args, *four_chips = build()
+        layout, out = None, {}
+        if four_chips:
+            layout, chip = _rows_over_four_chips(topo)
+            out = {"out_shardings": (None,) + (chip,) * len(args)}
+        abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                    for shape, dtype in args]
+        with att.attention_partition_scope(layout):
+            lowered = jax.jit(fn, **out).lower(*abstract)
+        compiled = lowered.compile()
+        _PROGRAMS[build] = (lowered.as_text(), compiled.as_text(),
+                            compiled.memory_analysis().temp_size_in_bytes)
+    return _PROGRAMS[build]
+
+
 # (id, builder of (fn, [(shape, dtype), ...]) - and True for the rows over
 #  the four chips' data,fsdp = 2x2 inside attention_partition_scope -,
 #  tpu_custom_calls expected or (what else to count in the text, how
@@ -312,19 +325,18 @@ CASES = [
     # makes nothing the gradients read and is dropped.  None of XLA's own
     # `ragged-dot-*` kernels is left, forward or backward
     ("held-experts-8192x2048-top4-8of64",
-     lambda: _held_experts("fwd"), 4, False),
+     SHARED["held-experts-fwd"], 4, False),
     ("held-experts-8192x2048-top4-8of64-bwd",
-     lambda: _held_experts("bwd"), 12, False),
+     SHARED["held-experts-bwd"], 12, False),
     ("held-experts-8192x2048-top4-8of64-bwd-holds-no-ragged-dot",
-     lambda: _held_experts("bwd"), ("ragged-dot", 0), False),
+     SHARED["held-experts-bwd"], ("ragged-dot", 0), False),
     # the router's top-k and the dispatch's order and its inverse are
     # sorts; a recomputed block keeps all three results and its second
     # run sorts nothing (5 sorts if order and inverse were not kept)
     ("held-experts-8192x2048-top4-8of64-sorts",
-     lambda: _held_experts("fwd"), (" sort(", 3), False),
+     SHARED["held-experts-fwd"], (" sort(", 3), False),
     ("held-experts-8192x2048-top4-8of64-recomputed-sorts-once",
-     lambda: _recomputed(_held_experts("fwd"), (0, 1, 3, 4)),
-     (" sort(", 3), False),
+     SHARED["held-experts-recomputed"], (" sort(", 3), False),
     # Nemotron-3-Super's attention: query heads of 128 lanes that share
     # key/value heads, causal, 8192 positions - the cell's share (4 on 1)
     # and the published layer (32 on 2); the kernels index the shared
@@ -377,9 +389,9 @@ CASES = [
     ("latent-experts-8192x4096-top22-8of512",
      lambda: _latent_experts("fwd"), 4, False),
     ("latent-experts-8192x4096-top22-8of512-bwd",
-     lambda: _latent_experts("bwd"), 12, False),
+     SHARED["latent-experts-bwd"], 12, False),
     ("latent-experts-8192x4096-top22-8of512-bwd-holds-no-ragged-dot",
-     lambda: _latent_experts("bwd"), ("ragged-dot", 0), False),
+     SHARED["latent-experts-bwd"], ("ragged-dot", 0), False),
     ("ssd-scan-1x8192x16x64-chunk128", lambda: _scan("fwd"), 0, False),
     ("ssd-scan-1x8192x16x64-chunk128-bwd", lambda: _scan("bwd"), 0, False),
     # rotary positions on the packed layout at the Laguna cell's shapes
@@ -392,11 +404,9 @@ CASES = [
      lambda: _turned("fwd", (1, 8192, 8192), 64, dict(rotary_dim=128)),
      1, False),
     ("rotary-1x8192x64x128-every-lane-bwd",
-     lambda: _turned("bwd", (1, 8192, 8192), 64, dict(rotary_dim=128)),
-     2, False),
+     SHARED["rotary-every-lane-bwd"], 2, False),
     ("rotary-1x8192x64x128-every-lane-bwd-holds-no-four-axes",
-     lambda: _turned("bwd", (1, 8192, 8192), 64, dict(rotary_dim=128)),
-     ("[1,8192,64,128]", 0), False),
+     SHARED["rotary-every-lane-bwd"], ("[1,8192,64,128]", 0), False),
     ("rotary-1x8192x48x128-first-64-yarn-bwd",
      lambda: _turned("bwd", (1, 8192, 6144), 48, dict(
          rotary_dim=64, theta=5e5, first=True, yarn=(64, 4096, 64, 1),
@@ -461,22 +471,16 @@ def test_compiles_for_v5e(topo, chip, monkeypatch, build, custom_calls,
     # the code asks jax.default_backend(), sees the CPU here and would
     # take interpret mode: steer it in the test, not through an option
     monkeypatch.setattr(att, "_on_tpu", lambda: True)
-    fn, args, *four_chips = build()
     what, count = custom_calls if isinstance(custom_calls, tuple) \
         else ("tpu_custom_call", custom_calls)
-    layout, out = None, {}
-    if four_chips:
-        layout, chip = _rows_over_four_chips(topo)
-        out = {"out_shardings": (None,) + (chip,) * len(args)}
-    abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-                for shape, dtype in args]
-    with att.attention_partition_scope(layout):
-        lowered = jax.jit(fn, **out).lower(*abstract)
-    assert lowered.compile().as_text().count(what) == count
+    lowered, text, _ = _program(build, topo, chip)
+    assert text.count(what) == count
     if as_xla:
+        fn, args = build()
+        abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+                    for shape, dtype in args]
         with att.attention_impl_scope("xla"):
-            assert jax.jit(fn).lower(*abstract).as_text() \
-                == lowered.as_text()
+            assert jax.jit(fn).lower(*abstract).as_text() == lowered
 
 
 # cell: (the layer, what is differentiated, the exact buffer's rows, sorts,
@@ -495,9 +499,10 @@ def test_compiles_for_v5e(topo, chip, monkeypatch, build, custom_calls,
 #        compare against the expert axis is summed inside its fusion - an
 #        (N, k, E) array would be 369 MB more at the Nemotron shape).
 SIZED_EXPERTS = {
-    "glm": (lambda: _held_experts("fwd"), (0, 1, 3, 4), 32768, 3,
-            815_574_528, 1_250_000_000),
-    "nemotron": (lambda: _latent_experts("fwd"), (0, 1, 2, 4, 5), 65536, 5,
+    "glm": (SHARED["held-experts-recomputed"], 32768, 3, 815_574_528,
+            1_250_000_000),
+    "nemotron": (lambda: _recomputed(_latent_experts("fwd"),
+                                     (0, 1, 2, 4, 5)), 65536, 5,
                  1_163_004_928, 1_280_000_000),
 }
 
@@ -516,12 +521,8 @@ def test_a_recomputed_expert_layer_holds_both_buffer_sizes(topo, chip,
     exact branch's residuals with zeros in the short one (PR 28's second
     branch: 2.2 GB of them at the GLM shape)."""
     monkeypatch.setattr(att, "_on_tpu", lambda: True)
-    build, argnums, rows, sorts, parent, most = SIZED_EXPERTS[cell]
-    step, args = _recomputed(build(), argnums)
-    abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-                for shape, dtype in args]
-    compiled = jax.jit(step).lower(*abstract).compile()
-    text = compiled.as_text()
+    build, rows, sorts, parent, most = SIZED_EXPERTS[cell]
+    _, text, temp = _program(build, topo, chip)
     assert "ragged-dot" not in text and "grouped_product" in text
     assert text.count(" sort(") == sorts
     conditionals = [line.split(" conditional(")[0]
@@ -533,7 +534,6 @@ def test_a_recomputed_expert_layer_holds_both_buffer_sizes(topo, chip,
                        re.findall(r"\[%d,(\d+)\]" % rows, c))]
     # with the grouped kernels the Nemotron layer reads 1,162,763,264:
     # XLA's ragged-dot expansion had temporaries of its own (PR 39)
-    temp = compiled.memory_analysis().temp_size_in_bytes
     assert parent * 0.99 < temp <= most
 
 
@@ -547,10 +547,7 @@ def test_every_grouped_kernel_carries_the_experts_scope(topo, chip,
     ``jax.vjp`` of the whole exact path named them
     ``transpose(jvp(experts))``)."""
     monkeypatch.setattr(att, "_on_tpu", lambda: True)
-    fn, args = _held_experts("bwd")
-    abstract = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-                for shape, dtype in args]
-    text = jax.jit(fn).lower(*abstract).compile().as_text()
+    _, text, _ = _program(SHARED["held-experts-bwd"], topo, chip)
     names = [re.search(r'op_name="([^"]*)"', line).group(1)
              for line in text.splitlines() if "tpu_custom_call" in line]
     assert len(names) == 12
@@ -732,151 +729,6 @@ def test_batch_norm_statistics_come_out_of_the_convolution(topo, chip, form):
     assert len(convolution) == 1
     assert (len(alone), convolution[0].count(stat)) == \
         ((0, 2) if form == "operator" else (2, 1))
-
-
-def test_the_nemotron_step_fits_the_described_chip(topo, monkeypatch,
-                                                   capsys):
-    """The whole train step of the cell
-    `nemotron3super-train-s8192-ep64tp8share` - 607.0 M parameters built
-    on the host, the step's program lowered from shapes - compiles for one
-    chip of the described v5e:2x2 (benchmark/tools/aot_check.py, the
-    builder's own tool): its arguments (weights, masters, AdamW's moments)
-    and its temporaries together stay under the 15.0 GB the issue allows
-    of the chip's 16, and both attention layers run the grouped-KV flash
-    kernels (forward + one backward kernel each, kept across the
-    recomputation)."""
-    import importlib.util
-    from benchmark.run import Run
-    monkeypatch.setattr(att, "_on_tpu", lambda: True)
-    load = Run.model
-
-    def model(run):
-        """The cell's model file without its set-up passes over the net
-        (`balance_routers` moves parameter VALUES; here is no device to
-        run a forward on, and the program does not depend on them)."""
-        module = load(run)
-        module.balance_routers = lambda *args: None
-        return module
-
-    monkeypatch.setattr(Run, "model", model)
-    spec = importlib.util.spec_from_file_location(
-        "_aot_check", os.path.join(REPO, "benchmark", "tools",
-                                   "aot_check.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
-    assert tool.main(
-        ["--workload", "nemotron3super-train-s8192-ep64tp8share"]) == 0
-    printed = capsys.readouterr().out
-    got = json.loads(printed[printed.index("{"):])
-    assert 8.4 < got["argument_gb"] < 8.6
-    assert got["arguments_plus_temp_gb"] < 15.0
-    assert got["tpu_custom_calls"] > 0
-    # twice as many rows do not fit: what the cell's batch of 1 is for
-    assert got["batch"] == 1
-
-
-def test_the_laguna_step_fits_the_described_chip(topo, monkeypatch, capsys):
-    """The whole train step of the cell `lagunaxs2-train-s8192-ep8share` -
-    691.6 M parameters built on the host, the step's program lowered from
-    shapes - compiles for one chip of the described v5e:2x2: arguments
-    (weights, masters, AdamW's moments) 9.68 GB and temporaries together
-    under the 15.0 GB the issue allows of the chip's 16 (read: 12.77), all
-    five attention layers in the flash kernels - three of them windowed -
-    kept across the recomputation."""
-    import importlib.util
-    from benchmark.run import Run
-    monkeypatch.setattr(att, "_on_tpu", lambda: True)
-    load = Run.model
-
-    def model(run):
-        module = load(run)
-        module.balance_routers = lambda *args: None     # as above
-        return module
-
-    monkeypatch.setattr(Run, "model", model)
-    spec = importlib.util.spec_from_file_location(
-        "_aot_check", os.path.join(REPO, "benchmark", "tools",
-                                   "aot_check.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
-    assert tool.main(["--workload", "lagunaxs2-train-s8192-ep8share"]) == 0
-    printed = capsys.readouterr().out
-    got = json.loads(printed[printed.index("{"):])
-    assert 9.6 < got["argument_gb"] < 9.8
-    assert got["arguments_plus_temp_gb"] < 15.0
-    # 5 attention layers x (forward + one backward kernel) among them
-    assert got["tpu_custom_calls"] >= 10
-    assert got["batch"] == 1
-
-
-def test_the_laguna_step_turns_q_and_k_on_the_packed_layout(topo,
-                                                            monkeypatch,
-                                                            capsys):
-    """The same step's program text: q and k of the five attention layers
-    go through ops/rotary.py's kernel in the forward, in the recomputed
-    forward and in the backward (30 custom calls), each under its layer's
-    `rotary` scope - which is how benchmark/harness/scope_time_swa.py's
-    note finds them - and none under `attention_core`, whose time
-    `attention_kernel_share.swa` and `window_attention_roofline` read; no
-    instruction under `rotary` is a float32 tensor of four axes, and no
-    float32 tensor of a token's heads is copied into another layout (the
-    composition's `f32[1,8192,64,128]` result and its relayout copy:
-    PERF.md section 6, PR 40.  The head gate's backward still multiplies
-    at that shape inside its fusion: ROADMAP S17)."""
-    import importlib.util
-    from benchmark.run import Run
-    from mxnet_tpu import telemetry
-    monkeypatch.setattr(att, "_on_tpu", lambda: True)
-    load = Run.model
-
-    def model(run):
-        module = load(run)
-        module.balance_routers = lambda *args: None     # as above
-        return module
-
-    monkeypatch.setattr(Run, "model", model)
-    texts = []
-    as_text = jax.stages.Compiled.as_text
-
-    def noted(self, *args, **kwargs):
-        texts.append(as_text(self, *args, **kwargs))
-        return texts[-1]
-
-    monkeypatch.setattr(jax.stages.Compiled, "as_text", noted)
-    spec = importlib.util.spec_from_file_location(
-        "_aot_check", os.path.join(REPO, "benchmark", "tools",
-                                   "aot_check.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
-
-    def calls():
-        return {path: telemetry.registry.value("rotary_calls",
-                                               {"path": path})
-                for path in ("kernel", "composition")}
-
-    before = calls()
-    assert tool.main(["--workload", "lagunaxs2-train-s8192-ep8share"]) == 0
-    capsys.readouterr()
-    # q and k of each kind of layer, traced once a shape in a process
-    # (the test above may have traced them): none by the composition
-    assert calls()["kernel"] >= 4
-    assert calls()["composition"] == before["composition"]
-    lines = [line for line in texts[-1].splitlines() if " = " in line]
-    names = [re.search(r'op_name="([^"]*)"', line).group(1)
-             for line in lines if "tpu_custom_call" in line]
-    turns = [name for name in names if "rotary_turn" in name]
-    assert len(turns) == 5 * 2 * 3
-    assert all("rotary" in name.split("/")
-               and "attention_core" not in name.split("/")
-               and ("attention_window" in name.split("/")
-                    or "attention_full" in name.split("/"))
-               for name in turns), turns
-    assert sum("rematted_computation" in name for name in turns) == 10
-    wide = re.compile(r"= f32\[1,8192,\d+,128\]")
-    assert not [line for line in lines if wide.search(line)
-                and "/rotary/" in line]
-    assert not [line for line in lines if wide.search(line)
-                and " copy(" in line]
 
 
 # ---------------------------------------------------------------------------

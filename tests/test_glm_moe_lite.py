@@ -4,7 +4,7 @@ plain float32 reference the benchmark keeps
 both heads, loss and gradients; the expert layer's shares adding up to the
 whole; no dropped token under a skewed router; recomputation; the compiled
 step."""
-import importlib.util
+import functools
 import os
 
 import numpy as np
@@ -18,19 +18,10 @@ from mxnet_tpu import autograd, gluon, nd, programs, telemetry
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.parallel import moe
 
+import decoder_checks as checks
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _model_file():
-    spec = importlib.util.spec_from_file_location(
-        "_glm_4_7_flash", os.path.join(REPO, "benchmark", "models",
-                                       "glm_4_7_flash.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-MODEL = _model_file()
+MODEL = checks.model_file("glm_4_7_flash")
 
 # hidden 64, 4 heads of 24 + 8 | 32, 8 experts top-2 of which 2 are held,
 # 1 dense + 2 expert blocks + the MTP module, 96 ids
@@ -65,9 +56,12 @@ def _params(net):
     return {name: p.data()._jax for name, p in net.collect_params().items()}
 
 
-def _kind(name):
-    """A parameter's kind: its name without the block numbers."""
-    return ".".join(p for p in name.split(".") if not p.isdigit())
+@functools.lru_cache(maxsize=None)
+def _reading_net(dtype, seed):
+    """`_net(dtype, seed)` and its parameters as built, once a module: the
+    tests that hold the reference to it change neither."""
+    net, config = _net(dtype, seed=seed)
+    return net, config, _params(net)
 
 
 # -- logits, loss and gradients against the reference ------------------------
@@ -86,57 +80,6 @@ TOLERANCE = {"float32": {"logits": 2e-5, "loss": 1e-5, "grads": 2e-4,
                           "gap": 2e-2}}
 
 
-def _net_readings(net, ids):
-    """(logits (2,B,T,V), routing, loss, {name: grad}) of the Gluon net
-    through the tape."""
-    loss_fn = MODEL.loss_fn()
-    x = nd.array(ids, ctx=CTX, dtype="int32")
-    with autograd.record():
-        outs = net(x)
-        loss = loss_fn(outs[:2], x).mean()      # the reference's: over rows
-    loss.backward()
-    grads = {name: np.asarray(p.grad()._jax, np.float32)
-             for name, p in net.collect_params().items()
-             if p.grad_req != "null"}
-    logits = np.stack([np.asarray(o._jax, np.float32) for o in outs[:2]])
-    return logits, np.asarray(outs[2]._jax), \
-        float(np.asarray(loss._jax, np.float32).mean()), grads
-
-
-def _reference_readings(params, ids, config, operand=None, given=None):
-    """The same four of the reference (own router choices, or following
-    `given`), and the worst gap of a followed choice."""
-    inputs = (ids,) if given is None else (ids, given)
-    main, mtp, routing, _, gap = MODEL._forward(params, inputs, config,
-                                                operand)
-    loss, grads = jax.value_and_grad(
-        lambda ps: MODEL.reference_loss(ps, inputs, config, operand))(params)
-    return (np.stack([np.asarray(main), np.asarray(mtp)]),
-            np.asarray(routing), float(loss),
-            {k: np.asarray(v, np.float32) for k, v in grads.items()}), \
-        float(np.asarray(gap).max())
-
-
-def _errors(got, want):
-    """(logits error over the logit scale, relative loss error, {kind:
-    gradient error over that kind's gradient norm})."""
-    g_logits, _, g_loss, g_grads = got
-    w_logits, _, w_loss, w_grads = want
-    kinds = {}
-    for name, g in g_grads.items():
-        err = np.linalg.norm(g - w_grads[name])
-        scale = np.linalg.norm(w_grads[name]) + 1e-12
-        kinds[_kind(name)] = max(kinds.get(_kind(name), 0.0), err / scale)
-    return (np.abs(g_logits - w_logits).max() / np.abs(w_logits).max(),
-            abs(g_loss - w_loss) / abs(w_loss), kinds)
-
-
-def _fails(tol, errors, gap):
-    logits, loss, kinds = errors
-    return bool(logits > tol["logits"] or loss > tol["loss"]
-                or max(kinds.values()) > tol["grads"] or gap > tol["gap"])
-
-
 @pytest.mark.parametrize("dtype,seed", [("float32", 11), ("bfloat16", 11),
                                         ("bfloat16", 14)])
 def test_logits_loss_and_gradients_match_the_reference(dtype, seed):
@@ -148,12 +91,12 @@ def test_logits_loss_and_gradients_match_the_reference(dtype, seed):
     forward pass the second run took seed 14's near-tie the other way -
     gradients 37 % off (2 % with the blocks unmarked) under logits that
     agreed to 0.7 %."""
-    net, config = _net(dtype, seed=seed)
+    net, config, params = _reading_net(dtype, seed)
     ids = _ids()
-    params = _params(net)
-    got = _net_readings(net, ids)
-    want, gap = _reference_readings(params, ids, config, given=got[1])
-    logits, loss, kinds = _errors(got, want)
+    got = checks.net_readings(net, ids, MODEL.loss_fn(), heads=2)
+    want, gap = checks.reference_readings(MODEL, params, ids, config,
+                                          given=got[1])
+    logits, loss, kinds = checks.errors(got, want)
     tol = TOLERANCE[dtype]
     assert gap <= tol["gap"], gap
     assert logits <= tol["logits"], logits
@@ -161,7 +104,7 @@ def test_logits_loss_and_gradients_match_the_reference(dtype, seed):
     assert len(kinds) >= 20            # every parameter kind has a gradient
     worst = max(kinds, key=kinds.get)
     assert kinds[worst] <= tol["grads"], (worst, kinds[worst])
-    own, _ = _reference_readings(params, ids, config)
+    own, _ = checks.reference_readings(MODEL, params, ids, config)
     differ = (np.sort(got[1], -1) != np.sort(own[1], -1)).any(-1).mean()
     assert (differ == 0) if dtype == "float32" else (0 < differ < 0.05)
 
@@ -171,18 +114,17 @@ def test_the_limits_refuse_float8_and_a_skipped_term():
     a net is (its choices followed): the reference with float8_e4m3
     operands, and with one term left out - the selection bias, the shared
     expert, the routed scaling - must each fail at least one of them."""
-    net, config = _net("float32")
+    _, config, params = _reading_net("float32", 11)
     ids = _ids()
-    params = _params(net)
     tol = TOLERANCE["bfloat16"]
 
     def fails(other_params=None, operand=None, **other_config):
-        reading, _ = _reference_readings(other_params or params, ids,
-                                         dict(config, **other_config),
-                                         operand)
-        want, gap = _reference_readings(params, ids, config,
-                                        given=reading[1])
-        return _fails(tol, _errors(reading, want), gap)
+        reading, _ = checks.reference_readings(
+            MODEL, other_params or params, ids, dict(config, **other_config),
+            operand)
+        want, gap = checks.reference_readings(MODEL, params, ids, config,
+                                              given=reading[1])
+        return checks.fails(tol, checks.errors(reading, want), gap)
 
     assert not fails()
     assert fails(operand=jnp.float8_e4m3fn)
@@ -194,24 +136,24 @@ def test_the_limits_refuse_float8_and_a_skipped_term():
 
 
 def test_the_reference_follows_near_ties_and_refuses_a_wrong_router():
-    net, config = _net("float32")
+    _, config, params = _reading_net("float32", 11)
     config = dict(config, check_routing_gap=0.02)
     ids = _ids()
-    params = _params(net)
-    _, _, own, _, _ = MODEL._forward(params, (ids,), config)
+    forward = checks.jitted(MODEL._forward, config)
+    reference = checks.jitted(MODEL.reference, config)
+    _, _, own, _, _ = forward(params, ids)
     slot = MODEL.check_inputs(config, {"batch": 2, "seq": 32}, 0)[1]
     assert slot.shape == own.shape and (slot == -1).all()
-    free = np.asarray(MODEL.reference(params, (ids, slot), config))
-    same = np.asarray(MODEL.reference(params, (ids, np.asarray(own)),
-                                      config))
+    free = np.asarray(reference(params, ids, slot))
+    same = np.asarray(reference(params, ids, np.asarray(own)))
     np.testing.assert_array_equal(free, same)
     # an expert far from the top-k at one token of the first expert layer:
     # that token's logits (and the row's later MTP positions) are refused
     wrong = np.array(own)
     taken = set(wrong[0, 1, 5].tolist())
     wrong[0, 1, 5, 0] = next(e for e in range(8) if e not in taken)
-    out = np.asarray(MODEL.reference(params, (ids, wrong), config))
-    _, _, _, _, gap = MODEL._forward(params, (ids, wrong), config)
+    out = np.asarray(reference(params, ids, wrong))
+    _, _, _, _, gap = forward(params, ids, wrong)
     if float(np.asarray(gap).max()) > 0.02:
         assert np.isnan(out[:, 1, 5]).all()
         assert np.isfinite(out[:, 0]).all()
@@ -322,8 +264,9 @@ def test_the_grouped_products_differentiate_like_the_dense_loop():
             ** 2).sum()
 
     ws = [params[n] for n in names]
-    got = jax.grad(mine, argnums=(0, 1, 2, 3))(x, *ws)
-    want = jax.grad(theirs, argnums=(0, 1, 2, 3))(x, *ws)
+    # each side under one jax.jit: eagerly every primitive compiles alone
+    got = jax.jit(jax.grad(mine, argnums=(0, 1, 2, 3)))(x, *ws)
+    want = jax.jit(jax.grad(theirs, argnums=(0, 1, 2, 3)))(x, *ws)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
                                    atol=1e-4 * float(jnp.abs(w).max()))

@@ -6,7 +6,7 @@ mode against the banded composition; `_visits` with a window against a
 brute count of the visible pairs; YaRN's frequencies at the published
 numbers; the partial rotary part; the shares of a sparse layer adding up to
 the whole; the compiled step."""
-import importlib.util
+import functools
 import json
 import math
 import os
@@ -24,19 +24,10 @@ from mxnet_tpu.gluon.model_zoo import laguna
 from mxnet_tpu.ops import attention
 from mxnet_tpu.ops import nn as ops_nn
 
+import decoder_checks as checks
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _model_file():
-    spec = importlib.util.spec_from_file_location(
-        "_laguna_xs_2", os.path.join(REPO, "benchmark", "models",
-                                     "laguna_xs_2.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-MODEL = _model_file()
+MODEL = checks.model_file("laguna_xs_2")
 PUBLISHED_ROPE = laguna.ROPE_XS_2
 
 # hidden 48; heads of 16 lanes, 6 (full) and 8 (sliding) query heads on 2
@@ -81,9 +72,12 @@ def _params(block):
             for name, p in block.collect_params().items()}
 
 
-def _kind(name):
-    """A parameter's kind: its name without the layer numbers."""
-    return ".".join(p for p in name.split(".") if not p.isdigit())
+@functools.lru_cache(maxsize=None)
+def _reading_net(dtype, seed):
+    """`_net(dtype, seed)` and its parameters as built, once a module: the
+    tests that hold the reference to it change neither."""
+    net, config = _net(dtype, seed=seed)
+    return net, config, _params(net)
 
 
 # -- logits, loss and gradients against the reference ------------------------
@@ -101,60 +95,15 @@ TOLERANCE = {"float32": {"logits": 2e-5, "loss": 1e-5, "grads": 2e-4,
                           "gap": 2e-2}}
 
 
-def _net_readings(net, ids):
-    """(logits (B,T,V), routing, loss, {name: grad}) of the Gluon net
-    through the tape."""
-    loss_fn = MODEL.loss_fn()
-    x = nd.array(ids, ctx=CTX, dtype="int32")
-    with autograd.record():
-        outs = net(x)
-        loss = loss_fn(outs[0], x).mean()
-    loss.backward()
-    grads = {name: np.asarray(p.grad()._jax, np.float32)
-             for name, p in net.collect_params().items()
-             if p.grad_req != "null"}
-    return np.asarray(outs[0]._jax, np.float32), np.asarray(outs[1]._jax), \
-        float(np.asarray(loss._jax, np.float32).mean()), grads
-
-
-def _reference_readings(params, ids, config, operand=None, given=None,
-                        without=(), gradients=True):
-    inputs = (ids,) if given is None else (ids, given)
-    out, routing, _, gap = MODEL._forward(params, inputs, config, operand,
-                                          without)
-    if gradients:
-        loss, grads = jax.value_and_grad(lambda ps: MODEL.reference_loss(
-            ps, inputs, config, operand))(params)
-    else:       # the loss of the logits at hand, whatever made them wrong
-        logp = jax.nn.log_softmax(out[:, :-1], axis=-1)
-        loss, grads = -jnp.take_along_axis(
-            logp, jnp.asarray(ids)[:, 1:, None], axis=-1).mean(), {}
-    return (np.asarray(out), np.asarray(routing), float(loss),
-            {k: np.asarray(v, np.float32) for k, v in grads.items()}), \
-        float(np.asarray(gap).max())
-
-
-def _errors(got, want):
-    g_logits, _, g_loss, g_grads = got
-    w_logits, _, w_loss, w_grads = want
-    kinds = {}
-    for name, g in g_grads.items():
-        err = np.linalg.norm(g - w_grads[name])
-        scale = np.linalg.norm(w_grads[name]) + 1e-12
-        kinds[_kind(name)] = max(kinds.get(_kind(name), 0.0), err / scale)
-    return (np.abs(g_logits - w_logits).max() / np.abs(w_logits).max(),
-            abs(g_loss - w_loss) / abs(w_loss), kinds)
-
-
 @pytest.mark.parametrize("dtype,seed", [("float32", 11), ("bfloat16", 11),
                                         ("bfloat16", 14)])
 def test_logits_loss_and_gradients_match_the_reference(dtype, seed):
-    net, config = _net(dtype, seed=seed)
+    net, config, params = _reading_net(dtype, seed)
     ids = _ids()
-    params = _params(net)
-    got = _net_readings(net, ids)
-    want, gap = _reference_readings(params, ids, config, given=got[1])
-    logits, loss, kinds = _errors(got, want)
+    got = checks.net_readings(net, ids, MODEL.loss_fn(), heads=1)
+    want, gap = checks.reference_readings(MODEL, params, ids, config,
+                                          given=got[1])
+    logits, loss, kinds = checks.errors(got, want)
     tol = TOLERANCE[dtype]
     assert gap <= tol["gap"], gap
     assert logits <= tol["logits"], logits
@@ -166,7 +115,7 @@ def test_logits_loss_and_gradients_match_the_reference(dtype, seed):
     assert "blocks.attention_full.g_proj.weight" in kinds
     worst = max(kinds, key=kinds.get)
     assert kinds[worst] <= tol["grads"], (worst, kinds[worst])
-    own, _ = _reference_readings(params, ids, config)
+    own, _ = checks.reference_readings(MODEL, params, ids, config)
     differ = (np.sort(got[1], -1) != np.sort(own[1], -1)).any(-1).mean()
     assert (differ == 0) if dtype == "float32" else (differ < 0.2)
 
@@ -178,19 +127,17 @@ def test_the_limits_refuse_lower_precision_and_a_wrong_reading(wrong):
     operands, with the band left out of the sliding layers, with YaRN
     left out of the full layers, and with the selection correction left
     out of the router must each fail at least one of them."""
-    net, config = _net("float32")
+    _, config, params = _reading_net("float32", 11)
     ids = _ids()
-    params = _params(net)
     tol = TOLERANCE["bfloat16"]
 
     def fails(operand=None, without=()):
-        reading, _ = _reference_readings(params, ids, config, operand,
-                                         without=without, gradients=False)
-        want, gap = _reference_readings(params, ids, config,
-                                        given=reading[1], gradients=False)
-        logits, loss, _ = _errors(reading, want)
-        return bool(logits > tol["logits"] or loss > tol["loss"]
-                    or gap > tol["gap"])
+        reading, _ = checks.reference_readings(
+            MODEL, params, ids, config, operand, without=without,
+            gradients=False)
+        want, gap = checks.reference_readings(
+            MODEL, params, ids, config, given=reading[1], gradients=False)
+        return checks.fails(tol, checks.errors(reading, want), gap)
 
     assert not fails()
     if wrong == "float8":
@@ -202,16 +149,15 @@ def test_the_limits_refuse_lower_precision_and_a_wrong_reading(wrong):
 def test_the_reference_refuses_a_wrong_router_with_nan():
     """A followed choice more than `check_routing_gap` under the
     reference's own 8th selection score: that token's logits are NaN."""
-    net, config = _net("float32")
+    net, config, params = _reading_net("float32", 11)
     ids = _ids()
-    params = _params(net)
     inputs = (ids, np.asarray(net(nd.array(ids, ctx=CTX,
                                            dtype="int32"))[1]._jax))
-    assert not np.isnan(np.asarray(
-        MODEL.reference(params, inputs, config))).any()
+    reference = checks.jitted(MODEL.reference, config)
+    assert not np.isnan(np.asarray(reference(params, *inputs))).any()
     flat = {k: (jnp.zeros_like(v) if k.endswith("router_correction") else v)
             for k, v in params.items()}
-    assert np.isnan(np.asarray(MODEL.reference(flat, inputs, config))).any()
+    assert np.isnan(np.asarray(reference(flat, *inputs))).any()
 
 
 # -- the windowed kernels against the banded composition ----------------------

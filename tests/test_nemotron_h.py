@@ -6,7 +6,7 @@ over positions, forward and backward; grouped key/value heads in the flash
 kernels against the composition; the latent squared-ReLU expert layer
 against a dense loop and its buffer under the worst imbalance; the shares
 of all three mixer kinds adding up to the whole; the compiled step."""
-import importlib.util
+import functools
 import json
 import os
 
@@ -22,19 +22,10 @@ from mxnet_tpu.gluon import nn
 from mxnet_tpu.ops import attention, ssm
 from mxnet_tpu.parallel import moe
 
+import decoder_checks as checks
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _model_file():
-    spec = importlib.util.spec_from_file_location(
-        "_nemotron_3_super", os.path.join(REPO, "benchmark", "models",
-                                          "nemotron_3_super.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-MODEL = _model_file()
+MODEL = checks.model_file("nemotron_3_super")
 
 # hidden 64; Mamba: 4 heads of 8 in 2 groups, state 16, chunks of 16;
 # attention: 4 query heads on 1 key/value head of 16; 16 experts top-5 of
@@ -71,9 +62,12 @@ def _params(block):
             for name, p in block.collect_params().items()}
 
 
-def _kind(name):
-    """A parameter's kind: its name without the layer numbers."""
-    return ".".join(p for p in name.split(".") if not p.isdigit())
+@functools.lru_cache(maxsize=None)
+def _reading_net(dtype, seed):
+    """`_net(dtype, seed)` and its parameters as built, once a module: the
+    tests that hold the reference to it change neither."""
+    net, config = _net(dtype, seed=seed)
+    return net, config, _params(net)
 
 
 # -- logits, loss and gradients against the reference ------------------------
@@ -99,62 +93,15 @@ TOLERANCE = {"float32": {"logits": 2e-5, "loss": 1e-5, "grads": 2e-4,
                           "gap": 2e-2}}
 
 
-def _net_readings(net, ids):
-    """(logits (2,B,T,V), routing, loss, {name: grad}) of the Gluon net
-    through the tape."""
-    loss_fn = MODEL.loss_fn()
-    x = nd.array(ids, ctx=CTX, dtype="int32")
-    with autograd.record():
-        outs = net(x)
-        loss = loss_fn(outs[:2], x).mean()
-    loss.backward()
-    grads = {name: np.asarray(p.grad()._jax, np.float32)
-             for name, p in net.collect_params().items()
-             if p.grad_req != "null"}
-    logits = np.stack([np.asarray(o._jax, np.float32) for o in outs[:2]])
-    return logits, np.asarray(outs[2]._jax), \
-        float(np.asarray(loss._jax, np.float32).mean()), grads
-
-
-def _reference_readings(params, ids, config, operand=None, given=None):
-    inputs = (ids,) if given is None else (ids, given)
-    main, mtp, routing, _, gap = MODEL._forward(params, inputs, config,
-                                                operand)
-    loss, grads = jax.value_and_grad(
-        lambda ps: MODEL.reference_loss(ps, inputs, config, operand))(params)
-    return (np.stack([np.asarray(main), np.asarray(mtp)]),
-            np.asarray(routing), float(loss),
-            {k: np.asarray(v, np.float32) for k, v in grads.items()}), \
-        float(np.asarray(gap).max())
-
-
-def _errors(got, want):
-    g_logits, _, g_loss, g_grads = got
-    w_logits, _, w_loss, w_grads = want
-    kinds = {}
-    for name, g in g_grads.items():
-        err = np.linalg.norm(g - w_grads[name])
-        scale = np.linalg.norm(w_grads[name]) + 1e-12
-        kinds[_kind(name)] = max(kinds.get(_kind(name), 0.0), err / scale)
-    return (np.abs(g_logits - w_logits).max() / np.abs(w_logits).max(),
-            abs(g_loss - w_loss) / abs(w_loss), kinds)
-
-
-def _fails(tol, errors, gap):
-    logits, loss, kinds = errors
-    return bool(logits > tol["logits"] or loss > tol["loss"]
-                or max(kinds.values()) > tol["grads"] or gap > tol["gap"])
-
-
 @pytest.mark.parametrize("dtype,seed", [("float32", 11), ("bfloat16", 11),
                                         ("bfloat16", 14)])
 def test_logits_loss_and_gradients_match_the_reference(dtype, seed):
-    net, config = _net(dtype, seed=seed)
+    net, config, params = _reading_net(dtype, seed)
     ids = _ids()
-    params = _params(net)
-    got = _net_readings(net, ids)
-    want, gap = _reference_readings(params, ids, config, given=got[1])
-    logits, loss, kinds = _errors(got, want)
+    got = checks.net_readings(net, ids, MODEL.loss_fn(), heads=2)
+    want, gap = checks.reference_readings(MODEL, params, ids, config,
+                                          given=got[1])
+    logits, loss, kinds = checks.errors(got, want)
     tol = TOLERANCE[dtype]
     assert gap <= tol["gap"], gap
     assert logits <= tol["logits"], logits
@@ -162,7 +109,7 @@ def test_logits_loss_and_gradients_match_the_reference(dtype, seed):
     assert len(kinds) >= 35            # every parameter kind has a gradient
     worst = max(kinds, key=kinds.get)
     assert kinds[worst] <= tol["grads"], (worst, kinds[worst])
-    own, _ = _reference_readings(params, ids, config)
+    own, _ = checks.reference_readings(MODEL, params, ids, config)
     differ = (np.sort(got[1], -1) != np.sort(own[1], -1)).any(-1).mean()
     assert (differ == 0) if dtype == "float32" else (differ < 0.2)
 
@@ -173,18 +120,17 @@ def test_the_limits_refuse_lower_precision_and_a_skipped_term():
     operands, and with one term left out - the selection bias, the shared
     expert, the scan's skip, the routed scaling - must each fail at least
     one of them."""
-    net, config = _net("float32")
+    _, config, params = _reading_net("float32", 11)
     ids = _ids()
-    params = _params(net)
     tol = TOLERANCE["bfloat16"]
 
     def fails(other_params=None, operand=None, **other_config):
-        reading, _ = _reference_readings(other_params or params, ids,
-                                         dict(config, **other_config),
-                                         operand)
-        want, gap = _reference_readings(params, ids, config,
-                                        given=reading[1])
-        return _fails(tol, _errors(reading, want), gap)
+        reading, _ = checks.reference_readings(
+            MODEL, other_params or params, ids, dict(config, **other_config),
+            operand)
+        want, gap = checks.reference_readings(MODEL, params, ids, config,
+                                              given=reading[1])
+        return checks.fails(tol, checks.errors(reading, want), gap)
 
     def without(suffix):
         return {k: (jnp.zeros_like(v) if k.endswith(suffix) else v)
@@ -244,23 +190,23 @@ def test_the_selection_correction_is_balanced_by_its_own_rule(capsys):
 
 
 def test_the_reference_follows_near_ties_and_refuses_a_wrong_router():
-    net, config = _net("float32")
+    _, config, params = _reading_net("float32", 11)
     ids = _ids()
-    params = _params(net)
-    _, _, own, _, _ = MODEL._forward(params, (ids,), config)
+    forward = checks.jitted(MODEL._forward, config)
+    reference = checks.jitted(MODEL.reference, config)
+    _, _, own, _, _ = forward(params, ids)
     slot = MODEL.check_inputs(config, {"batch": 2, "seq": 48}, 0)[1]
     assert slot.shape == own.shape == (3, 2, 48, 5) and (slot == -1).all()
-    free = np.asarray(MODEL.reference(params, (ids, slot), config))
-    same = np.asarray(MODEL.reference(params, (ids, np.asarray(own)),
-                                      config))
+    free = np.asarray(reference(params, ids, slot))
+    same = np.asarray(reference(params, ids, np.asarray(own)))
     np.testing.assert_array_equal(free, same)
     # an expert far from the top-k at one token of the first expert layer:
     # that token's logits are refused
     wrong = np.array(own)
     taken = set(wrong[0, 1, 5].tolist())
     wrong[0, 1, 5, 0] = next(e for e in range(16) if e not in taken)
-    out = np.asarray(MODEL.reference(params, (ids, wrong), config))
-    _, _, _, _, gap = MODEL._forward(params, (ids, wrong), config)
+    out = np.asarray(reference(params, ids, wrong))
+    _, _, _, _, gap = forward(params, ids, wrong)
     if float(np.asarray(gap).max()) > 0.02:
         assert np.isnan(out[:, 1, 5]).all()
         assert np.isfinite(out[:, 0]).all()
@@ -315,9 +261,11 @@ def test_the_chunked_scan_is_the_recurrence_forward_and_backward(
     x, dt, a, b, c = _scan_inputs(shape, dtype)
     weight = jnp.asarray(np.random.RandomState(1).randn(*x.shape),
                          jnp.float32)
+    # each side under one jax.jit (as a step runs the scan): eagerly every
+    # primitive compiles alone, and each lax.scan traces anew
     with jax.default_matmul_precision("highest"):
-        got = ssm.ssd_scan(x, dt, a, b, c, chunk)
-        want = _recurrence(x, dt, a, b, c)
+        got = jax.jit(ssm.ssd_scan, static_argnums=5)(x, dt, a, b, c, chunk)
+        want = jax.jit(_recurrence)(x, dt, a, b, c)
         assert got.dtype == x.dtype and got.shape == x.shape
         scale = float(jnp.abs(want).max())
         assert float(jnp.abs(got.astype(jnp.float32) - want).max()) \
@@ -327,10 +275,10 @@ def test_the_chunked_scan_is_the_recurrence_forward_and_backward(
             return lambda *args: (fn(*args).astype(jnp.float32)
                                   * weight).sum()
 
-        mine = jax.grad(loss(lambda *v: ssm.ssd_scan(*v, chunk)),
-                        argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
-        theirs = jax.grad(loss(_recurrence),
-                          argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+        mine = jax.jit(jax.grad(loss(lambda *v: ssm.ssd_scan(*v, chunk)),
+                                argnums=(0, 1, 2, 3, 4)))(x, dt, a, b, c)
+        theirs = jax.jit(jax.grad(loss(_recurrence),
+                                  argnums=(0, 1, 2, 3, 4)))(x, dt, a, b, c)
     for name, g, w in zip("x dt a b c".split(), mine, theirs):
         assert g.dtype == w.dtype and g.shape == w.shape, name
         err = float(jnp.abs(g.astype(jnp.float32)
@@ -476,10 +424,11 @@ def test_latent_squared_relu_experts_are_the_dense_loop():
 
     latent = x @ params["latent_down.weight"].T
     ws = [params[n] for n in names]
+    # each side under one jax.jit: eagerly every primitive compiles alone
     with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(mine, argnums=(0, 1, 2, 3, 4))(x, latent,
-                                                                *ws)
-        want = jax.value_and_grad(theirs, argnums=(0, 1, 2, 3, 4))(
+        got = jax.jit(jax.value_and_grad(mine, argnums=(0, 1, 2, 3, 4)))(
+            x, latent, *ws)
+        want = jax.jit(jax.value_and_grad(theirs, argnums=(0, 1, 2, 3, 4)))(
             x, latent, *ws)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
     for g, w in zip(got[1], want[1]):

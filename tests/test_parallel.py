@@ -225,7 +225,9 @@ def test_ring_attention_gradients_match_local():
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v).sum()
 
-    g_ring = jax.grad(ring_loss, argnums=(0, 1, 2))(q, k, v)
+    # under jax.jit: eagerly each primitive of the ring's backward pass
+    # dispatches (and compiles) on its own
+    g_ring = jax.jit(jax.grad(ring_loss, argnums=(0, 1, 2)))(q, k, v)
     g_loc = jax.grad(local_loss, argnums=(0, 1, 2))(q, k, v)
     for gr, gl in zip(g_ring, g_loc):
         np.testing.assert_allclose(np.asarray(gr), np.asarray(gl),
@@ -439,17 +441,58 @@ def test_moe_rejects_gate_expert_mismatch():
         apply(jnp.zeros((16, 4), jnp.float32), gate_w, params)
 
 
-def test_ring_attention_flash_path_matches_single_device():
+def _flash_ring_readings(mesh, q, k, v, causal, g=None, **keywords):
+    """context_parallel_attention by the ring, traced under one
+    ``jax.jit`` - eagerly every step of an interpreted kernel's grid
+    dispatches on its own: the output, and with a cotangent `g` also the
+    gradients by q, k and v."""
+    from mxnet_tpu.parallel import context_parallel_attention
+
+    def run(q, k, v):
+        return context_parallel_attention(q, k, v, mesh, causal=causal,
+                                          **keywords)
+
+    def readings(q, k, v, g):
+        if g is None:
+            return run(q, k, v)
+        out, vjp = jax.vjp(run, q, k, v)
+        return (out,) + vjp(g)
+
+    return jax.jit(readings)(q, k, v, g)
+
+
+@pytest.mark.parametrize("case", ["full", "causal", "aligned-shards"])
+def test_ring_attention_flash_path_matches_single_device(case):
     """Block-aligned shards route ring hops through the Pallas flash
     kernel (interpret off-TPU): outputs AND gradients must match the
-    single-device attention reference."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    single-device attention reference ("full" and "causal": 256 a shard
+    over sp=4).  "aligned-shards": per-shard shapes aligned to the flash
+    blocks over sp=2 with the other devices on dp - each ring hop runs
+    the REAL Pallas kernel (interpret on CPU, Mosaic on TPU) and the
+    output must still match the dense reference."""
+    from jax.sharding import Mesh
+    from mxnet_tpu.parallel import make_mesh
     from mxnet_tpu.parallel import ring as ring_mod
-    from mxnet_tpu.ops.attention import _attention_jnp
+    from mxnet_tpu.ops.attention import _attention_jnp, attention_impl_scope
 
+    if case == "aligned-shards":
+        np.random.seed(3)
+        B, L, H, D = 1, 512, 1, 128          # sp=2 -> 256 per shard
+        q = np.random.randn(B, L, H, D).astype(np.float32)
+        k = np.random.randn(B, L, H, D).astype(np.float32)
+        v = np.random.randn(B, L, H, D).astype(np.float32)
+        mesh = make_mesh(axes=("dp", "sp"), shape=(4, 2))
+        with attention_impl_scope("pallas"):
+            assert ring_mod._flash_ok(
+                jnp.zeros((B, L // 2, H, D)), jnp.zeros((B, L // 2, H, D)))
+            out = _flash_ring_readings(mesh, jnp.asarray(q), jnp.asarray(k),
+                                       jnp.asarray(v), True)
+        ref = _ref_attention(q, k, v, True)
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
+                                   atol=2e-4)
+        return
+
+    causal = case == "causal"
     devs = np.array(jax.devices("cpu")[:4])
     mesh = Mesh(devs, ("sp",))
     B, L, H, D = 1, 1024, 2, 128          # 256 per shard: block-aligned
@@ -459,35 +502,28 @@ def test_ring_attention_flash_path_matches_single_device():
     v = jnp.asarray(rng.randn(B, L, H, D), jnp.float32) * 0.1
     g = jnp.asarray(rng.randn(B, L, H, D), jnp.float32) * 0.1
     scale = 1.0 / np.sqrt(D)
-
-    from mxnet_tpu.ops import attention as att
-    prev = att.set_attention_impl("pallas")   # engage flash off-TPU
-    for causal in (False, True):
-        def run(q, k, v):
-            return ring_mod.context_parallel_attention(
-                q, k, v, mesh, sp_axis="sp", causal=causal, method="ring",
-                scale=scale)
+    with attention_impl_scope("pallas"):      # engage flash off-TPU
         # the flash path must actually engage on these shapes
         assert ring_mod._flash_ok(
             jnp.zeros((B, L // 4, H, D)), jnp.zeros((B, L // 4, H, D)))
-        out, vjp = jax.vjp(run, q, k, v)
-        dq, dk, dv = vjp(g)
+        out, dq, dk, dv = _flash_ring_readings(mesh, q, k, v, causal, g,
+                                               sp_axis="sp", method="ring",
+                                               scale=scale)
 
-        def ref(q, k, v):
-            o = _attention_jnp(q.transpose(0, 2, 1, 3),
-                               k.transpose(0, 2, 1, 3),
-                               v.transpose(0, 2, 1, 3), scale, causal)
-            return o.transpose(0, 2, 1, 3)
-        want, vjp_r = jax.vjp(ref, q, k, v)
-        dq_r, dk_r, dv_r = vjp_r(g)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   rtol=2e-3, atol=2e-4)
-        for got, ref_g, nm in ((dq, dq_r, "dq"), (dk, dk_r, "dk"),
-                               (dv, dv_r, "dv")):
-            err = np.abs(np.asarray(got) - np.asarray(ref_g)).max()
-            rel = err / max(np.abs(np.asarray(ref_g)).max(), 1e-6)
-            assert rel < 5e-3, (causal, nm, rel)
-    att.set_attention_impl(prev)
+    def ref(q, k, v):
+        o = _attention_jnp(q.transpose(0, 2, 1, 3),
+                           k.transpose(0, 2, 1, 3),
+                           v.transpose(0, 2, 1, 3), scale, causal)
+        return o.transpose(0, 2, 1, 3)
+    want, vjp_r = jax.vjp(ref, q, k, v)
+    dq_r, dk_r, dv_r = vjp_r(g)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-3, atol=2e-4)
+    for got, ref_g, nm in ((dq, dq_r, "dq"), (dk, dk_r, "dk"),
+                           (dv, dv_r, "dv")):
+        err = np.abs(np.asarray(got) - np.asarray(ref_g)).max()
+        rel = err / max(np.abs(np.asarray(ref_g)).max(), 1e-6)
+        assert rel < 5e-3, (causal, nm, rel)
 
 
 # -- 2-bit gradient compression (reference: gradient_compression.cc) --------
@@ -596,24 +632,3 @@ def test_2bit_compressed_dp_training_converges():
     comp = train({"type": "2bit", "threshold": 5.0})
     # convergence delta bound: compressed within 2x of uncompressed + eps
     assert comp < 2 * plain + 0.1, (plain, comp)
-
-
-def test_ring_attention_flash_path_aligned_shards():
-    """Per-shard shapes aligned to the flash blocks + impl forced to
-    'pallas': each ring hop runs the REAL Pallas kernel (interpret on
-    CPU, Mosaic on TPU) and must still match the dense reference."""
-    from mxnet_tpu.ops.attention import attention_impl_scope
-    from mxnet_tpu.parallel import make_mesh, context_parallel_attention
-    np.random.seed(3)
-    B, L, H, D = 1, 512, 1, 128          # sp=2 -> 256 per shard
-    q = np.random.randn(B, L, H, D).astype(np.float32)
-    k = np.random.randn(B, L, H, D).astype(np.float32)
-    v = np.random.randn(B, L, H, D).astype(np.float32)
-    mesh = make_mesh(axes=("dp", "sp"), shape=(4, 2))
-    with attention_impl_scope("pallas"):
-        out = context_parallel_attention(
-            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), mesh,
-            causal=True)
-    ref = _ref_attention(q, k, v, True)
-    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-3,
-                               atol=2e-4)
